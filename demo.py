@@ -8,6 +8,7 @@ import argparse
 
 import numpy as np
 
+from mx_rcnn_tpu.compile import setup_compile_cache
 from mx_rcnn_tpu.data.image import get_image, resize_to_bucket, transform_image
 from mx_rcnn_tpu.eval import Predictor, im_detect
 from mx_rcnn_tpu.logger import logger
@@ -28,6 +29,7 @@ def parse_args():
 
 
 def demo_net(args):
+    setup_compile_cache()
     cfg = config_from_args(args, train=False)
     model = build_model(cfg)
     params = load_eval_params(args, cfg, model)

@@ -12,7 +12,9 @@ vs ``compile/aot_miss`` in the telemetry stream).
 
 from mx_rcnn_tpu.compile.registry import (ProgramKey, ProgramRegistry,
                                           config_digest, configure_jax_cache,
-                                          registry_cache_dir)
+                                          registry_cache_dir,
+                                          setup_compile_cache)
 
 __all__ = ["ProgramRegistry", "ProgramKey", "config_digest",
-           "configure_jax_cache", "registry_cache_dir"]
+           "configure_jax_cache", "registry_cache_dir",
+           "setup_compile_cache"]

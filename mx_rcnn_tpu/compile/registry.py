@@ -19,12 +19,17 @@ from scratch.
   ``lookup(kind, static=...)`` replace the Predictor's ad-hoc dicts:
   builders are lazy, built-once, and LRU-evicted past ``max_programs``
   (multi-model serving needs a bound; XLA executables pin device memory).
-* **One persistent cache.**  When the registry owns a cache base (the
-  ``MXR_PROGRAM_CACHE`` env var or an explicit ``cache_base``), it
-  points jax's compilation cache at a machine-fingerprint dir extended
-  with the dtype and cache-schema version (``registry_cache_dir``) and
-  drops ``jax_persistent_cache_min_compile_time_secs`` to 0 so even
-  tiny-model programs persist.  A sidecar *marker manifest*
+* **One persistent cache.**  Where jax's compilation cache lives is
+  decided in ONE place, :func:`setup_compile_cache`, which every entry
+  point calls: ``JAX_COMPILATION_CACHE_DIR`` when the environment sets it
+  (jax reads the variable itself; no code path here redirects it), else
+  one fixed git-ignored dir in the checkout (``.jax_cache``).  When the registry owns a
+  cache base (the ``MXR_PROGRAM_CACHE`` env var or an explicit
+  ``cache_base``) and the environment variable is unset, it points jax's
+  compilation cache at a machine-fingerprint dir extended with the dtype
+  and cache-schema version (``registry_cache_dir``) and drops
+  ``jax_persistent_cache_min_compile_time_secs`` to 0 so even tiny-model
+  programs persist.  A sidecar *marker manifest*
   (``<dir>/programs/<keyhash>.json``, one JSON file per program) records
   which programs a previous process already compiled: on the first
   in-process dispatch of a key, a present-and-matching marker counts as
@@ -65,6 +70,18 @@ from mx_rcnn_tpu.telemetry import Hist
 CACHE_SCHEMA = "mxr-programs-v1"
 
 ENV_CACHE_BASE = "MXR_PROGRAM_CACHE"
+
+# jax's own variable for the persistent compilation cache: where it is set,
+# the XLA entries live there and nothing in this repo points jax elsewhere
+ENV_JAX_CACHE = "JAX_COMPILATION_CACHE_DIR"
+
+# the program's cache when the environment names none: ONE fixed path in
+# the checkout (git-ignored).  The path is part of what makes an entry
+# findable again, so it is never derived from a temp name, a pid, a time
+# or the host's name.
+DEFAULT_JAX_CACHE = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 INFER_DTYPES = ("float32", "bfloat16", "int8", "int8-activation")
 
@@ -114,24 +131,45 @@ def registry_cache_dir(base: Optional[str] = None,
     return machine_cache_dir(base, extra=(f"dtype={dtype}", CACHE_SCHEMA))
 
 
+def setup_compile_cache() -> str:
+    """Place jax's persistent compilation cache for a program entry point
+    (every driver calls this before its first compile) and return the
+    directory.  Where the cache is already placed it is left alone:
+    ``JAX_COMPILATION_CACHE_DIR`` reaches this config through jax itself,
+    and a harness that runs a driver in-process (the test suite) has set
+    its own.  Otherwise :data:`DEFAULT_JAX_CACHE`, so that a later process
+    of the same checkout finds what this one compiled."""
+    import jax
+
+    placed = jax.config.jax_compilation_cache_dir
+    if placed:
+        return placed
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_JAX_CACHE)
+    return DEFAULT_JAX_CACHE
+
+
 def configure_jax_cache(cache_dir: str) -> None:
-    """Point jax's persistent compilation cache at ``cache_dir`` and
-    persist *every* compile (min_compile_time 0): the registry's warm
-    boots depend on tiny programs hitting disk too, not just the
-    >1 s flagship compiles ``__graft_entry__`` filters for.
+    """Persist *every* compile (min_compile_time 0: the registry's warm
+    boots depend on tiny programs hitting disk too, not just the >1 s
+    flagship compiles jax's default filters for) and — unless
+    ``JAX_COMPILATION_CACHE_DIR`` placed the cache from outside, which is
+    never redirected — point jax's persistent compilation cache at
+    ``cache_dir``.
 
     jax initializes its cache object at most once, on the first compile
     — and model/param init compiles typically run before any registry
-    exists, pinning the cache to whatever dir the environment set at
-    import time.  ``reset_cache()`` drops that instance so the next
-    compile re-initializes against ``cache_dir``; without it the config
-    update is silently ignored and nothing persists where the marker
-    manifest says it does."""
+    exists, pinning the cache to whatever dir was configured first.
+    ``reset_cache()`` drops that instance so the next compile
+    re-initializes against ``cache_dir``; without it the config update is
+    silently ignored and nothing persists where the marker manifest says
+    it does."""
     import jax
 
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    if os.environ.get(ENV_JAX_CACHE):
+        return
     os.makedirs(cache_dir, exist_ok=True)
     jax.config.update("jax_compilation_cache_dir", cache_dir)
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     from jax.experimental.compilation_cache import compilation_cache
     compilation_cache.reset_cache()
 
@@ -171,13 +209,13 @@ class ProgramRegistry:
     dtype : inference dtype variant this registry's programs run in
     plan : MeshPlan or None — folded into every key's sharding field
     cache_base : explicit persistent-cache base dir.  When given (or the
-        ``MXR_PROGRAM_CACHE`` env var is set) the registry OWNS the jax
-        compilation cache: it points jax at ``registry_cache_dir`` and
-        keeps its marker manifest there.  Otherwise it piggybacks marker
-        files on whatever cache dir is already configured (the
-        ``__graft_entry__``/conftest machine dir), never touching global
-        jax config — warm-start accounting still works, test-suite
-        caching is untouched.
+        ``MXR_PROGRAM_CACHE`` env var is set) the registry OWNS a cache
+        dir: it keeps its marker manifest under ``registry_cache_dir``
+        and — unless ``JAX_COMPILATION_CACHE_DIR`` already placed jax's
+        cache from outside — points jax there too.  Otherwise it
+        piggybacks marker files on whatever cache dir is already
+        configured (``setup_compile_cache``/conftest), never touching
+        global jax config — warm-start accounting still works.
     max_programs : LRU bound on *built callables* (not markers); None =
         unbounded.
     pinned : exempt this registry's callables from LRU eviction even
@@ -205,6 +243,9 @@ class ProgramRegistry:
         self.counters: Dict[str, int] = {
             "programs": 0, "aot_hit": 0, "aot_miss": 0,
             "key_collisions": 0, "evictions": 0,
+            # persistent cache could not be configured: serving goes on,
+            # every boot compiles cold — counted so it cannot hide
+            "cache_unavailable": 0,
         }
         self.compile_hist = Hist()
 
@@ -217,6 +258,8 @@ class ProgramRegistry:
             except Exception as e:  # cache is an optimization, not a dep
                 logger.warning("program registry: persistent cache "
                                "unavailable (%s)", e)
+                self.counters["cache_unavailable"] += 1
+                telemetry.get().counter("compile/cache_unavailable")
                 self.cache_dir = None
         else:
             self.cache_dir = self._active_jax_cache_dir()

@@ -34,8 +34,9 @@ threshold or per-gt tie (tests/test_assign_fused.py bounds this).
 
 Non-TPU backends fall back to the dense path (Mosaic only lowers on TPU);
 CI parity runs this kernel in Pallas interpret mode
-(tests/test_assign_fused.py), and the on-chip gate is
-scripts/check_pallas.py + tests/test_tpu_kernels.py.
+(tests/test_assign_fused.py), tests/test_tpu_kernels.py compiles it for
+a described v5e at the FPN P2 shape, and the on-chip equivalence check is
+scripts/check_pallas.py.
 """
 
 from __future__ import annotations
@@ -46,6 +47,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from mx_rcnn_tpu.kernels.per_image import map_images
 
 _TILE_N = 2048   # anchors per grid step ((TILE_N, 128) f32 tile = 1 MB VMEM)
 _G_PAD = 128     # gt padded to one lane width
@@ -186,8 +189,7 @@ def _assign_vmappable(interpret: bool):
         )
         # map body calls fn (not _assign_core) so nested vmaps re-enter
         # this rule instead of pushing batching into pallas_call
-        out = jax.lax.map(lambda t: fn(*t),
-                          (anchors, gt_boxes, gt_valid, inside))
+        out = map_images(fn, (anchors, gt_boxes, gt_valid, inside))
         return out, (True, True, True, True)
 
     _VMAP_CACHE[interpret] = fn

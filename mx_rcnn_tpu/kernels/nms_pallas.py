@@ -52,6 +52,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from mx_rcnn_tpu.kernels.per_image import map_images
+
 _BR = 256    # row tile (sublane multiple)
 _BS = 8      # sweep block: rows resolved per step (8-aligned, divides 32)
 _PL = 32     # bits per packed word
@@ -178,9 +180,11 @@ def nms_pallas(boxes: jnp.ndarray, scores: jnp.ndarray, max_out: int,
     sweep is sequential per image anyway.
 
     On non-TPU backends (the CPU test mesh) this delegates to the pure-JAX
-    oracle — Mosaic kernels only lower on TPU; kernel-vs-oracle equivalence
-    runs on the real chip (scripts/check_pallas.py, and bench exercises it
-    every round via CXX_PROPOSAL).
+    oracle — Mosaic kernels only lower on TPU.  A chip run must prove which
+    side it took: ``chip_smoke.py`` fails unless the programs it ran hold
+    the ``tpu_custom_call`` and the kernel equals the oracle on the chip
+    (scripts/check_pallas.py is the longer hand-run sweep);
+    tests/test_tpu_kernels.py compiles ``_nms_core`` for a described v5e.
     """
     if jax.default_backend() != "tpu":
         from mx_rcnn_tpu.ops.nms import nms_padded
@@ -209,14 +213,15 @@ def _nms_vmappable(max_out: int, iou_thresh: float):
             for a, b in zip((boxes, scores, valid), in_batched)
         )
         # The Mosaic kernels can't auto-batch (SMEM specs), so each batch
-        # level becomes one serial lax.map.  The map body calls the
+        # level becomes one serial lax.map (map_images; on a mesh, each
+        # device over its own rows).  The map body calls the
         # custom_vmap-wrapped fn — NOT _nms_core — so a nested vmap batches
         # the inner call, re-enters this rule, and gets its own lax.map
         # instead of pushing batching into the pallas_call (the lowering
         # failure this rule exists to avoid).  Glue (prep/post) inside vs
         # outside the scan measured perf-neutral at B=8: the scan's
         # residual cost is kernel sequencing, not glue.
-        out = jax.lax.map(lambda t: fn(*t), (boxes, scores, valid))
+        out = map_images(fn, (boxes, scores, valid))
         return out, (True, True)
 
     _VMAP_CACHE[(max_out, iou_thresh)] = fn

@@ -30,11 +30,19 @@ TPU equivalent of the KVStore push/pull in the reference call stack
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Sequence
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+
+def batch_axes_of(mesh) -> tuple:
+    """The mesh axes the batch shards over: every axis that is neither
+    ``model`` nor ``space`` (so ``dcn`` and ``data``).  Takes a concrete
+    or an abstract mesh."""
+    return tuple(n for n in mesh.axis_names if n not in ("model", "space"))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -54,8 +62,25 @@ class MeshPlan:
 
     @property
     def batch_axes(self) -> tuple:
-        return tuple(n for n in self.mesh.axis_names
-                     if n not in ("model", "space"))
+        return batch_axes_of(self.mesh)
+
+    def traced(self, fn):
+        """``fn``, on its way into ``jax.jit``, wrapped so that it is
+        traced with this plan's mesh ambient
+        (``jax.sharding.get_abstract_mesh``).  XLA partitions everything
+        in a step by itself EXCEPT a Mosaic kernel, which jax refuses to
+        lower for more than one device outside a ``shard_map``; the
+        ambient mesh is how the kernels' batching rules
+        (``kernels/per_image.py``) know to wrap themselves in one.  The
+        axes stay Auto, so nothing else about the trace changes."""
+        abstract = self.mesh.abstract_mesh
+
+        @functools.wraps(fn)
+        def under_mesh(*args, **kwargs):
+            with jax.sharding.use_abstract_mesh(abstract):
+                return fn(*args, **kwargs)
+
+        return under_mesh
 
     @property
     def n_data(self) -> int:

@@ -7,6 +7,7 @@ from __future__ import annotations
 import argparse
 import os
 
+from mx_rcnn_tpu.compile import setup_compile_cache
 from mx_rcnn_tpu.data import TestLoader
 from mx_rcnn_tpu.eval import Predictor, generate_proposals
 from mx_rcnn_tpu.logger import logger
@@ -16,6 +17,7 @@ from mx_rcnn_tpu.tools.common import (add_common_args, config_from_args,
 
 
 def test_rpn(args, cfg=None, params=None, imdb=None, roidb=None):
+    setup_compile_cache()
     cfg = cfg or config_from_args(args, train=False)
     if imdb is None:
         imdb = get_imdb(args, cfg)

@@ -6,6 +6,7 @@ from __future__ import annotations
 
 import argparse
 
+from mx_rcnn_tpu.compile import setup_compile_cache
 from mx_rcnn_tpu.data import AnchorLoader
 from mx_rcnn_tpu.logger import logger
 from mx_rcnn_tpu.models import build_model
@@ -19,6 +20,7 @@ from mx_rcnn_tpu.train import ResilienceOptions, fit
 def train_rpn(args, cfg=None, params=None, roidb=None, frozen_shared=False):
     """Callable both as a CLI stage and from train_alternate (which passes
     params of the previous stage and frozen_shared=True for round 2)."""
+    setup_compile_cache()
     plan, pidx, pcount = setup_parallel(args)
     cfg = cfg or config_from_args(args, train=True)
     n_dev = plan.n_data if plan else 1

@@ -187,6 +187,7 @@ def _jit_planned(fn, plan: MeshPlan, donate: bool, wrap=lambda sh: sh):
     pytree structure mismatch at dispatch."""
     repl = plan.replicated()
     batch_sh = wrap(plan.batch())
+    fn = plan.traced(fn)   # mesh ambient: Mosaic kernels shard_map themselves
     if plan.n_model > 1 or plan.n_space > 1:
         cache = {}
 

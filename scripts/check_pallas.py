@@ -1,9 +1,15 @@
 #!/usr/bin/env python
-"""Kernel-vs-oracle equivalence + timing on the REAL TPU chip.
+"""Kernel-vs-oracle equivalence + timing on the REAL TPU chip — the long
+hand-run sweep (odd shapes, adversarial structure, the vmap rule, the
+fused assign kernel, timings).
 
-Run manually (pytest runs on the CPU mesh where Mosaic can't lower; there
-``nms_pallas`` delegates to the oracle, so CPU tests can't catch kernel
-bugs).  Exits nonzero on any mismatch.
+Run manually on the chip.  The two guards that run by themselves are
+narrower: tests/test_tpu_kernels.py COMPILES the kernels for a described
+v5e at production shapes (pytest runs on the CPU mesh, where ``nms_pallas``
+delegates to the oracle, so nothing there can execute a kernel), and
+``chip_smoke.py``'s kernels phase checks NMS equality on the chip at the
+12000->2000 and 6000->300 contracts.  The assert below is the rule for
+every chip script: fail, never fall back.  Exits nonzero on any mismatch.
 """
 
 import os

@@ -57,8 +57,11 @@ queue wait + forward + post-process + transport); ``imgs_per_sec`` is
 2xx responses over the wall from first fire to last response;
 ``error_rate`` is the non-2xx fraction.  With ``--assert-2xx`` the exit
 code is 1 unless every response was 2xx, and the failure line on stderr
-names each offending status and its count.  Pure stdlib + numpy; no jax
-import, safe on a machine with no accelerator.
+names each offending status and its count.  Stdlib + numpy on its own
+paths; importing ``mx_rcnn_tpu.serve.frontend`` for the payload codec does
+put jax into ``sys.modules``, but no back end is ever initialised (pinned
+by tests/test_chip_smoke.py) — safe on a machine with no accelerator, and
+beside a server process that holds the chip.
 
 Fabric mode (ISSUE 12): with ``--fabric`` the TCP target is a fabric
 router (serve.py --fabric) — the router's ``/metrics`` per-member

@@ -7,7 +7,7 @@ program itself: the xplane module busy divided by n is the true per-step
 device time inside the loop, and state.step is asserted to advance by
 exactly n (no silently skipped iterations).  Divergence between in-loop
 and per-dispatch step time = real program differences (loop-invariant
-code motion, donation aliasing vs per-call buffer copies), not tunnel
+code motion, donation aliasing vs per-call buffer copies), not host-clock
 artifacts.
 """
 

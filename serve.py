@@ -45,6 +45,7 @@ import tempfile
 import threading
 
 from mx_rcnn_tpu import telemetry
+from mx_rcnn_tpu.compile import setup_compile_cache
 from mx_rcnn_tpu.logger import logger
 from mx_rcnn_tpu.tools.common import (add_common_args, apply_program_cache,
                                       config_from_args,
@@ -52,7 +53,7 @@ from mx_rcnn_tpu.tools.common import (add_common_args, apply_program_cache,
                                       start_observability)
 
 
-def parse_args():
+def parse_args(argv=None):
     parser = argparse.ArgumentParser(
         description="Serve a Faster R-CNN network over HTTP")
     add_common_args(parser, train=False)
@@ -338,7 +339,7 @@ def parse_args():
     parser.add_argument("--watch-tick-s", type=float, default=1.0,
                         dest="watch_tick_s",
                         help="watchtower evaluation tick period")
-    return parser.parse_args()
+    return parser.parse_args(argv)
 
 
 def _configure_tracing(args, member: str, rank: int = 0) -> None:
@@ -960,6 +961,7 @@ def choose_mode(args) -> str:
 
 
 def main(args):
+    setup_compile_cache()
     mode = choose_mode(args)
     if getattr(args, "cascade", "") and not getattr(args, "models", ""):
         raise SystemExit("--cascade routes between two --models entries; "

@@ -7,6 +7,7 @@ from __future__ import annotations
 
 import argparse
 
+from mx_rcnn_tpu.compile import setup_compile_cache
 from mx_rcnn_tpu.data import TestLoader
 from mx_rcnn_tpu.eval import Predictor, pred_eval
 from mx_rcnn_tpu.logger import logger
@@ -48,6 +49,7 @@ def parse_args():
 
 
 def test_rcnn(args):
+    setup_compile_cache()
     cfg = config_from_args(args, train=False)
     if args.device_postprocess and cfg.network.HAS_MASK \
             and cfg.TEST.MASK_PASTE == "native":

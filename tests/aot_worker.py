@@ -25,10 +25,9 @@ import time
 def main(cache_base: str) -> None:
     os.environ["JAX_PLATFORMS"] = "cpu"
     os.environ["MXR_PROGRAM_CACHE"] = cache_base
-    import jax
-
-    jax.config.update("jax_platforms", "cpu")
     import dataclasses
+
+    import jax
 
     from mx_rcnn_tpu.config import generate_config
     from mx_rcnn_tpu.eval import Predictor

@@ -43,15 +43,17 @@ def main(pid: int, nproc: int, port: int, ckpt_dir: str):
         f"--xla_force_host_platform_device_count={N_LOCAL[nproc]}")
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
-    # PER-RANK compile cache: a shared cache makes hit/miss asymmetric
-    # between ranks, skewing their compile finish times; the Gloo clique
-    # rendezvous (first collective) tolerates only ~30 s of skew on top
-    # of the init_distributed warmup barrier.  A per-rank dir keeps every
-    # rank's cache behavior identical run to run.
-    cache = os.environ.get("JAX_TEST_CACHE", "/tmp/jax_test_cache")
-    jax.config.update("jax_compilation_cache_dir", f"{cache}_mp{nproc}_{pid}")
-    jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
+    # PER-RANK compile cache (fixed path: world size + rank): a shared
+    # cache makes hit/miss asymmetric between ranks, skewing their compile
+    # finish times; the Gloo clique rendezvous (first collective)
+    # tolerates only ~30 s of skew on top of the init_distributed warmup
+    # barrier.  A per-rank dir keeps every rank's cache behavior identical
+    # run to run.  Like the suite's own cache it yields to
+    # JAX_COMPILATION_CACHE_DIR, which jax reads itself.
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        cache = os.environ.get("JAX_TEST_CACHE", "/tmp/jax_test_cache")
+        jax.config.update("jax_compilation_cache_dir",
+                          f"{cache}_mp{nproc}_{pid}")
     if nproc > 1:
         from mx_rcnn_tpu.parallel import init_distributed
 

@@ -132,3 +132,40 @@ def test_fused_vmap_batches_via_map(rng):
         np.testing.assert_allclose(np.asarray(out[2][b]), gm, rtol=0, atol=ULP)
         _check_discrete(ov, gm, np.asarray(v), am, np.asarray(out[1][b]), f"argmax[{b}]")
         _check_discrete(ov, gm, np.asarray(v), tie, np.asarray(out[3][b]), f"tie[{b}]")
+
+
+import pytest  # noqa: E402
+
+
+@pytest.mark.parametrize("batch", [4, 3])
+def test_fused_vmap_on_a_mesh_shard_maps_itself(rng, batch):
+    """Traced for a mesh (``MeshPlan.traced``), the batching rule wraps its
+    per-image map in a ``shard_map`` — XLA cannot partition a Mosaic
+    kernel, and jax refuses to lower one for several devices outside one.
+    Same results as the plain map, whether the data axis divides the
+    batch (each device takes its rows) or not (computed whole on each)."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from mx_rcnn_tpu.parallel import make_mesh
+
+    plan = make_mesh(jax.devices()[:4], data=4)
+    anchors, _, _, inside = _case(rng, 1)
+    cases = [_case(rng, n)[1:3] for n in range(2, 2 + batch)]
+    gts = jnp.stack([jnp.asarray(g) for g, _ in cases])
+    valids = jnp.stack([jnp.asarray(v) for _, v in cases])
+
+    def batched(g, v):
+        return jax.vmap(lambda gi, vi: assign_reduce_pallas(
+            jnp.asarray(anchors), gi, vi, jnp.asarray(inside),
+            interpret=True))(g, v)
+
+    plain = jax.jit(batched)
+    on_mesh = jax.jit(plan.traced(batched))
+    rows = P("data") if batch % 4 == 0 else P()
+    placed = [jax.device_put(x, NamedSharding(plan.mesh, rows))
+              for x in (gts, valids)]
+    text = on_mesh.lower(*placed).as_text()
+    assert "sdy.manual_computation" in text   # the shard_map is there
+    assert "sdy.manual_computation" not in plain.lower(gts, valids).as_text()
+    for got, want in zip(on_mesh(*placed), plain(gts, valids)):
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

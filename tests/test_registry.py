@@ -10,6 +10,8 @@ the persistent XLA cache) lives in tests/test_warmstart.py.
 
 import json
 import os
+import subprocess
+import sys
 
 import jax
 import pytest
@@ -50,7 +52,8 @@ def test_note_dispatch_first_seen_once_and_markers(tmp_path,
     assert reg.note_dispatch("predict", (2, 96, 128, 3)) is False
     assert reg.note_dispatch("predict", (2, 128, 96, 3)) is True
     assert reg.counters == {"programs": 2, "aot_hit": 0, "aot_miss": 2,
-                            "key_collisions": 0, "evictions": 0}
+                            "key_collisions": 0, "evictions": 0,
+                            "cache_unavailable": 0}
 
     # each first dispatch left a marker manifest entry
     markers = os.listdir(os.path.join(reg.cache_dir, "programs"))
@@ -216,3 +219,81 @@ def test_snapshot_shape_and_digest_stability():
     (prog,) = snap["programs"]
     assert prog["kind"] == "predict" and prog["compile_s"] == 0.25
     assert snap["compile_seconds"]["count"] == 1
+
+
+# -- cache placement (one rule for every entry point) ----------------------
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.mark.parametrize("with_program_cache", [False, True])
+def test_registry_never_redirects_a_cache_placed_from_outside(
+        tmp_path, monkeypatch, jax_cache_guard, with_program_cache):
+    # JAX_COMPILATION_CACHE_DIR set: jax's cache lives there; a registry —
+    # with or without --program-cache — keeps only its marker manifest
+    # under its own base
+    from mx_rcnn_tpu.compile import setup_compile_cache
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
+    placed = jax.config.jax_compilation_cache_dir
+    assert setup_compile_cache() == placed
+    base = str(tmp_path / "base") if with_program_cache else None
+    reg = ProgramRegistry(dtype="float32", cache_base=base)
+    assert jax.config.jax_compilation_cache_dir == placed
+    assert reg.counters["cache_unavailable"] == 0
+    assert reg.note_dispatch("predict", (2, 96, 128, 3)) is True
+    manifest_home = base if with_program_cache else placed
+    assert reg._marker_path(reg.key_for("predict", (2, 96, 128, 3))) \
+        .startswith(manifest_home)
+
+
+def test_unconfigurable_cache_is_counted_not_hidden(tmp_path, monkeypatch,
+                                                    jax_cache_guard):
+    # a base that cannot be a directory: serving goes on, cold, and says so
+    blocker = tmp_path / "file"
+    blocker.write_text("not a directory")
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    reg = ProgramRegistry(dtype="float32", cache_base=str(blocker))
+    assert reg.cache_dir is None
+    assert reg.counters["cache_unavailable"] == 1
+    assert reg.snapshot()["counters"]["cache_unavailable"] == 1
+    assert reg.note_dispatch("predict", (2, 96, 128, 3)) is True
+
+
+_PLACEMENT_PROBE = """
+import sys, jax
+from mx_rcnn_tpu.compile import ProgramRegistry, setup_compile_cache
+setup_compile_cache()
+ProgramRegistry(dtype='float32')
+if sys.argv[1]:
+    ProgramRegistry(dtype='float32', cache_base=sys.argv[1])
+print(jax.config.jax_compilation_cache_dir)
+"""
+
+
+def _placed_dir(env_dir, program_cache=""):
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR", "MXR_PROGRAM_CACHE")}
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = env_dir
+    proc = subprocess.run(
+        [sys.executable, "-c", _PLACEMENT_PROBE, program_cache], cwd=REPO,
+        env=env, capture_output=True, text=True, timeout=120)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    return proc.stdout.strip().splitlines()[-1]
+
+
+@pytest.mark.parametrize("with_program_cache", [False, True])
+def test_fresh_process_cache_goes_where_the_variable_says(
+        tmp_path, with_program_cache):
+    env_dir = str(tmp_path / "xla")
+    base = str(tmp_path / "base") if with_program_cache else ""
+    assert _placed_dir(env_dir, base) == env_dir
+
+
+def test_fresh_process_default_cache_is_one_fixed_path_in_the_checkout():
+    from mx_rcnn_tpu.compile.registry import DEFAULT_JAX_CACHE
+
+    assert DEFAULT_JAX_CACHE == os.path.join(REPO, ".jax_cache")
+    # identical across two fresh processes: no temp name, pid, time or host
+    assert _placed_dir("") == _placed_dir("") == DEFAULT_JAX_CACHE

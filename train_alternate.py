@@ -27,6 +27,7 @@ import argparse
 
 import jax
 
+from mx_rcnn_tpu.compile import setup_compile_cache
 from mx_rcnn_tpu.logger import logger
 from mx_rcnn_tpu.models import build_model
 from mx_rcnn_tpu.tools.common import (add_common_args, config_from_args,
@@ -51,6 +52,7 @@ def parse_args():
 
 
 def alternate_train(args):
+    setup_compile_cache()
     if (getattr(args, "dist_auto", False)
             or getattr(args, "dist_coordinator", None) is not None
             or getattr(args, "dist_num_processes", None) is not None

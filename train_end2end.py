@@ -23,6 +23,7 @@ import argparse
 
 import jax
 
+from mx_rcnn_tpu.compile import setup_compile_cache
 from mx_rcnn_tpu.logger import logger
 from mx_rcnn_tpu.data import AnchorLoader
 from mx_rcnn_tpu.models import build_model
@@ -35,17 +36,18 @@ from mx_rcnn_tpu.tools.common import (CappedLoader, add_common_args,
 from mx_rcnn_tpu.train import ResilienceOptions, fit
 
 
-def parse_args():
+def parse_args(argv=None):
     parser = argparse.ArgumentParser(description="Train Faster R-CNN end2end")
     add_common_args(parser, train=True)
     parser.add_argument("--profile", default="",
                         help="write an XProf device trace of early steps here")
     # --steps-per-dispatch comes from add_common_args (shared with the
     # alternate-training stage tools since round 5)
-    return parser.parse_args()
+    return parser.parse_args(argv)
 
 
 def train_net(args):
+    setup_compile_cache()
     # rendezvous before anything can touch the jax backend
     plan, pidx, pcount = setup_parallel(args)
     cfg = config_from_args(args, train=True)
