@@ -37,6 +37,13 @@ from scratch.
   missing one as ``compile/aot_miss``, and a present-but-mismatching one
   as ``compile/key_collision`` (treated as a miss — the marker is
   overwritten, never trusted).
+* **What XLA really did.**  The marker manifest is a *forecast* — it says
+  what a previous process left behind, not what jax then does.  The truth
+  comes from jax itself: ``jax.monitoring`` listeners, registered once a
+  process, keep :func:`xla_counters` — every program the process builds
+  (the eager one-op ones too), the seconds that took, every retrace, and
+  the persistent cache's hits and misses.  ``snapshot()`` carries them
+  under ``counters`` beside the forecast.
 * **One compile-seconds histogram.**  ``record_compile_seconds`` feeds
   the PR-6 ``Hist`` primitive per program kind plus the aggregate
   ``compile/seconds`` telemetry hist, so the report can show the compile
@@ -174,6 +181,63 @@ def configure_jax_cache(cache_dir: str) -> None:
     compilation_cache.reset_cache()
 
 
+# jax.monitoring's event names (jax 0.9: _src/dispatch.py, _src/compiler.py)
+_EV_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+_EV_JAXPR_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_EV_COUNTED = {"/jax/compilation_cache/cache_hits": "persistent_cache_hits",
+               "/jax/compilation_cache/cache_misses":
+                   "persistent_cache_misses"}
+
+_xla_lock = threading.Lock()
+_xla_listening = False
+_xla = {"xla_compiles": 0, "xla_compile_s": 0.0, "jaxpr_traces": 0,
+        "persistent_cache_hits": 0, "persistent_cache_misses": 0}
+
+
+def _on_xla_duration(event, duration, **_):
+    if event == _EV_BACKEND_COMPILE:
+        with _xla_lock:
+            _xla["xla_compiles"] += 1
+            _xla["xla_compile_s"] += duration
+    elif event == _EV_JAXPR_TRACE:
+        with _xla_lock:
+            _xla["jaxpr_traces"] += 1
+
+
+def _on_xla_event(event, **_):
+    key = _EV_COUNTED.get(event)
+    if key is not None:
+        with _xla_lock:
+            _xla[key] += 1
+
+
+def listen_to_xla() -> None:
+    """Register the ``jax.monitoring`` listeners behind
+    :func:`xla_counters`, once a process (every registry asks)."""
+    global _xla_listening
+    with _xla_lock:
+        if _xla_listening:
+            return
+        _xla_listening = True
+    from jax import monitoring
+
+    monitoring.register_event_duration_secs_listener(_on_xla_duration)
+    monitoring.register_event_listener(_on_xla_event)
+
+
+def xla_counters() -> dict:
+    """What XLA did in this process since the first registry was built,
+    monotone: ``xla_compiles`` / ``xla_compile_s`` — programs jax handed to
+    the backend and the seconds until each was loaded (a program found in
+    the persistent cache is counted too; its seconds are the load's);
+    ``persistent_cache_hits`` / ``persistent_cache_misses`` — how many of
+    them the cache served or did not; ``jaxpr_traces`` — every trace of a
+    jitted function.  EVERY program counts, the eager one-op ones as well
+    as the registry's own."""
+    with _xla_lock:
+        return dict(_xla)
+
+
 @dataclasses.dataclass(frozen=True)
 class ProgramKey:
     """Identity of one XLA program as the registry sees it."""
@@ -240,6 +304,10 @@ class ProgramRegistry:
         self._builders: Dict[str, Callable[..., Callable]] = {}
         self._fns: "OrderedDict[Tuple[str, Tuple], Callable]" = OrderedDict()
         self._seen: Dict[ProgramKey, dict] = {}
+        # aot_hit / aot_miss are a FORECAST from the marker manifest (what a
+        # previous process left, not what jax then does: a marker without
+        # its cache entry still reads "hit").  What XLA really built or
+        # loaded is xla_counters(), merged in by snapshot().
         self.counters: Dict[str, int] = {
             "programs": 0, "aot_hit": 0, "aot_miss": 0,
             "key_collisions": 0, "evictions": 0,
@@ -248,6 +316,7 @@ class ProgramRegistry:
             "cache_unavailable": 0,
         }
         self.compile_hist = Hist()
+        listen_to_xla()
 
         base = cache_base or os.environ.get(ENV_CACHE_BASE)
         self.owns_cache = bool(base)
@@ -372,9 +441,10 @@ class ProgramRegistry:
         once per (kind, shape) per process — the caller's "this dispatch
         compiles" signal (steady state must return False forever after).
 
-        On the first sighting, probes the marker manifest: a matching
+        On the first sighting, probes the marker manifest — a FORECAST,
+        not what XLA then does (that is :func:`xla_counters`): a matching
         marker from a previous process is an ``aot_hit`` (the persistent
-        cache will serve the executable), anything else an ``aot_miss``
+        cache should serve the executable), anything else an ``aot_miss``
         (plus ``key_collision`` when a marker exists but disagrees with
         the key — it is overwritten, not trusted)."""
         key = self.key_for(kind, shape)
@@ -461,7 +531,7 @@ class ProgramRegistry:
     def snapshot(self) -> dict:
         """JSON-able state for ``/metrics`` and the warmup log."""
         with self._lock:
-            counters = dict(self.counters)
+            counters = {**self.counters, **xla_counters()}
             seen = [dict(kind=k.kind, shape=list(k.shape),
                          dtype=k.dtype, aot=v["aot"],
                          compile_s=round(v.get("compile_s", 0.0), 3))

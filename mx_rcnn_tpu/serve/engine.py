@@ -42,6 +42,37 @@ keeps its own latency :class:`~mx_rcnn_tpu.telemetry.Hist` instances
 request time), which is what lets ``serve/controller.py`` read live p99s
 and ``/metrics`` expose histogram families in every configuration.
 
+Stages (sink on or off): every stage of a dispatcher turn, of a request
+and of start-up is timed ONCE, through :class:`~mx_rcnn_tpu.telemetry.stage`
+— a ``Hist`` in :attr:`ServeEngine.hists`, a span in the sink when one is
+on, and a ``jax.profiler.TraceAnnotation`` of the same name, so a
+``jax.profiler`` trace of the running server shows them on the Python
+threads' line beside the device's ops.  Dispatcher thread, flat siblings
+with no enclosing annotation: ``serve/idle`` (the wait when nothing is
+due), ``serve/assemble``, ``serve/forward`` (h2d + enqueue),
+``serve/readback`` (the wait for the device + d2h), per image
+``serve/post/decode`` / ``serve/post/nms`` (their ``Hist``s observe once a
+batch, summed over its images) and ``serve/post/records`` (on the timeline
+only); ``serve/postprocess`` (the whole loop) and ``serve/service_time``
+(the whole turn) are clocks without an annotation; ``serve/h2d`` exists in
+``serve_e2e`` mode.  Request threads: ``frontend/read``,
+``frontend/decode``, ``serve/host_prep``, and ``frontend/reply`` (on the
+timeline only).  Start-up: ``setup/model`` / ``setup/params`` /
+``setup/predictor`` (``serve.py::_build_engine``) and ``setup/warmup``
+(``warmup()``), kept as seconds.
+``/metrics`` carries ``"stages": {name: {"count", "sum_s"}}`` for every
+``Hist`` (monotone: two scrapes' difference is the window's), ``"setup":
+{"model_s", "params_s", "predictor_s", "warmup_s"}``, the scrape's
+``"t_s"`` (the server's own clock, for a rate between two scrapes), the
+counters ``post_candidates`` / ``post_kept`` (÷ ``served``: how much the
+host post-process is handed an image, and whether ``TEST.MAX_PER_IMAGE``
+binds) and ``h2d_bytes`` beside ``readback_bytes`` (÷ ``batches``: what a
+turn ships each way, the number ``--serve-e2e`` shrinks), and under
+``"compile"`` the registry's snapshot with what XLA really compiled or
+loaded (``xla_compiles``, ``xla_compile_s``, ``persistent_cache_hits`` /
+``_misses``; ``jaxpr_traces`` rising in steady state is a retrace a turn,
+which no compile counter shows).
+
 SLO hooks (driven by :class:`~mx_rcnn_tpu.serve.controller.SLOController`
 when ``--target-p99-ms`` is set, inert otherwise):
 
@@ -260,6 +291,10 @@ class ServeEngine:
                          # path reports its own so bench can compare)
                          "h2d_transfers": 0, "dispatches": 0,
                          "readbacks": 0, "readback_bytes": 0,
+                         "h2d_bytes": 0,
+                         # host post-process work: boxes over TEST.THRESH
+                         # that went into the per-class NMS, records returned
+                         "post_candidates": 0, "post_kept": 0,
                          "host_prep_ms_total": 0.0,
                          # stream-aware flush bookkeeping: batches that
                          # carried >= 1 stream frame, the frame count, and
@@ -282,6 +317,21 @@ class ServeEngine:
             # serve_e2e shrinks from a cv2 resize+normalize to a pad-copy
             "serve/host_prep": Hist(),
         }
+        # where a dispatcher turn and a request spend their time
+        # (telemetry.stage: each also a span on the profiler's timeline).
+        # One observation a batch (serve/post/*: summed over its images),
+        # except serve/idle — one an idle period, when it ends — and
+        # frontend/* — one a request.
+        for name in ("serve/idle", "serve/assemble", "serve/forward",
+                     "serve/readback", "serve/postprocess",
+                     "serve/post/decode", "serve/post/nms",
+                     "frontend/read", "frontend/decode"):
+            self.hists[name] = Hist()
+        if self.opts.serve_e2e:
+            self.hists["serve/h2d"] = Hist()
+        # start-up's split, seconds ("model_s", "params_s", "predictor_s",
+        # "warmup_s"): written once by serve.py's _build_engine and warmup()
+        self.setup: Dict[str, float] = {}
         self._bucket_hists: Dict[str, Hist] = {}  # "HxW" -> request_time
         # SLO-controller policy overrides (None/absent = configured opts);
         # max_batch is a FLUSH THRESHOLD <= opts.batch_size — the padded
@@ -512,30 +562,29 @@ class ServeEngine:
             raise ValueError(f"expected (H, W, 3) RGB image, "
                              f"got shape {tuple(image.shape)}")
         tel = telemetry.get()
-        t_prep = time.perf_counter()
         raw_hw = ratio = None
-        if self.opts.serve_e2e:
-            # single-dispatch mode: no host resize/normalize — stage the
-            # raw uint8 into its bucket (pad-copy; oversized raws shrink
-            # host-side, see stage_raw_to_bucket) and let the fused
-            # program run the prep on device
-            prepared, raw_hw, ratio, im_info = stage_raw_to_bucket(
-                np.asarray(image), self._scale,
-                max(self.cfg.network.IMAGE_STRIDE,
-                    self.cfg.network.RPN_FEAT_STRIDE))
-        elif self._pool is not None:
-            # host prep off the dispatcher thread either way: on the
-            # caller's thread (workers=0 — concurrent frontends
-            # parallelize the resize) or in the shared prep worker pool
-            # (byte-identical transform, pinned by test_loader_workers),
-            # so the device hot path never waits on a resize
-            prepared, im_info = self._pool.prepare(np.asarray(image),
-                                                   self._scale)
-        else:
-            prepared, im_info = prepare_image(np.asarray(image), self.cfg,
-                                              self._scale)
-        prep_s = time.perf_counter() - t_prep
-        self.hists["serve/host_prep"].observe(prep_s)
+        with self._stage("serve/host_prep") as prep:
+            if self.opts.serve_e2e:
+                # single-dispatch mode: no host resize/normalize — stage
+                # the raw uint8 into its bucket (pad-copy; oversized raws
+                # shrink host-side, see stage_raw_to_bucket) and let the
+                # fused program run the prep on device
+                prepared, raw_hw, ratio, im_info = stage_raw_to_bucket(
+                    np.asarray(image), self._scale,
+                    max(self.cfg.network.IMAGE_STRIDE,
+                        self.cfg.network.RPN_FEAT_STRIDE))
+            elif self._pool is not None:
+                # host prep off the dispatcher thread either way: on the
+                # caller's thread (workers=0 — concurrent frontends
+                # parallelize the resize) or in the shared prep worker pool
+                # (byte-identical transform, pinned by test_loader_workers),
+                # so the device hot path never waits on a resize
+                prepared, im_info = self._pool.prepare(np.asarray(image),
+                                                       self._scale)
+            else:
+                prepared, im_info = prepare_image(np.asarray(image),
+                                                  self.cfg, self._scale)
+        prep_s = prep.seconds
         tel.observe("serve/host_prep", prep_s)
         orig_hw = (int(image.shape[0]), int(image.shape[1]))
         staged = staged_hw = None
@@ -750,12 +799,7 @@ class ServeEngine:
         with self._cond:
             if self._stop:
                 return None, None
-            if now is None:
-                now = time.monotonic()
-            expired = self._sweep_expired_locked(now)
-            batch, wait = self._next_batch_locked(now)
-            if batch is not None:
-                self._inflight += 1
+            expired, batch, wait = self._claim_locked(now)
         self._fail_expired(expired)
         return batch, wait
 
@@ -774,19 +818,34 @@ class ServeEngine:
                 self._inflight -= 1
                 self._cond.notify_all()  # drain() waits on this
 
+    def _claim_locked(self, now: Optional[float] = None):
+        """``(expired, batch, wait_s)`` as of ``now``: sweeps the deadlines
+        and pops one due bucket's flush, which takes an inflight slot."""
+        if now is None:
+            now = time.monotonic()
+        expired = self._sweep_expired_locked(now)
+        batch, wait = self._next_batch_locked(now)
+        if batch is not None:
+            self._inflight += 1
+        return expired, batch, wait
+
     def _dispatch_loop(self):
         while True:
             with self._cond:
                 if self._stop:
                     return
-                now = time.monotonic()
-                expired = self._sweep_expired_locked(now)
-                batch, wait = self._next_batch_locked(now)
-                if batch is not None:
-                    self._inflight += 1
+                expired, batch, wait = self._claim_locked()
                 if batch is None and not expired:
-                    self._cond.wait(timeout=wait)
-                    continue
+                    # nothing is due: the device idles for want of work,
+                    # not because the host is slow.  ONE span a period,
+                    # however often a submit wakes the wait, or the
+                    # timeline would show an idle period in pieces
+                    with self._stage("serve/idle"):
+                        while batch is None and not expired:
+                            self._cond.wait(timeout=wait)
+                            if self._stop:
+                                return
+                            expired, batch, wait = self._claim_locked()
             self._fail_expired(expired)
             if batch is not None:
                 self.dispatch_batch(batch)
@@ -804,21 +863,22 @@ class ServeEngine:
             tel.observe("serve/queue_wait", now - r.t_enqueue)
         # pad partial batches with repeats (the TestLoader recipe); the
         # padded rows never reach a response
-        images = np.stack([r.image for r in reqs]
-                          + [reqs[-1].image] * pad)
-        im_info = np.stack([r.im_info for r in reqs]
-                           + [reqs[-1].im_info] * pad)
+        with self._stage("serve/assemble"):
+            images = np.stack([r.image for r in reqs]
+                              + [reqs[-1].image] * pad)
+            im_info = np.stack([r.im_info for r in reqs]
+                               + [reqs[-1].im_info] * pad)
         tel.gauge("serve/batch_fill", len(reqs) / B)
         tel.gauge("serve/pad_ratio", pad / B)
-        # distributed tracing: tracing-off costs exactly this ONE
-        # attribute check per batch (the capture contract) — phases stay
-        # None and every trace branch below is a no-op
+        # each stage is timed once (telemetry.stage); the batch's phase
+        # seconds come back for the tracer, which costs exactly ONE
+        # attribute check per batch when tracing is off (the capture
+        # contract)
         tracer = tracectx.get()
-        phases = {} if tracer.enabled else None
         if self.opts.serve_e2e:
-            xfer = self._forward_e2e(reqs, images, im_info, tel, phases)
+            xfer, phases = self._forward_e2e(reqs, images, im_info, tel)
         else:
-            xfer = self._forward_legacy(reqs, images, im_info, tel, phases)
+            xfer, phases = self._forward_legacy(reqs, images, im_info, tel)
         # latency distributions: service time once per batch, end-to-end
         # request time once per request (global + per-bucket family) —
         # into the engine's own Hists AND the active sink, so the SLO
@@ -854,6 +914,9 @@ class ServeEngine:
                 self.counters[k] = self.counters.get(k, 0) + v
         tel.counter("serve/batches")
         tel.counter("serve/images", len(reqs))
+        tel.counter("serve/post_kept", xfer["post_kept"])
+        if "post_candidates" in xfer:
+            tel.counter("serve/post_candidates", xfer["post_candidates"])
         if stream_frames:
             tel.counter("stream/batches")
             tel.counter("stream/batch_frames", stream_frames)
@@ -926,6 +989,10 @@ class ServeEngine:
                 if d is not None:
                     tracer.record(pctx, f"engine/{ph}", d)
 
+    def _stage(self, name: str, annotate: bool = True) -> telemetry.stage:
+        """One use of a stage clock that observes into ``hists[name]``."""
+        return telemetry.stage(name, self.hists[name], annotate)
+
     def _note_first_dispatch(self, shape, kind: str, tel) -> bool:
         """First-seen accounting for one batch's program (registry when
         the predictor carries one, local shape set otherwise) + the
@@ -948,58 +1015,71 @@ class ServeEngine:
         return first
 
     def _forward_legacy(self, reqs: List[_Request], images, im_info,
-                        tel, phases: Optional[dict] = None) -> dict:
+                        tel) -> Tuple[dict, dict]:
         """PR-3 path: host-prepped batch in, full score/delta readback,
-        host decode + per-class NMS.  Returns the batch's boundary-
-        crossing counter increments (two h2d arrays — images and im_info
-        ship separately into the jit call — one dispatch, one fat
-        readback).  ``phases`` (tracing on only) collects per-phase wall
-        durations for the engine's dispatch sub-spans."""
+        host decode + per-class NMS.  Returns the batch's counter
+        increments (two h2d arrays — images and im_info ship separately
+        into the jit call — one dispatch, one fat readback, the
+        post-process's candidates and records) and its phase seconds for
+        the engine's dispatch sub-spans."""
         import jax
 
         shape = tuple(images.shape)
         first = self._note_first_dispatch(shape, "serve_predict", tel)
-        t_fwd = time.monotonic()
-        t_ph = time.perf_counter() if phases is not None else 0.0
-        with tel.span("serve/forward"):
+        # until predict returns: the h2d of the batch + the enqueue
+        with self._stage("serve/forward") as fwd:
             rois, roi_valid, cls_prob, bbox_deltas, _ = \
                 self.predictor.predict(images, im_info)
-        if phases is not None:
-            t_now = time.perf_counter()
-            phases["forward"] = t_now - t_ph
-            t_ph = t_now
-        with tel.span("serve/readback"):
+        # the wait for the device + d2h
+        with self._stage("serve/readback") as rb:
             rois, roi_valid, cls_prob, bbox_deltas = jax.device_get(
                 (rois, roi_valid, cls_prob, bbox_deltas))
-        if phases is not None:
-            t_now = time.perf_counter()
-            phases["readback"] = t_now - t_ph
-            t_ph = t_now
         if first and self.registry is not None:
             # first dispatch of a shape = its compile: the forward +
             # readback wall is the compile(+first run) cost this program
             # would charge a cold user request
             self.predictor.record_compile_seconds(
-                shape, time.monotonic() - t_fwd)
+                shape, fwd.seconds + rb.seconds)
         cfg = self.cfg
-        with tel.span("serve/postprocess"):
+        # per image on the timeline, summed into one observation a batch
+        decode = telemetry.stage("serve/post/decode")
+        nms = telemetry.stage("serve/post/nms")
+        records = telemetry.stage("serve/post/records")  # timeline only
+        kept = 0
+        with self._stage("serve/postprocess", annotate=False) as post:
             for b, r in enumerate(reqs):
-                boxes = decode_image_boxes(rois[b], bbox_deltas[b],
-                                           np.asarray(r.im_info))
-                dets_pc = per_class_nms(cls_prob[b], boxes, roi_valid[b],
-                                        cfg.NUM_CLASSES, cfg.TEST.THRESH,
-                                        cfg.TEST.NMS,
-                                        cfg.TEST.MAX_PER_IMAGE)
-                r.future._set_result(detections_to_records(dets_pc))
-        if phases is not None:
-            phases["postprocess"] = time.perf_counter() - t_ph
+                with decode:
+                    boxes = decode_image_boxes(rois[b], bbox_deltas[b],
+                                               np.asarray(r.im_info))
+                with nms:
+                    dets_pc = per_class_nms(cls_prob[b], boxes, roi_valid[b],
+                                            cfg.NUM_CLASSES, cfg.TEST.THRESH,
+                                            cfg.TEST.NMS,
+                                            cfg.TEST.MAX_PER_IMAGE)
+                with records:
+                    recs = detections_to_records(dets_pc)
+                    kept += len(recs)
+                    r.future._set_result(recs)
+        for st in (decode, nms):
+            st.book(self.hists[st.name])
+        # the boxes over the threshold that went into the per-class NMS
+        # (per_class_nms's ``sel``, all classes and images at once)
+        n = len(reqs)
+        candidates = int(np.count_nonzero(
+            (cls_prob[:n, :, 1:cfg.NUM_CLASSES] > cfg.TEST.THRESH)
+            & np.asarray(roi_valid[:n], bool)[:, :, None]))
         nbytes = int(sum(np.asarray(a).nbytes for a in
                          (rois, roi_valid, cls_prob, bbox_deltas)))
-        return {"h2d_transfers": 2, "dispatches": 1, "readbacks": 1,
-                "readback_bytes": nbytes}
+        return ({"h2d_transfers": 2, "dispatches": 1, "readbacks": 1,
+                 "readback_bytes": nbytes,
+                 "h2d_bytes": int(images.nbytes + im_info.nbytes),
+                 "post_candidates": candidates,
+                 "post_kept": kept},
+                {"forward": fwd.seconds, "readback": rb.seconds,
+                 "postprocess": post.seconds})
 
     def _forward_e2e(self, reqs: List[_Request], staged, im_info,
-                     tel, phases: Optional[dict] = None) -> dict:
+                     tel) -> Tuple[dict, dict]:
         """Single-dispatch path (``--serve-e2e``): ONE ``device_put`` of
         the staged uint8 batch + its sidecars, ONE fused
         prep → forward → decode+NMS dispatch (registry kind
@@ -1022,48 +1102,41 @@ class ServeEngine:
         th = float(cfg.TEST.THRESH)
         shape = tuple(staged.shape) + (f"mpi={mpi}", f"th={th:g}")
         first = self._note_first_dispatch(shape, "serve_e2e", tel)
-        t_fwd = time.monotonic()
-        t_ph = time.perf_counter() if phases is not None else 0.0
-        with tel.span("serve/h2d"):
+        host_args = (staged, raw_hw, ratio,
+                     np.asarray(im_info, np.float32), flip)
+        with self._stage("serve/h2d") as h2d:
             # the one host→device transfer: a single put of the argument
             # tuple whose only large buffer is the staged uint8 batch
-            args = jax.device_put((staged, raw_hw, ratio,
-                                   np.asarray(im_info, np.float32), flip))
-        if phases is not None:
-            t_now = time.perf_counter()
-            phases["h2d"] = t_now - t_ph
-            t_ph = t_now
-        with tel.span("serve/forward"):
+            args = jax.device_put(host_args)
+        with self._stage("serve/forward") as fwd:
             dets, dvalid = self.predictor.predict_serve_e2e(*args, mpi, th)
-        if phases is not None:
-            t_now = time.perf_counter()
-            phases["forward"] = t_now - t_ph
-            t_ph = t_now
         if self.cascade is not None:
             # on-device confidence gate: fold the (B, cap, 6) detections
             # into per-image hardness while they are STILL device arrays —
             # the gate consumes tensors already on device and reads back
             # (B,) floats, adding zero h2d transfers to the batch
             self.cascade.gate_batch(dets, dvalid, reqs)
-        with tel.span("serve/readback"):
+        with self._stage("serve/readback") as rb:
             dets, dvalid = jax.device_get((dets, dvalid))
-        if phases is not None:
-            t_now = time.perf_counter()
-            phases["readback"] = t_now - t_ph
-            t_ph = t_now
         if first and self.registry is not None:
             self.predictor.record_compile_seconds(
-                shape, time.monotonic() - t_fwd, kind="serve_e2e")
-        with tel.span("serve/postprocess"):
+                shape, h2d.seconds + fwd.seconds + rb.seconds,
+                kind="serve_e2e")
+        kept = 0
+        with self._stage("serve/postprocess") as post:
             for b, r in enumerate(reqs):
                 dets_pc = device_dets_to_per_class(dets[b], dvalid[b],
                                                    cfg.NUM_CLASSES)
-                r.future._set_result(detections_to_records(dets_pc))
-        if phases is not None:
-            phases["postprocess"] = time.perf_counter() - t_ph
+                recs = detections_to_records(dets_pc)
+                kept += len(recs)
+                r.future._set_result(recs)
         nbytes = int(np.asarray(dets).nbytes + np.asarray(dvalid).nbytes)
-        return {"h2d_transfers": 1, "dispatches": 1, "readbacks": 1,
-                "readback_bytes": nbytes}
+        return ({"h2d_transfers": 1, "dispatches": 1, "readbacks": 1,
+                 "readback_bytes": nbytes,
+                 "h2d_bytes": int(sum(a.nbytes for a in host_args)),
+                 "post_kept": kept},
+                {"h2d": h2d.seconds, "forward": fwd.seconds,
+                 "readback": rb.seconds, "postprocess": post.seconds})
 
     # -- introspection ---------------------------------------------------
 
@@ -1099,6 +1172,14 @@ class ServeEngine:
                 if v is not None:
                     latency[f"{short}_{tag}"] = round(v * 1e3, 3)
         out["latency"] = latency
+        # monotone clocks: (after - before) of two snapshots is a window's
+        out["stages"] = {}
+        for name, h in self.hists.items():
+            doc = h.to_dict()  # count and sum under the Hist's own lock
+            out["stages"][name] = {"count": doc["count"],
+                                   "sum_s": doc["sum"]}
+        out["setup"] = dict(self.setup)
+        out["t_s"] = time.monotonic()
         out["policy"] = self.policy()
         out["dtype"] = self._dtype
         if self.capture.enabled:

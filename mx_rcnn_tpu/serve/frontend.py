@@ -40,7 +40,11 @@ Endpoints:
   the server was built with a ``reloader`` callback; 404 otherwise).
   Body is a reload target doc; 200 → new generation live, 409 → load or
   canary failure, previous weights restored.
-* ``GET /metrics`` — engine counters + queue state as JSON; with
+* ``GET /metrics`` — engine counters + queue state as JSON (with the
+  cumulative clock of every stage under ``"stages"`` — this module's own
+  are ``frontend/read`` and ``frontend/decode``, one observation a
+  ``/predict`` request and a profiler annotation each; ``frontend/reply``
+  is an annotation only); with
   ``Accept: text/plain`` or ``?format=prom``, Prometheus text exposition
   instead — rendered by ``telemetry/obs.py`` from the same registry the
   ``--obs-port`` server scrapes (one metrics path, not two).
@@ -63,6 +67,7 @@ from typing import Optional
 
 import numpy as np
 
+from mx_rcnn_tpu import telemetry
 from mx_rcnn_tpu.logger import logger
 from mx_rcnn_tpu.serve.engine import (DeadlineExceededError, RejectedError,
                                       ServeEngine)
@@ -156,7 +161,9 @@ def handle_request_doc(engine: ServeEngine, doc: dict,
     ``"trace"`` response key ONLY when the client sent one or tracing is
     on, so a tracing-off ``/predict`` stays byte-for-byte."""
     try:
-        img = decode_image_payload(doc)
+        with telemetry.stage("frontend/decode",
+                             engine.hists["frontend/decode"]):
+            img = decode_image_payload(doc)
     except (ValueError, TypeError, KeyError) as e:
         return 400, {"error": str(e)}
     tracer = tracectx.get()
@@ -466,8 +473,10 @@ class _Handler(BaseHTTPRequestHandler):
             self._reply_raw(200, payload.encode(), "application/x-ndjson")
             return
         try:
-            length = int(self.headers.get("Content-Length", 0))
-            doc = json.loads(self.rfile.read(length) or b"{}")
+            # observed below, once the body has said which engine it is for
+            with telemetry.stage("frontend/read") as read:
+                length = int(self.headers.get("Content-Length", 0))
+                doc = json.loads(self.rfile.read(length) or b"{}")
         except (ValueError, json.JSONDecodeError) as e:
             self._reply(400, {"error": f"bad JSON body: {e}"})
             return
@@ -493,10 +502,12 @@ class _Handler(BaseHTTPRequestHandler):
                 m = doc.get("model")
                 if isinstance(m, str) and m:
                     mid = m
+        read.book(engine.hists["frontend/read"])
         status, resp = handle_request_doc(
             engine, doc, trace_header=self.headers.get(TRACE_HEADER),
             cascade=self.cascade, model_id=mid)
-        self._reply(status, resp)
+        with telemetry.stage("frontend/reply"):  # on the timeline only
+            self._reply(status, resp)
         if self.request_hook is not None:
             self.request_hook(status)
 

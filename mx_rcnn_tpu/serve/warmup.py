@@ -22,11 +22,10 @@ the AOT win lands: a second boot over a warm cache dir reports
 
 from __future__ import annotations
 
-import time
-
 import numpy as np
 
 from mx_rcnn_tpu import telemetry
+from mx_rcnn_tpu.compile.registry import xla_counters
 from mx_rcnn_tpu.logger import logger
 
 
@@ -48,17 +47,24 @@ def warmup(engine) -> int:
     assert engine._thread is not None or engine._external, \
         "start() the engine before warmup"
     short, long_ = engine._scale
-    t0 = time.perf_counter()
     reg = getattr(engine, "registry", None)
     before = engine.counters["recompiles"]
     aot_before = (dict(reg.counters) if reg is not None else {})
-    for h, w in ((short, long_), (long_, short)):  # landscape, portrait
-        dummy = np.zeros((h, w, 3), np.uint8)
-        futs = [engine.submit(dummy, deadline_ms=0)  # never expire
-                for _ in range(engine.opts.batch_size)]
-        for f in futs:
-            f.result(timeout=600.0)
-    dt = time.perf_counter() - t0
+    xla_before = xla_counters()
+    with telemetry.stage("setup/warmup") as whole:
+        for h, w in ((short, long_), (long_, short)):  # landscape, portrait
+            dummy = np.zeros((h, w, 3), np.uint8)
+            futs = [engine.submit(dummy, deadline_ms=0)  # never expire
+                    for _ in range(engine.opts.batch_size)]
+            for f in futs:
+                f.result(timeout=600.0)
+        # a future resolves before the dispatcher has booked its batch:
+        # wait for that too, so that the counters read here, and by
+        # whoever asks once warm-up has returned, hold every warm-up batch
+        with engine._cond:
+            while engine._inflight:
+                engine._cond.wait(timeout=0.05)
+    dt = engine.setup["warmup_s"] = whole.seconds
     compiled = engine.counters["recompiles"] - before
     engine.counters["warmup_programs"] += compiled
     # warmup completion IS readiness: /readyz flips to 200 here, so a
@@ -70,9 +76,17 @@ def warmup(engine) -> int:
     if reg is not None:
         hits = reg.counters["aot_hit"] - aot_before.get("aot_hit", 0)
         misses = reg.counters["aot_miss"] - aot_before.get("aot_miss", 0)
+        # the markers' forecast beside what XLA did meanwhile, for every
+        # program of the process (the one-op ones too)
+        xla = {k: v - xla_before[k] for k, v in xla_counters().items()}
         logger.info("serve warmup: %d program(s) ready in %.1fs — "
-                    "%d AOT cache hit(s), %d compile(s) (batch=%d, "
+                    "forecast %d AOT cache hit(s), %d compile(s); XLA "
+                    "built %d program(s) in %.1fs, %d of them loaded from "
+                    "the persistent cache, %d missed it (batch=%d, "
                     "scale=%s, dtype=%s)", compiled, dt, hits, misses,
+                    xla["xla_compiles"], xla["xla_compile_s"],
+                    xla["persistent_cache_hits"],
+                    xla["persistent_cache_misses"],
                     engine.opts.batch_size, engine._scale,
                     getattr(engine, "_dtype", "float32"))
     else:

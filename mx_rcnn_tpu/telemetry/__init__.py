@@ -27,6 +27,8 @@ back into the human table and BENCH-compatible rows.
 
 from __future__ import annotations
 
+import sys
+import time
 from typing import Optional
 
 from mx_rcnn_tpu.telemetry.sink import (HIST_LE, NULL, RING_SIZE,
@@ -37,7 +39,7 @@ from mx_rcnn_tpu.telemetry.sink import (HIST_LE, NULL, RING_SIZE,
 __all__ = ["Telemetry", "NullTelemetry", "NULL", "RING_SIZE",
            "SCHEMA_VERSION", "SUMMARY_NAME", "Hist", "HIST_LE",
            "quantile_from_counts", "configure", "get", "reset_null",
-           "shutdown"]
+           "shutdown", "stage"]
 
 _active: "NullTelemetry | Telemetry" = NULL
 
@@ -63,6 +65,71 @@ def configure(out_dir: str, rank: int = 0, world: int = 1,
 def get() -> "NullTelemetry | Telemetry":
     """The active sink (the no-op :data:`NULL` when none is configured)."""
     return _active
+
+
+class stage:
+    """One timed stage: ``with telemetry.stage("serve/forward", hist) as st``.
+
+    Every use (the object may be entered again: one per batch, entered per
+    image) opens a ``jax.profiler.TraceAnnotation(name)`` — so the span lies
+    on the profiler's own clock beside the device's ops, at a few hundred ns
+    when no profile is being taken — takes ``perf_counter()`` at entry and
+    exit, and adds the duration to ``seconds`` (the total over ``uses``),
+    for callers that hand the time on (tracectx phases, compile seconds).
+
+    With a ``hist`` each use is booked as it ends: one observation into the
+    hist and, when a sink is on, one span into it (``add``, what
+    ``tel.span`` did — in trace mode with the wall-clock start).  Without
+    one nothing is booked: the owner calls :meth:`book` where the hist is
+    known only later or the stage is summed over a batch's images, or
+    leaves it at the annotation and the clock.
+
+    jax is never imported here: the annotation is entered only where the
+    process already has it (``"jax" in sys.modules``).  ``annotate=False``
+    keeps the clocks and leaves the timeline alone — for a stage whose
+    parts are annotated themselves: an enclosing event would cover every
+    idle gap and name none of them."""
+
+    __slots__ = ("name", "hist", "annotate", "seconds", "uses", "_ann",
+                 "_t0", "_w0")
+
+    def __init__(self, name: str, hist: Optional[Hist] = None,
+                 annotate: bool = True):
+        self.name = name
+        self.hist = hist
+        self.annotate = annotate
+        self.seconds = 0.0
+        self.uses = 0
+
+    def __enter__(self) -> "stage":
+        jax = sys.modules.get("jax") if self.annotate else None
+        profiler = getattr(jax, "profiler", None)
+        self._ann = (profiler.TraceAnnotation(self.name)
+                     if profiler is not None else None)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._w0 = time.time() if _active.trace else None
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        dt = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self.seconds += dt
+        self.uses += 1
+        if self.hist is not None:
+            self.hist.observe(dt)
+            if _active.enabled:
+                _active.add(self.name, dt, ts=self._w0)
+        return False
+
+    def book(self, hist: Hist) -> None:
+        """All uses so far as ONE observation into ``hist`` and one span
+        record (``n`` = the uses) into the sink when it is on."""
+        hist.observe(self.seconds)
+        if _active.enabled:
+            _active.add(self.name, self.seconds, n=self.uses)
 
 
 def reset_null():

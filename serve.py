@@ -473,13 +473,17 @@ def _build_engine(args, cfg, external: bool = False):
     from mx_rcnn_tpu.tools.common import calibrate_from_args
 
     apply_program_cache(args)  # before the Predictor builds its registry
-    model = build_model(cfg)
-    params = eval_params_from_args(args, cfg, model)
+    # start-up's split: each phase timed once, onto /metrics' "setup"
+    with telemetry.stage("setup/model") as t_model:
+        model = build_model(cfg)
+    with telemetry.stage("setup/params") as t_params:
+        params = eval_params_from_args(args, cfg, model)
     # --calibrate-shard: activation scales from the FLOAT params, persisted
     # next to the AOT markers BEFORE the Predictor quantizes its copy
     act_scales = calibrate_from_args(args, cfg, model, params)
-    predictor = Predictor(model, params, cfg, dtype=args.infer_dtype,
-                          act_scales=act_scales)
+    with telemetry.stage("setup/predictor") as t_predictor:
+        predictor = Predictor(model, params, cfg, dtype=args.infer_dtype,
+                              act_scales=act_scales)
     engine = ServeEngine(predictor, cfg, ServeOptions(
         batch_size=args.serve_batch, max_delay_ms=args.max_delay_ms,
         max_queue=args.max_queue, deadline_ms=args.deadline_ms,
@@ -496,6 +500,8 @@ def _build_engine(args, cfg, external: bool = False):
             shard_records=args.capture_shard_records,
             byte_budget=args.capture_bytes,
             member=getattr(args, "capture_member", None)))
+    engine.setup.update(model_s=t_model.seconds, params_s=t_params.seconds,
+                        predictor_s=t_predictor.seconds)
     engine.start(external=external)
     return predictor, engine
 

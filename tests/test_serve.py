@@ -374,6 +374,10 @@ def test_serve_e2e_fused_single_dispatch_contract():
         base = dict(engine.counters)
         futs = [engine.submit(img) for img in (land_a, land_b)]
         got = [f.result(timeout=300) for f in futs]
+        # a future resolves before the dispatcher books its batch: wait
+        # for the quiescent point before reading the counters
+        assert engine.drain(timeout=30)
+        engine.resume()
         delta = {k: engine.counters[k] - base[k]
                  for k in ("h2d_transfers", "dispatches", "readbacks",
                            "batches")}
