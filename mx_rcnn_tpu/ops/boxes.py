@@ -7,6 +7,15 @@ jax.numpy functions.  The legacy "+1" width convention (w = x2 - x1 + 1) is
 preserved throughout for numeric parity.
 
 All functions are shape-polymorphic over leading dims and safe under jit.
+
+This is the IN-GRAPH version: everything traced calls it (the proposal
+layer, target assignment in training, ``ops.postprocess.device_postprocess``),
+often with numpy constants beside tracers, so it stays ``jnp`` throughout
+and does not look at what it is handed.  Host code that holds numpy arrays
+a readback has already brought back must not call it — each op would run as
+its own program on the device, with a transfer each way.  The host twin of
+``bbox_pred`` + ``clip_boxes`` is ``ops.postprocess.decode_image_boxes``
+(numpy only); ``tests/test_postprocess.py`` ties the two.
 """
 
 from __future__ import annotations

@@ -51,10 +51,12 @@ threads' line beside the device's ops.  Dispatcher thread, flat siblings
 with no enclosing annotation: ``serve/idle`` (the wait when nothing is
 due), ``serve/assemble``, ``serve/forward`` (h2d + enqueue),
 ``serve/readback`` (the wait for the device + d2h), per image
-``serve/post/decode`` / ``serve/post/nms`` (their ``Hist``s observe once a
-batch, summed over its images) and ``serve/post/records`` (on the timeline
-only); ``serve/postprocess`` (the whole loop) and ``serve/service_time``
-(the whole turn) are clocks without an annotation; ``serve/h2d`` exists in
+``serve/post/decode`` (``decode_image_boxes`` on the real requests' rows:
+numpy on the arrays the readback brought, no device array and no program)
+/ ``serve/post/nms`` (their ``Hist``s observe once a batch, summed over its
+images) and ``serve/post/records`` (on the timeline only);
+``serve/postprocess`` (the whole loop) and ``serve/service_time`` (the whole
+turn) are clocks without an annotation; ``serve/h2d`` exists in
 ``serve_e2e`` mode.  Request threads: ``frontend/read``,
 ``frontend/decode``, ``serve/host_prep``, and ``frontend/reply`` (on the
 timeline only).  Start-up: ``setup/model`` / ``setup/params`` /
