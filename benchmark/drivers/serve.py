@@ -6,9 +6,13 @@ every size and rate come from the traffic file.
 
 Nothing of the program is changed.  One seam is used: the server takes its
 weights from ``serve.eval_params_from_args``, and this driver puts the
-benchmark's weights (``benchmark.weights``, from ``--seed``) behind that
-name for the run, after checking that the program's tree has exactly the
-reference's leaves.
+benchmark's weights (the configuration's ``weights`` module, from
+``--seed``) behind that name for the run, after checking that the program's
+tree has exactly the reference's leaves.
+
+What belongs to one architecture — weights, plain reference, comparison,
+operation counts — this driver takes from ``harness.modules_of(config)``
+and knows by no other name (README, "A configuration").
 """
 
 from __future__ import annotations
@@ -24,7 +28,8 @@ import tempfile
 import threading
 import time
 
-from benchmark import compare, flops, harness, loadgen, weights, xplane
+from benchmark import harness, loadgen, xplane
+from benchmark.weights import as_tree, check_against
 
 TRACE_AT = 0.3          # the traced stretch starts this far into the window
 TRACE_SECONDS = 4.0     # and lasts this long (or 40 % of a short window)
@@ -118,12 +123,14 @@ class Conductor(threading.Thread):
                     tracer.start()
             elif doc["event"] == "result":
                 self.out["result"] = doc
+                # the second snapshot now, window + drain after the first:
+                # not after the child's exit and the tracer's join
+                self.out["metrics_after"] = _get_metrics(self.sock)
         rc = self.child.wait()
         if tracer is not None:
             tracer.join()
         if rc != 0 or "result" not in self.out:
             raise RuntimeError(f"load generator exited {rc} without a result")
-        self.out["metrics_after"] = _get_metrics(self.sock)
         self.out["memory_peak_bytes"] = harness.memory_peak_bytes(self.chips)
 
 
@@ -141,8 +148,8 @@ def serve_window(spec: dict, seed: int, seconds: float, trace: bool,
     def benchmark_params(args, cfg, model):
         shapes = jax.eval_shape(
             lambda: init_params(model, cfg, jax.random.PRNGKey(0)))
-        weights.check_against(flat, shapes)
-        return weights.as_tree(flat)
+        check_against(flat, shapes)
+        return as_tree(flat)
 
     trace_dir = None
     if trace:
@@ -180,24 +187,84 @@ def serve_window(spec: dict, seed: int, seconds: float, trace: bool,
     return out
 
 
-def reference_dense(flat: dict, sample: list, net: dict) -> list:
-    from benchmark.reference import frcnn_c4
+class Warmer(Conductor):
+    """The conductor of the pre-compile child: no window.  Once the server
+    is ready (both programs built and warmed) the generator's own warm
+    requests, so that whatever a first request builds is in the cache too;
+    then the stop."""
 
-    return [frcnn_c4.detect(flat, s["doc"], net) for s in sample]
+    def _drive(self):
+        traffic = self.spec["traffic"]
+        mix = traffic["bodies"]
+        loadgen.wait_ready(self.sock, 1000.0)
+        loadgen.warm(self.sock, loadgen.make_bodies(mix, self.spec["seed"]),
+                     loadgen.body_sizes(mix), int(self.spec["num_classes"]),
+                     int(traffic.get("warm_per_orientation", 16)), 600.0)
+        self.out["t0"] = time.monotonic()
+
+
+def precompile(spec: dict, seed: int) -> None:
+    """Everything a run of this cell builds before its window, built once
+    and left in the persistent cache: the weights' program and the server's
+    (``run.py --precompile``, a child of the first run in a checkout)."""
+    config = spec["config"]
+    flat = harness.modules_of(config)["weights"].make(config["net"], seed)
+    serve_window(spec, seed, 0.0, False, flat, time.monotonic(),
+                 conductor=Warmer)
+
+
+def after_window(mods: dict, config: dict, flat: dict, res: dict,
+                 metrics: dict, device: dict, breakdown=None):
+    """What decides ``correct``, once the window has closed and the server
+    is gone: the configuration's reference over each sampled request, its
+    comparison over the sample's whole responses, its judgement against the
+    configuration's limits -> (the result's last line, {name: (value,
+    limit)})."""
+    net = config["net"]
+    t_ref = time.monotonic()
+    dense = [mods["reference"].detect(flat, s["doc"], net)
+             for s in res["sample"]]
+    numbers = mods["compare"].compare(res["sample"], dense, net)
+    print(f"reference: {len(dense)} requests in "
+          f"{time.monotonic() - t_ref:.1f} s", file=sys.stderr)
+    ok, compared = mods["compare"].judge(numbers, config["correct"])
+    line = harness.result_line(ok, res["attempted"], res["failed"], metrics,
+                               device, {k: {"value": v, "limit": l}
+                                        for k, (v, l) in compared.items()},
+                               breakdown)
+    return line, compared
+
+
+def stage_means(before: dict, after: dict) -> dict:
+    """{stage: [observations, ms an observation]} between two ``/metrics``
+    snapshots, for every stage clock the program keeps: a line on standard
+    error in every run, traced or not, for whoever reads a set's spread."""
+    out = {}
+    zero = {"count": 0, "sum_s": 0.0}
+    b = before.get("stages") or {}
+    for name, a in sorted((after.get("stages") or {}).items()):
+        n = a["count"] - b.get(name, zero)["count"]
+        if n > 0:
+            out[name] = [n, round(1e3 * (a["sum_s"] - b.get(name, zero)[
+                "sum_s"]) / n, 3)]
+    return out
 
 
 def run(spec: dict, seed: int, seconds: float, trace: bool, device: dict,
         t_start: float):
     """-> (the result's last line, {name: (value, limit)})."""
     config, cell, bench = spec["config"], spec["cell"], spec["bench"]
-    net = config["net"]
-    flat = weights.make(net, seed)
+    mods = harness.modules_of(config)
+    flat = mods["weights"].make(config["net"], seed)
     out = serve_window(spec, seed, seconds, trace, flat, t_start)
     res = out["result"]
     print(f"window: {json.dumps({k: v for k, v in res.items() if k != 'sample'})}",
           file=sys.stderr)
     print(f"setup_s {out['setup_s']:.3f}  memory_peak_bytes "
           f"{out['memory_peak_bytes']}", file=sys.stderr)
+    print("stages: " + json.dumps(stage_means(out["metrics_before"],
+                                              out["metrics_after"])),
+          file=sys.stderr)
     device = dict(device, memory_peak_bytes=out["memory_peak_bytes"])
 
     breakdown = None
@@ -213,7 +280,8 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, device: dict,
                "traffic": spec["traffic"], "cell": cell,
                "peaks": harness.peaks_for(device["kind"]),
                "metrics_before": out["metrics_before"],
-               "metrics_after": out["metrics_after"], "flops": flops}
+               "metrics_after": out["metrics_after"],
+               "flops": mods["flops"]}
         metrics = harness.read_layers(bench, cell["name"], ctx)
     else:
         metrics = {}
@@ -224,14 +292,4 @@ def run(spec: dict, seed: int, seconds: float, trace: bool, device: dict,
     # the program's state is gone with serve.main; what is left on the chip
     # is the benchmark's weights.  Now the reference, request by request.
     gc.collect()
-    t_ref = time.monotonic()
-    dense = reference_dense(flat, res["sample"], net)
-    numbers = compare.compare(res["sample"], dense, net)
-    print(f"reference: {len(dense)} requests in "
-          f"{time.monotonic() - t_ref:.1f} s", file=sys.stderr)
-    ok, compared = compare.judge(numbers, config["correct"])
-    line = harness.result_line(ok, res["attempted"], res["failed"], metrics,
-                               device, {k: {"value": v, "limit": l}
-                                        for k, (v, l) in compared.items()},
-                               breakdown)
-    return line, compared
+    return after_window(mods, config, flat, res, metrics, device, breakdown)

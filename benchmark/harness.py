@@ -5,9 +5,13 @@ name: those are files that ``BENCHMARK.json`` points at."""
 
 from __future__ import annotations
 
+import hashlib
+import importlib
+import importlib.metadata
 import importlib.util
 import json
 import os
+import subprocess
 import sys
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -37,6 +41,31 @@ def load_cell(workload: str, root: str = ROOT) -> dict:
             "traffic": traffic}
 
 
+MODULE_KEYS = ("weights", "reference", "compare", "flops", "control")
+
+
+def modules_of(config: dict) -> dict:
+    """The configuration's own modules, imported by the names its file
+    gives under ``modules`` (README, "A configuration"): {key: module} for
+    every key of ``MODULE_KEYS``.  A key that is missing, or a name that
+    does not import, is an error that names the key."""
+    names = config.get("modules")
+    if not isinstance(names, dict):
+        raise RuntimeError("the configuration has no 'modules' object; it "
+                           f"has to name {', '.join(MODULE_KEYS)}")
+    out = {}
+    for key in MODULE_KEYS:
+        if key not in names:
+            raise RuntimeError(f"the configuration's 'modules' lacks "
+                               f"{key!r}")
+        try:
+            out[key] = importlib.import_module(names[key])
+        except ImportError as e:
+            raise RuntimeError(f"the configuration's modules[{key!r}] = "
+                               f"{names[key]!r} does not import: {e}") from e
+    return out
+
+
 def metrics_of(bench: dict, group: str, workload: str) -> list:
     """The metrics of ``end_to_end`` / ``per_layer`` this cell reports."""
     return [m for m in bench[group]
@@ -58,6 +87,69 @@ def setup_compile_cache() -> str:
     # timestamp file (seen on the chip machine) stops every later write
     jax.config.update("jax_compilation_cache_max_size", -1)
     return CACHE_DIR
+
+
+def versions() -> dict:
+    """What compiles the programs, read without importing any of it."""
+    out = {}
+    for pkg in ("jax", "jaxlib", "libtpu"):
+        try:
+            out[pkg] = importlib.metadata.version(pkg)
+        except importlib.metadata.PackageNotFoundError:
+            out[pkg] = None
+    return out
+
+
+def programs_marker(spec: dict) -> str:
+    """The file in ``CACHE_DIR`` that says this checkout's cache holds the
+    cell's programs: keyed by the configuration as it is run (flags and
+    sizes), the driver and the jax / jaxlib / libtpu versions.  The checkout
+    path is in jax's own key, and the marker lies inside the cache it
+    speaks of, so the two are emptied together."""
+    key = json.dumps({"config": spec["config"],
+                      "kind": spec["traffic"]["kind"],
+                      "versions": versions()}, sort_keys=True)
+    return os.path.join(CACHE_DIR, "programs-"
+                        + hashlib.sha256(key.encode()).hexdigest()[:20]
+                        + ".json")
+
+
+def write_programs_marker(spec: dict) -> None:
+    path = programs_marker(spec)
+    os.makedirs(CACHE_DIR, exist_ok=True)
+    with open(path + ".tmp", "w") as f:
+        json.dump({"config": spec["cell"]["config"],
+                   "kind": spec["traffic"]["kind"],
+                   "versions": versions()}, f)
+    os.replace(path + ".tmp", path)
+
+
+def ensure_programs_cached(spec: dict, seed: int, spawn=subprocess.run,
+                           limit_s: float = 1000.0) -> bool:
+    """Before this process touches jax (the chip belongs to one process at a
+    time): where the marker is absent, build and warm the cell's programs
+    once in a child that exits — ``run.py --precompile``: the same server,
+    the same flags, no window — so that every measured window runs in a
+    process that loaded its programs from the cache.  -> whether a child
+    ran.  A child that fails ends this run with no result."""
+    marker = programs_marker(spec)
+    if os.path.exists(marker):
+        return False
+    argv = [sys.executable, os.path.join(HERE, "run.py"), "--workload",
+            spec["cell"]["name"], "--seed", str(seed), "--seconds", "0",
+            "--precompile"]
+    print(f"benchmark: no marker {os.path.basename(marker)} in {CACHE_DIR}; "
+          f"compiling in a child first", file=sys.stderr, flush=True)
+    try:
+        rc = spawn(argv, stdout=sys.stderr, cwd=ROOT,
+                   timeout=limit_s).returncode
+    except subprocess.TimeoutExpired:   # run() has killed and reaped it
+        rc = 124
+    if rc != 0 or not os.path.exists(marker):
+        print(f"benchmark: the pre-compile child exited {rc}; no result",
+              file=sys.stderr)
+        raise SystemExit(rc or 3)
+    return True
 
 
 def device_doc() -> dict:

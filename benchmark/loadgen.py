@@ -346,8 +346,12 @@ def main() -> int:
     out = summarize(reqs, t0, seconds, (seconds + drain_s) * 1e3, closed)
     pick = pick_sample(reqs, bodies, int(traffic.get("sample", 8)), seed)
     out["event"] = "result"
+    # each sampled request with its whole response document: a
+    # configuration's comparison may read more than the records' cls, score
+    # and bbox ("detections" is the response's list, by its old name)
     out["sample"] = [{"body": reqs[i].body,
                       "doc": json.loads(bodies[reqs[i].body]),
+                      "response": reqs[i].doc,
                       "detections": reqs[i].doc["detections"]} for i in pick]
     out["drained_s"] = time.monotonic() - (t0 + seconds)
     emit(out)

@@ -7,9 +7,11 @@
 One process, one set-up: the server of the cell's configuration is started
 once and the cell's traffic is offered at each rate in turn (a child
 generator a rate).  Prints one JSON line a rate.  The knee is the highest
-rate at which every request came back good and the p95 stayed in line with
-the rates below it; the cell's traffic file then fixes 0.8 of it.  Not part
-of a benchmark run.
+rate at which every request came back good, the completed rate equals the
+offered one and the tails stayed in line with the rates below it (just
+under the rate that sheds, every request is still answered while the
+backlog grows all through the window); the cell's traffic file then fixes
+0.8 of it.  Not part of a benchmark run.
 """
 
 from __future__ import annotations
@@ -34,13 +36,17 @@ def main(argv=None) -> int:
     ap.add_argument("--seconds", type=float, default=20.0)
     ap.add_argument("--rates", type=float, nargs="+", required=True)
     args = ap.parse_args(argv)
-    from benchmark import harness, weights
+    from benchmark import harness
     from benchmark.drivers import serve as drv
 
     spec = harness.load_cell(args.workload)
+    # as a run does: the sweep's server loads its programs from the cache (a
+    # process that compiled them serves 8-17 % faster, PERF.md section 6)
+    harness.ensure_programs_cached(spec, args.seed)
     harness.setup_compile_cache()
     harness.require_chips(spec["cell"]["chips"])
-    flat = weights.make(spec["config"]["net"], args.seed)
+    flat = harness.modules_of(spec["config"])["weights"].make(
+        spec["config"]["net"], args.seed)
 
     class Sweep(drv.Conductor):
         """A conductor that offers each rate in turn before the stop."""

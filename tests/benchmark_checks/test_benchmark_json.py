@@ -14,6 +14,12 @@ NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 BENCH = harness.load_json(os.path.join(harness.ROOT, "BENCHMARK.json"))
 ALL_METRICS = BENCH["end_to_end"] + BENCH["per_layer"]
+# benchmark/README.md, "A configuration": what each module must provide
+MODULE_FUNCTIONS = {
+    "weights": ("make",), "reference": ("detect",),
+    "compare": ("compare", "judge"),
+    "flops": ("predict_flops_per_image", "nms_work", "roofline_seconds"),
+    "control": ("control_numbers",)}
 
 
 def test_top_level_keys_and_limits():
@@ -54,9 +60,15 @@ def test_names_are_unique():
 @pytest.mark.parametrize("cell", BENCH["workloads"], ids=lambda w: w["name"])
 def test_cell_files_resolve(cell):
     spec = harness.load_cell(cell["name"])
-    assert spec["traffic"]["kind"] in ("serve", "train")
     assert os.path.exists(os.path.join(
         harness.HERE, "drivers", spec["traffic"]["kind"] + ".py"))
+    # the configuration's own modules import and offer what the README's
+    # table says the harness calls
+    mods = harness.modules_of(spec["config"])
+    assert set(mods) == set(MODULE_FUNCTIONS)
+    for key, functions in MODULE_FUNCTIONS.items():
+        for fn in functions:
+            assert callable(getattr(mods[key], fn, None)), (key, fn)
     assert cell["chips"] in (1, 4)
     assert len(spec["config"]["source"]) <= 200
     assert spec["config"]["reduced"] == next(
@@ -115,7 +127,8 @@ def test_readers_return_none_when_there_is_nothing_to_read():
                                             "recompiles": 2},
                                "options": {"batch_size": 16}},
              "peaks": harness.peaks_for("TPU v5 lite"),
-             "flops": __import__("benchmark.flops", fromlist=["x"])}
+             "flops": harness.modules_of(harness.load_cell(
+                 BENCH["workloads"][0]["name"])["config"])["flops"]}
     out = harness.read_layers(BENCH, BENCH["workloads"][0]["name"], empty)
     # nothing traced, nothing served: only the count of recompiles (0) reads
     assert set(out) == {"recompiles_in_window"}

@@ -14,8 +14,21 @@ LIMITS = {"records": 50, "box_gap": 0.009, "score_gap": 0.018,
           "nms_faults": 0}
 
 
-def tiny_spec(workload: str = "c4-serve-open") -> dict:
-    spec = copy.deepcopy(harness.load_cell(workload))
+def canned_window(records: list, extra: dict | None = None) -> dict:
+    """What the generator reports of a window, canned: three requests, all
+    answered, one of them sampled — its body, and its whole response
+    document (``records`` under ``detections`` + whatever ``extra`` adds)."""
+    response = dict({"detections": records, "queue_wait_ms": 1.0},
+                    **(extra or {}))
+    return {"event": "result", "attempted": 3, "failed": 0,
+            "status": {"200": 3}, "serve_imgs_per_s": 1.0,
+            "sample": [{"body": 0, "doc": {"shape": [2, 2, 3], "data": ""},
+                        "response": response, "detections": records}]}
+
+
+def tiny_spec(workload: str = "c4-serve-open",
+              root: str = harness.ROOT) -> dict:
+    spec = copy.deepcopy(harness.load_cell(workload, root=root))
     c = spec["config"]
     c["network"] = "resnet50"
     c["cfg"] = ["tpu__SCALES=((96,128),)", "TEST__RPN_PRE_NMS_TOP_N=300",
