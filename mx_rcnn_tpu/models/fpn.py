@@ -289,21 +289,29 @@ class FPNFasterRCNN(nn.Module):
         (eval runs mask chunks per batch without re-running the backbone)."""
         cfg = self.cfg
         te = cfg.TEST
-        feats = self._pyramid(images)
-        levels = self._rpn_over_levels(feats)
-        level_scores = [L.fg_prob(c) for c, _, _ in levels]
-        level_deltas = [b for _, b, _ in levels]
-        anchors_l = [a for _, _, a in levels]
-        rois, roi_scores, roi_valid = jax.vmap(
-            lambda ls, ld, info: propose_fpn(
-                list(ls), list(ld), anchors_l, info[0], info[1], info[2],
-                pre_nms_top_n=te.RPN_PRE_NMS_TOP_N,
-                post_nms_top_n=te.RPN_POST_NMS_TOP_N,
-                nms_thresh=te.RPN_NMS_THRESH, min_size=te.RPN_MIN_SIZE,
-                use_pallas=te.CXX_PROPOSAL),
-        )(tuple(level_scores), tuple(level_deltas), im_info)
-        cls_logits, bbox_deltas = self._box_head(feats, rois)
-        cls_prob = jax.nn.softmax(cls_logits, axis=-1)
+        # the five stages carry their names into the device trace's op
+        # metadata (fpn/neck holds the trunk too: _pyramid is one call)
+        with jax.named_scope("fpn/neck"):
+            feats = self._pyramid(images)
+        with jax.named_scope("fpn/rpn"):
+            levels = self._rpn_over_levels(feats)
+            level_scores = [L.fg_prob(c) for c, _, _ in levels]
+            level_deltas = [b for _, b, _ in levels]
+            anchors_l = [a for _, _, a in levels]
+        with jax.named_scope("fpn/propose"):
+            rois, roi_scores, roi_valid = jax.vmap(
+                lambda ls, ld, info: propose_fpn(
+                    list(ls), list(ld), anchors_l, info[0], info[1], info[2],
+                    pre_nms_top_n=te.RPN_PRE_NMS_TOP_N,
+                    post_nms_top_n=te.RPN_POST_NMS_TOP_N,
+                    nms_thresh=te.RPN_NMS_THRESH, min_size=te.RPN_MIN_SIZE,
+                    use_pallas=te.CXX_PROPOSAL),
+            )(tuple(level_scores), tuple(level_deltas), im_info)
+        with jax.named_scope("fpn/pool"):
+            pooled = self._pool_levels(feats, rois, pooled=7)
+        with jax.named_scope("fpn/box_head"):
+            cls_logits, bbox_deltas = self.rcnn_out(self.head_body(pooled))
+            cls_prob = jax.nn.softmax(cls_logits, axis=-1)
         return (rois, roi_valid, cls_prob, bbox_deltas, roi_scores), feats
 
     def masks_from_feats(self, feats, boxes, labels):

@@ -106,8 +106,15 @@ def _level_topk(scores: jnp.ndarray, k: int) -> jnp.ndarray:
     split.
     """
     n = scores.shape[0]
-    # largest split with whole rows no shorter than k (P2 @ 116736/k=2400
-    # → g=16; P3 @ 29184 → g=8; smaller levels fall back to argsort)
+    # largest split with whole rows no shorter than k.  Shapes that run:
+    # train at 608×1024, k=2400 — P2 @ 116736 → g=16, P3 @ 29184 → g=8,
+    # smaller levels fall back to argsort; the served test contract at
+    # 800×1344, k=1000 (benchmark cell fpn-serve-closed) — P2 @ 201600 →
+    # g=16 (rows of 12600), P3 @ 50400 → g=16 (3150), P4 @ 12600 → g=8
+    # (1575; 16 does not divide it), P5 @ 3150 → g=2 (1575), P6 @ 819 <
+    # k → the caller passes k=819 and the argsort branch takes it whole.
+    # All of them compile for a described v5e in tests/test_tpu_kernels.py
+    # and run on the chip in that cell.
     g = next((g for g in (16, 8, 4, 2) if n % g == 0 and n // g >= k), 1)
     if g == 1:
         return jnp.argsort(-scores)[:k]
@@ -167,8 +174,12 @@ def propose_fpn(
         #     k=2400 shape, not the fusion pass, so isolation cannot fix
         #     it.  (assign_anchor's top_k survives because its k=256 takes
         #     a different emitter path.)
-        # The argsort costs ~1.3 ms at P2; retry the ledger on libtpu/jax
-        # upgrades.
+        # Hence _level_topk's two stages (rows far below that shape; the
+        # argsort only for a level too small to split, ~1.3 ms at P2 when
+        # it was the whole fence).  Served at 800×1344 the levels hold
+        # 201600 / 50400 / 12600 / 3150 / 819 anchors at k_level=1000: P6
+        # has fewer than k, so k=819 there and the joint NMS below sees
+        # 4×1000 + 819 = 4819.  Retry the ledger on libtpu/jax upgrades.
         top_idx = _level_topk(scores, k)
         cand_boxes.append(boxes[top_idx])
         cand_scores.append(scores[top_idx])

@@ -68,7 +68,10 @@ timeline only).  Start-up: ``setup/model`` / ``setup/params`` /
 ``"t_s"`` (the server's own clock, for a rate between two scrapes), the
 counters ``post_candidates`` / ``post_kept`` (÷ ``served``: how much the
 host post-process is handed an image, and whether ``TEST.MAX_PER_IMAGE``
-binds) and ``h2d_bytes`` beside ``readback_bytes`` (÷ ``batches``: what a
+binds), on a pyramid network ``rois_valid`` and ``rois_level_p2`` …
+``rois_level_p5`` (the proposals the joint NMS kept and the level the FPN
+paper's eq. 1 pools each from, counted on the host by the legacy path) and
+``h2d_bytes`` beside ``readback_bytes`` (÷ ``batches``: what a
 turn ships each way, the number ``--serve-e2e`` shrinks), and under
 ``"compile"`` the registry's snapshot with what XLA really compiled or
 loaded (``xla_compiles``, ``xla_compile_s``, ``persistent_cache_hits`` /
@@ -236,6 +239,22 @@ class _Request:
         self.future = ServeFuture()
 
 
+def _roi_level_counts(rois: np.ndarray, roi_valid: np.ndarray) -> dict:
+    """A batch's valid proposals and how the FPN paper's eq. 1 spreads them
+    over P2..P5, as ``models/fpn.py::_assign_level`` writes it (``+1``
+    widths, k0 = 4 at 224 px, held to 2..5) — counted on the host from the
+    arrays the legacy path reads back anyway.  rois (n, R, 4), roi_valid
+    (n, R) → the ``rois_valid`` / ``rois_level_p2`` … ``p5`` increments."""
+    valid = np.asarray(roi_valid, bool)
+    w = rois[..., 2] - rois[..., 0] + 1.0
+    h = rois[..., 3] - rois[..., 1] + 1.0
+    k = np.clip(np.floor(4.0 + np.log2(np.sqrt(w * h) / 224.0 + 1e-8)), 2, 5)
+    out = {"rois_valid": int(valid.sum())}
+    for lvl in (2, 3, 4, 5):
+        out[f"rois_level_p{lvl}"] = int(np.count_nonzero(valid & (k == lvl)))
+    return out
+
+
 class ServeEngine:
     """The dynamic batcher.  ``start()`` before submitting; ``stop()``
     fails whatever is still queued (a draining stop would hold clients
@@ -306,6 +325,11 @@ class ServeEngine:
                          # per-batch contract above is stream-agnostic.
                          "stream_batches": 0, "stream_batch_frames": 0,
                          "stream_coalesced_batches": 0}
+        if cfg.network.HAS_FPN:
+            # a pyramid network's proposals: how many survived the joint
+            # NMS and which level eq. 1 pools each from (_forward_legacy)
+            self.counters.update(_roi_level_counts(np.zeros((0, 4)),
+                                                   np.zeros((0,), bool)))
         self._pool = None  # prep worker pool (opts.prep_workers > 0)
         # engine-authoritative latency distributions (same contract as
         # self.counters: live even with telemetry off — the controller's
@@ -1072,11 +1096,14 @@ class ServeEngine:
             & np.asarray(roi_valid[:n], bool)[:, :, None]))
         nbytes = int(sum(np.asarray(a).nbytes for a in
                          (rois, roi_valid, cls_prob, bbox_deltas)))
-        return ({"h2d_transfers": 2, "dispatches": 1, "readbacks": 1,
-                 "readback_bytes": nbytes,
-                 "h2d_bytes": int(images.nbytes + im_info.nbytes),
-                 "post_candidates": candidates,
-                 "post_kept": kept},
+        xfer = {"h2d_transfers": 2, "dispatches": 1, "readbacks": 1,
+                "readback_bytes": nbytes,
+                "h2d_bytes": int(images.nbytes + im_info.nbytes),
+                "post_candidates": candidates,
+                "post_kept": kept}
+        if cfg.network.HAS_FPN:
+            xfer.update(_roi_level_counts(rois[:n], roi_valid[:n]))
+        return (xfer,
                 {"forward": fwd.seconds, "readback": rb.seconds,
                  "postprocess": post.seconds})
 

@@ -151,3 +151,43 @@ def test_assign_reduce_compiles_for_v5e(one_chip):
         ((n, 4), jnp.float32), ((g, 4), jnp.float32), ((g,), jnp.bool_),
         ((n,), jnp.bool_))
     assert "tpu_custom_call" in text
+
+
+# ---- the 800 x 1344 bucket of benchmark cell fpn-serve-closed (r101-fpn,
+# TEST.RPN_PRE_NMS_TOP_N=5000 -> 1000 a level, RPN_POST_NMS_TOP_N=1000) ----
+
+SERVE_H, SERVE_W = 800, 1344
+
+
+def _serve_level_anchors() -> list:
+    """Anchors a level at the served bucket: 3 a cell on strides 4..32, P6
+    the stride-2 subsample of P5's 25 x 42 map."""
+    sizes = [(SERVE_H // s, SERVE_W // s) for s in (4, 8, 16, 32)]
+    sizes.append(((sizes[-1][0] + 1) // 2, (sizes[-1][1] + 1) // 2))
+    return [h * w * 3 for h, w in sizes]
+
+
+def test_served_fpn_joint_nms_compiles_for_v5e(one_chip):
+    # what propose_fpn's ONE joint NMS meets there: 4 x 1000 + P6's 819
+    n = sum(min(1000, a) for a in _serve_level_anchors())
+    assert n == 4819
+    text = _compiled_text(
+        lambda b, s, v: nms_mod._nms_core(b, s, v, 1000, 0.7),
+        one_chip, *_nms_shapes(n))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("level", range(5), ids=["P2", "P3", "P4", "P5", "P6"])
+def test_level_topk_compiles_at_the_served_row_lengths(one_chip, level):
+    # the windowed-TopK fence of ops/proposal.py::_level_topk at the rows it
+    # now meets: (16, 12600), (16, 3150), (8, 1575), (2, 1575) at k = 1000
+    # and their (g * k,) second stages; P6's 819 anchors are fewer than k
+    # and take the argsort branch
+    from mx_rcnn_tpu.ops.proposal import _level_topk
+
+    n = _serve_level_anchors()[level]
+    assert n == (201600, 50400, 12600, 3150, 819)[level]
+    k = min(1000, n)
+    text = _compiled_text(lambda s: _level_topk(s, k), one_chip,
+                          ((n,), jnp.float32))
+    assert ("/top_k" in text) == (level < 4)
