@@ -2,14 +2,20 @@
 
 Mirrors the reference's native tier (``rcnn/cython`` + pycocotools C): IoU
 matrix, greedy NMS, RLE intersection/IoU.  The library is built on first
-use (``make`` → g++, ~1 s); every entry point has a pure-numpy fallback, so
-an unbuildable environment degrades to slower eval, never to failure.
+use (``make`` → g++, ~1 s) and rebuilt when ``src/mxr_native.cpp`` is newer
+than the ``.so`` (its mtime), so an entry point added to the source is in
+the library the next process loads; every entry point has a pure-numpy
+fallback, so an unbuildable environment degrades to slower eval, never to
+failure.
 
 API (drop-in with the numpy versions):
   bbox_overlaps(boxes (N,4), query (K,4)) -> (N,K) f32
   nms(dets (N,5), thresh) -> list[int]
+  nms_classes(scores (R,K), boxes (R,4K), valid (R,), thresh, nms_thresh)
+      -> (N,6) f32 [x1,y1,x2,y2,score,cls] or None   (one image's per-class
+      NMS in ONE foreign call; the caller's fallback is a loop over ``nms``)
   rle_iou(dts, gts, iscrowd) -> (D,G) f64   (RLE dicts, uncompressed counts)
-  available() -> bool
+  available(symbol=None) -> bool
 """
 
 from __future__ import annotations
@@ -63,6 +69,15 @@ def _load() -> Optional[ctypes.CDLL]:
     lib.mxr_nms.argtypes = [
         ctypes.POINTER(ctypes.c_float), ctypes.c_int64, ctypes.c_float,
         ctypes.POINTER(ctypes.c_int64)]
+    try:  # absent only in a stale .so that failed to rebuild
+        lib.mxr_nms_classes.restype = ctypes.c_int64
+        lib.mxr_nms_classes.argtypes = [
+            ctypes.POINTER(ctypes.c_float), ctypes.POINTER(ctypes.c_float),
+            ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64, ctypes.c_int64,
+            ctypes.c_float, ctypes.c_float, ctypes.POINTER(ctypes.c_float)]
+    except AttributeError:
+        logger.warning("stale native library has no all-class NMS entry "
+                       "point; per_class_nms loops over the classes")
     try:  # absent only in a stale pre-round-4 .so that failed to rebuild
         lib.mxr_rle_encode.restype = ctypes.c_int64
         lib.mxr_rle_encode.argtypes = [
@@ -88,8 +103,11 @@ def _load() -> Optional[ctypes.CDLL]:
     return _lib
 
 
-def available() -> bool:
-    return _load() is not None
+def available(symbol: Optional[str] = None) -> bool:
+    """Whether the library loaded — and, given ``symbol``, has that entry
+    point (a stale ``.so`` that could not be rebuilt may lack a newer one)."""
+    lib = _load()
+    return lib is not None and (symbol is None or hasattr(lib, symbol))
 
 
 def _fptr(a: np.ndarray):
@@ -121,6 +139,36 @@ def nms(dets: np.ndarray, thresh: float) -> List[int]:
     cnt = lib.mxr_nms(_fptr(dets), len(dets), thresh,
                       keep.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)))
     return keep[:cnt].tolist()
+
+
+def nms_classes(scores: np.ndarray, boxes: np.ndarray, valid,
+                thresh: float, nms_thresh: float) -> Optional[np.ndarray]:
+    """One image's per-class NMS in one foreign call: (R, K) scores,
+    (R, 4K) boxes, (R,) validity → the kept rows ``[x1, y1, x2, y2, score,
+    cls]`` as (N, 6) float32, classes 1..K-1 in turn, each in the order
+    :func:`nms` keeps — or None when the library (or this entry point) is
+    missing and the caller has to loop.
+
+    Float32 throughout, as the loop over :func:`nms` computes: scores and
+    boxes of another dtype are cast first, so a score is compared with
+    ``thresh`` as the float32 it is kept as."""
+    lib = _load()
+    if lib is None or not hasattr(lib, "mxr_nms_classes"):
+        return None
+    scores = np.ascontiguousarray(scores, np.float32)
+    boxes = np.ascontiguousarray(boxes, np.float32)
+    valid = np.ascontiguousarray(valid, bool)
+    assert scores.ndim == 2 and valid.shape == scores.shape[:1] \
+        and boxes.shape == (scores.shape[0], 4 * scores.shape[1]), \
+        f"(R, K) scores, (R, 4K) boxes and (R,) valid wanted; got " \
+        f"{scores.shape}, {boxes.shape}, {valid.shape}"
+    r, k = scores.shape
+    out = np.empty((r * max(k - 1, 0), 6), np.float32)
+    n = lib.mxr_nms_classes(
+        _fptr(scores), _fptr(boxes),
+        valid.view(np.uint8).ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+        r, k, thresh, nms_thresh, _fptr(out))
+    return out[:n]
 
 
 _enc_buf: Optional[np.ndarray] = None  # reused across per-det encode calls

@@ -77,6 +77,85 @@ int64_t mxr_nms(const float* dets, int64_t n, float thresh,
   return kept;
 }
 
+// One image's per-class NMS in ONE call (ops/postprocess.per_class_nms):
+// (R,K) scores, (R,4K) boxes and (R,) validity in; the kept rows
+// [x1,y1,x2,y2,score,cls] out, class 1..K-1 in turn, each class in the
+// order mxr_nms keeps (stable sort by descending score over the rows'
+// order).  A class's candidates (score > score_thresh on a valid row) are
+// gathered once into sorted contiguous x1/y1/x2/y2/area arrays, and the
+// greedy sweep over them has no data-dependent branch in its inner loop:
+// `(iw > 0) & (ih > 0) & (inter / (a_i + a_j - inter) > thresh)` is
+// mxr_nms's verdict for every pair (its two `continue`s and the test of an
+// already removed box change no verdict), in the same float32 operations,
+// so the rows are mxr_nms's bit for bit and -O3 vectorises the loop.
+// out holds R*(K-1) rows of 6; returns the rows written.
+int64_t mxr_nms_classes(const float* scores, const float* boxes,
+                        const uint8_t* valid, int64_t r, int64_t k,
+                        float score_thresh, float nms_thresh, float* out) {
+  if (r <= 0 || k <= 1) return 0;
+  // candidates by class, in the rows' order: count, then fill
+  std::vector<int64_t> start(k + 1, 0);
+  for (int64_t i = 0; i < r; ++i) {
+    if (!valid[i]) continue;
+    const float* s = scores + i * k;
+    for (int64_t c = 1; c < k; ++c) start[c + 1] += s[c] > score_thresh;
+  }
+  int64_t most = 0;
+  for (int64_t c = 1; c < k; ++c) {
+    most = std::max(most, start[c + 1]);
+    start[c + 1] += start[c];
+  }
+  std::vector<int32_t> rows(start[k]);
+  {
+    std::vector<int64_t> at(start.begin(), start.end() - 1);
+    for (int64_t i = 0; i < r; ++i) {
+      if (!valid[i]) continue;
+      const float* s = scores + i * k;
+      for (int64_t c = 1; c < k; ++c)
+        if (s[c] > score_thresh) rows[at[c]++] = (int32_t)i;
+    }
+  }
+  std::vector<float> buf(6 * most);
+  float* const x1 = buf.data();
+  float* const y1 = x1 + most;
+  float* const x2 = y1 + most;
+  float* const y2 = x2 + most;
+  float* const area = y2 + most;
+  float* const sc = area + most;
+  std::vector<int32_t> removed(most);
+  int64_t written = 0;
+  for (int64_t c = 1; c < k; ++c) {
+    int32_t* const cand = rows.data() + start[c];
+    const int64_t n = start[c + 1] - start[c];
+    std::stable_sort(cand, cand + n, [&](int32_t a, int32_t b) {
+      return scores[a * k + c] > scores[b * k + c];
+    });
+    for (int64_t j = 0; j < n; ++j) {
+      const float* b = boxes + (cand[j] * k + c) * 4;
+      x1[j] = b[0]; y1[j] = b[1]; x2[j] = b[2]; y2[j] = b[3];
+      area[j] = (b[2] - b[0] + 1.f) * (b[3] - b[1] + 1.f);
+      sc[j] = scores[cand[j] * k + c];
+      removed[j] = 0;
+    }
+    for (int64_t i = 0; i < n; ++i) {
+      if (removed[i]) continue;
+      const float ix1 = x1[i], iy1 = y1[i], ix2 = x2[i], iy2 = y2[i];
+      const float ia = area[i];
+      float* o = out + 6 * written++;
+      o[0] = ix1; o[1] = iy1; o[2] = ix2; o[3] = iy2;
+      o[4] = sc[i]; o[5] = (float)c;
+      for (int64_t j = i + 1; j < n; ++j) {
+        const float iw = std::min(ix2, x2[j]) - std::max(ix1, x1[j]) + 1.f;
+        const float ih = std::min(iy2, y2[j]) - std::max(iy1, y1[j]) + 1.f;
+        const float inter = iw * ih;
+        const float iou = inter / (ia + area[j] - inter);
+        removed[j] |= (iw > 0.f) & (ih > 0.f) & (iou > nms_thresh);
+      }
+    }
+  }
+  return written;
+}
+
 // Advance to the next run, skipping zero-length runs (each skipped run
 // still toggles the value — an RLE starting with count 0 means the mask
 // begins with foreground).

@@ -8,8 +8,14 @@ detections disagree with the eval metric for the same weights), so the
 single source of truth lives here and a parity test pins it to the
 reference block's semantics (``tests/test_serve.py``).
 
-All host numpy — identical accounting to the reference's ``pred_eval``
-(per-class score threshold → NMS → global per-image cap).  Nothing outside
+All on the host — identical accounting to the reference's ``pred_eval``
+(per-class score threshold → NMS → global per-image cap).  The decode, the
+cap and the split by class are numpy; the threshold, the gather by class
+and every class's greedy NMS are one call an image into the native library
+(``native.nms_classes`` → ``mxr_nms_classes``), with the Python loop over
+``native.nms`` (numpy without the library) kept as the fallback and the
+oracle: 80 trips through the interpreter and the GIL an image were the
+largest stage of a serving turn.  Nothing outside
 :func:`device_postprocess` touches a device array, the device or a compiled
 program: :func:`decode_image_boxes` is the host twin of the in-graph
 ``ops.boxes.bbox_pred`` + ``clip_boxes`` (``tests/test_postprocess.py`` ties
@@ -71,10 +77,28 @@ def per_class_nms(scores: np.ndarray, boxes: np.ndarray, valid,
     per-image score cap).
 
     Returns a list indexed by class; index 0 (background) is ``None``.
-    ``nms_fn`` defaults to the native C++ NMS (numpy fallback inside) —
-    injectable for oracle tests."""
+    With no ``nms_fn`` and the native library loaded, the threshold, the
+    gather and every class's NMS are ONE foreign call
+    (``native.nms_classes``); the cap and the split by class are a few
+    numpy calls on its flat rows.  With an ``nms_fn`` injected (oracle
+    tests) or no library, the loop below runs, a ``nms_fn`` call a class:
+    the fallback, and the oracle the native call is held to row for row."""
     if nms_fn is None:
-        from mx_rcnn_tpu.native import nms as nms_fn
+        from mx_rcnn_tpu import native
+
+        rows = native.nms_classes(scores[:, :num_classes],
+                                  boxes[:, :4 * num_classes], valid,
+                                  thresh, nms_thresh)
+        if rows is not None:
+            # cap total detections per image (reference max_per_image block)
+            if 0 < max_per_image < len(rows):
+                th = np.sort(rows[:, 4])[-max_per_image]
+                rows = rows[rows[:, 4] >= th]
+            # rows come sorted by class: class k is rows[at[k-1]:at[k]]
+            at = np.searchsorted(rows[:, 5], np.arange(1, num_classes + 1))
+            rows = np.ascontiguousarray(rows[:, :5])
+            return [None] + [rows[a:b] for a, b in zip(at[:-1], at[1:])]
+        nms_fn = native.nms
     v = np.asarray(valid, bool)
     dets: List[Optional[np.ndarray]] = [None] * num_classes
     for k in range(1, num_classes):

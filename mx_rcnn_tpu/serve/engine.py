@@ -68,8 +68,11 @@ timeline only).  Start-up: ``setup/model`` / ``setup/params`` /
 ``"t_s"`` (the server's own clock, for a rate between two scrapes), the
 counters ``post_candidates`` / ``post_kept`` (÷ ``served``: how much the
 host post-process is handed an image, and whether ``TEST.MAX_PER_IMAGE``
-binds), on a pyramid network ``rois_valid`` and ``rois_level_p2`` …
-``rois_level_p5`` (the proposals the joint NMS kept and the level the FPN
+binds) and ``post_nms_native`` (the images whose per-class NMS was the one
+native call of ``ops/postprocess.per_class_nms``: equal to ``served``, or 0
+where the library did not build and the Python loop ran), on a pyramid
+network ``rois_valid`` and ``rois_level_p2`` … ``rois_level_p5`` (the
+proposals the joint NMS kept and the level the FPN
 paper's eq. 1 pools each from, counted on the host by the legacy path) and
 ``h2d_bytes`` beside ``readback_bytes`` (÷ ``batches``: what a
 turn ships each way, the number ``--serve-e2e`` shrinks), and under
@@ -101,7 +104,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from mx_rcnn_tpu import telemetry
+from mx_rcnn_tpu import native, telemetry
 from mx_rcnn_tpu.telemetry import Hist, tracectx
 from mx_rcnn_tpu.telemetry.tracectx import TraceContext
 from mx_rcnn_tpu.config import Config
@@ -316,6 +319,9 @@ class ServeEngine:
                          # host post-process work: boxes over TEST.THRESH
                          # that went into the per-class NMS, records returned
                          "post_candidates": 0, "post_kept": 0,
+                         # images whose per-class NMS was the one native
+                         # call (== served; 0 = no library, the loop ran)
+                         "post_nms_native": 0,
                          "host_prep_ms_total": 0.0,
                          # stream-aware flush bookkeeping: batches that
                          # carried >= 1 stream frame, the frame count, and
@@ -943,6 +949,7 @@ class ServeEngine:
         tel.counter("serve/post_kept", xfer["post_kept"])
         if "post_candidates" in xfer:
             tel.counter("serve/post_candidates", xfer["post_candidates"])
+            tel.counter("serve/post_nms_native", xfer["post_nms_native"])
         if stream_frames:
             tel.counter("stream/batches")
             tel.counter("stream/batch_frames", stream_frames)
@@ -1100,7 +1107,9 @@ class ServeEngine:
                 "readback_bytes": nbytes,
                 "h2d_bytes": int(images.nbytes + im_info.nbytes),
                 "post_candidates": candidates,
-                "post_kept": kept}
+                "post_kept": kept,
+                "post_nms_native":
+                    n if native.available("mxr_nms_classes") else 0}
         if cfg.network.HAS_FPN:
             xfer.update(_roi_level_counts(rois[:n], roi_valid[:n]))
         return (xfer,

@@ -16,9 +16,11 @@ import threading
 import time
 
 import numpy as np
+import pytest
 
 from mx_rcnn_tpu.config import generate_config
 from mx_rcnn_tpu.data import prepare_image
+from mx_rcnn_tpu.ops.nms import nms as numpy_nms
 from mx_rcnn_tpu.ops.postprocess import (decode_image_boxes,
                                          detections_to_records,
                                          per_class_nms)
@@ -163,6 +165,43 @@ def test_partial_batch_padded_and_responses_unmasked():
         assert abs(dets[0]["score"] - fake.row_score(prepared)) < 1e-5
     assert engine.counters["served"] == 3
     assert engine.counters["batches"] == 1
+
+
+@pytest.mark.parametrize("library", ["loaded", "missing"])
+def test_post_nms_native_counts_the_images_the_library_served(
+        library, monkeypatch):
+    """``post_nms_native`` beside ``served``: equal with the native library
+    (every image's per-class NMS was its one call), 0 without it — and the
+    records are the same either way."""
+    from mx_rcnn_tpu import native
+
+    if library == "missing":
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_load", lambda: None)
+    assert native.available("mxr_nms_classes") == (library == "loaded")
+    cfg = tiny_cfg()
+    engine = make_engine(cfg, batch_size=4, max_delay_ms=1.0)
+    imgs = [raw_image(60, 100, v) for v in (40, 120, 200)] \
+        + [raw_image(100, 60, 80)]
+    futs = [engine.submit(im) for im in imgs]
+    engine.start()
+    try:
+        results = [f.result(timeout=30) for f in futs]
+        counters = engine.metrics()["counters"]
+    finally:
+        engine.stop()
+    assert counters["served"] == 4
+    assert counters["post_nms_native"] == (4 if library == "loaded" else 0)
+    # the records: the loop over the numpy NMS on the same read-back
+    for img, dets in zip(imgs, results):
+        prepared, im_info = prepare_image(img, cfg, cfg.tpu.SCALES[0])
+        rois, valid, scores, deltas, _ = engine.predictor.predict(
+            prepared[None], im_info[None])
+        assert dets == detections_to_records(per_class_nms(
+            scores[0], decode_image_boxes(rois[0], deltas[0], im_info),
+            valid[0], cfg.NUM_CLASSES, cfg.TEST.THRESH, cfg.TEST.NMS,
+            cfg.TEST.MAX_PER_IMAGE, nms_fn=numpy_nms))
+        assert len(dets) == 1
 
 
 def test_full_bucket_flushes_before_older_partial():
