@@ -94,11 +94,11 @@ class TrainConfig:
     # SGD) momentum semantics; the mini-VOC fixture A/B measured bf16
     # neutral (BASELINE.md round-3 divergence ledger) but fixture
     # neutrality cannot bound a VOC07/COCO regression.  The SPEED half of
-    # the claim is now a one-flag measurement — ``python bench.py --mode
-    # train --opt-acc-ab`` runs the chain bench under both dtypes and
-    # emits f32/bf16 ms/step plus ``delta_ms_per_step`` in one JSON row —
-    # so bf16 stays opt-in until that A/B on real TPU hardware plus a
-    # real-dataset accuracy run pins (or retires) the −0.26 ms figure.
+    # the claim is undecided — ROADMAP D7: one A/B on a train cell of the
+    # benchmark, then this becomes the only path or goes (the CPU A/B
+    # that ``bench.py`` ran was deleted with it in PR 30) — so bf16 stays
+    # opt-in until that A/B on real TPU hardware plus a real-dataset
+    # accuracy run pins (or retires) the −0.26 ms figure.
     OPT_ACC_DTYPE: str = "float32"
     WARMUP: bool = False
     WARMUP_LR: float = 0.0

@@ -6,7 +6,8 @@ Hypothesis (round 4): XLA lowers ``nn.max_pool``'s backward to
 classically slow TPU pool transpose; for non-overlapping 2x2/2 windows a
 reshape+max formulation gets an equality-select backward instead.
 
-Measured on TPU v5-lite (r4_tpu_session2/3.log, scripts/bench_pool.py):
+Measured on TPU v5-lite in round 4 (scripts/bench_pool.py; the logs were
+deleted in PR 21, so these are claims to check):
 the swap is device-NEUTRAL — VGG16 step 17.336 ms (reshape) vs
 17.333 ms (reduce_window); isolated bwd 5.80/6.83 ms (reshape, two pool
 shapes) vs 6.53/6.26 ms (reduce_window).  The scatter's cost here equals

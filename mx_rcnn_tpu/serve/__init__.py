@@ -50,10 +50,10 @@ jitted bucket programs, and the shared ``ops/postprocess`` block that
   per-direction cooldowns, a thrash-freeze guard, and a zero-recompile
   assertion over registry counters on every scale event.
 
-Driver: top-level ``serve.py`` (``--replicas N`` for the plane);
-load generator: ``scripts/loadgen.py``; throughput: ``bench.py --mode
-serve``; smoke: ``script/serve_smoke.sh``, ``script/slo_smoke.sh``, and
-``script/replica_smoke.sh``.
+Driver: top-level ``serve.py`` (``--replicas N`` for the plane).  Speed
+is measured by the benchmark alone (``benchmark/README.md``); behaviour
+is pinned by ``tests/test_serve.py``, ``tests/test_slo.py`` and
+``tests/test_replica.py``.
 """
 
 from mx_rcnn_tpu.serve.autoscaler import (AutoscalerOptions,

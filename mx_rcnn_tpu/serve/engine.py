@@ -1,7 +1,7 @@
 """Online serving engine: bucket-aware dynamic batcher over ``Predictor``.
 
-The offline paths (``pred_eval``, ``bench.py --mode infer``) fill batches
-from a dataset; online traffic arrives one image at a time, at arbitrary
+The offline path (``pred_eval``) fills batches from a dataset; online
+traffic arrives one image at a time, at arbitrary
 sizes, and Faster R-CNN inference is throughput-bound on batch fill.
 Iteration-level dynamic batching (the Clipper recipe, Crankshaw et al.,
 NSDI 2017) is exactly what the static-shape bucket design enables: every

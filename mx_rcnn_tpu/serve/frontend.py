@@ -3,7 +3,7 @@
 Three transports, one JSON contract:
 
 * TCP HTTP (``make_server(engine, port=...)``) — the production-shaped
-  endpoint ``scripts/loadgen.py`` drives.
+  endpoint; fabric members are reached over it.
 * Unix-socket HTTP (``make_server(engine, unix_socket=path)``) — same
   handler over ``AF_UNIX``; what the tier-1 tests round-trip (no port
   allocation races on shared CI hosts).  ``unix_http_request`` is the

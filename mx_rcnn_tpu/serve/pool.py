@@ -36,8 +36,8 @@ destroying another's p99:
   the mask model's traffic first.
 
 Driver: ``serve.py --models a=resnet50,b=vgg16`` (per-model overrides
-via ``--model-arg``); loadgen: ``scripts/loadgen.py --models
-a=0.7,b=0.3``; smoke: ``script/multimodel_smoke.sh``.
+via ``--model-arg``); pinned by ``tests/test_multimodel.py`` (routing,
+paging under a budget, cross-model scheduling, two real models).
 """
 
 from __future__ import annotations
@@ -469,7 +469,7 @@ class ModelPool:
     def metrics(self) -> dict:
         """The pool-mode ``/metrics`` payload.  Top-level ``counters``
         aggregates every model's engine counters (so single-model
-        clients — loadgen's server-counter deltas — keep working), with
+        clients — a generator's server-counter deltas — keep working), with
         the full per-model picture under ``models`` and the pool's own
         scheduling + residency state alongside."""
         with self._lock:

@@ -23,9 +23,9 @@ replica process:
   ``--replicas 1``).
 * :class:`ReplicaFaults` — the serve-side chaos harness: behavior is
   driven by ``MXR_FAULT_REPLICA_*`` env vars (the resilience.py
-  ``MXR_FAULT_*`` precedent) so tests and ``script/replica_smoke.sh``
-  inject kill -9 / hang / slow-start / corrupt-checkpoint without
-  touching the code path under test.
+  ``MXR_FAULT_*`` precedent) so ``tests/test_replica.py`` injects
+  kill -9 / hang / slow-start / corrupt-checkpoint without touching the
+  code path under test.
 
 Fault-injection env contract (each var is a comma-separated list of
 ``INDEX[:VALUE]`` tokens; a token applies to the replica whose

@@ -10,8 +10,8 @@ post-process — so every program the steady state can dispatch is ready
 before the frontend accepts traffic, and the engine's recompile counter
 (the program registry's first-dispatch bookkeeping) proves it: after
 warmup, ``counters["recompiles"] == counters["warmup_programs"]`` must
-hold for the life of the process (asserted by ``script/serve_smoke.sh``
-and ``tests/test_serve.py``).
+hold for the life of the process (asserted by
+``tests/test_serve.py``).
 
 With a persistent program cache (``MXR_PROGRAM_CACHE``), warmup is where
 the AOT win lands: a second boot over a warm cache dir reports

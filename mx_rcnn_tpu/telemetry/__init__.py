@@ -22,7 +22,7 @@ instrumented hot paths pay one attribute check and zero allocations.
 Drivers expose this as ``--telemetry-dir`` (per-rank event files on
 multi-host, summary JSON from process 0 only — the ``profile_dir``
 rank-split contract); ``scripts/telemetry_report.py`` folds the files
-back into the human table and BENCH-compatible rows.
+back into the human table.
 """
 
 from __future__ import annotations

@@ -1,5 +1,5 @@
 """Fold telemetry JSONL event streams into the aggregated summary, the
-human table, and ``BENCH_*.json``-compatible metric rows.
+human table, and ``{"metric", "value", "unit"}`` rate rows.
 
 Library half of ``scripts/telemetry_report.py`` (importable so tests and
 other tools fold without a subprocess).  Input is any mix of event files
@@ -36,8 +36,7 @@ RECOVERY_COUNTERS = (
 # the serving subsystem's health counters (serve/engine.py): rendered as
 # their own section — zeros included — whenever the stream carries any
 # serve/* event, so "did the endpoint shed load, blow deadlines, or
-# recompile after warmup?" reads off one block (script/serve_smoke.sh
-# greps it the way fault_smoke.sh greps the recovery section)
+# recompile after warmup?" reads off one block
 SERVE_COUNTERS = (
     "serve/requests",
     "serve/images",
@@ -52,9 +51,8 @@ SERVE_COUNTERS = (
 # the cross-host fabric's membership/routing health (serve/fabric.py):
 # rendered as their own section — zeros included — whenever the stream
 # carries any fabric/* event, so "did the pool evict anyone, trip a
-# breaker, hedge, or declare a partition?" is one greppable block
-# (script/fabric_smoke.sh reads it the way replica_smoke reads the
-# supervisor counters)
+# breaker, hedge, or declare a partition?" is one block
+# (tests/test_fabric.py::test_telemetry_report_fabric_health_section)
 FABRIC_COUNTERS = (
     "fabric/requests",
     "fabric/member_joined",
@@ -74,7 +72,7 @@ FABRIC_COUNTERS = (
 # the loader's replay mixing): rendered as their own section — zeros
 # included — whenever the stream carries any flywheel/* event, so "did
 # traffic actually capture, mine, and replay into training?" is one
-# greppable block (script/flywheel_smoke.sh reads it)
+# block (tests/test_flywheel.py::test_flywheel_counters_render_as_report_table)
 FLYWHEEL_COUNTERS = (
     "flywheel/captured",
     "flywheel/spilled_bytes",
@@ -103,9 +101,8 @@ FLYWHEEL_COUNTERS = (
 # (serve/pool.py): rendered as their own section — zeros included —
 # whenever the stream carries any of these, so "did weights page under
 # the budget, and did the scheduler actually interleave tenants?" is
-# one greppable block (script/multimodel_smoke.sh reads it); the
-# per-model variants (serve/weight_page_in/<model>, ...) render inside
-# the same section
+# one block; the per-model variants (serve/weight_page_in/<model>, ...)
+# render inside the same section
 POOL_COUNTERS = (
     "serve/weight_page_in",
     "serve/weight_page_out",
@@ -117,7 +114,7 @@ POOL_COUNTERS = (
 # engine's stream-aware flush bookkeeping): rendered as their own
 # section — zeros included — whenever the stream carries any stream/*
 # event, so "did frames actually skip, and did streams share batches?"
-# is one greppable block (script/stream_smoke.sh reads it)
+# is one block (tests/test_stream.py::test_telemetry_report_streaming_section)
 STREAM_COUNTERS = (
     "stream/frames",
     "stream/forwarded",
@@ -135,7 +132,7 @@ STREAM_COUNTERS = (
 # distributed request tracing (telemetry/tracectx.py): rendered as its
 # own section — zeros included — whenever the stream carries any
 # trace/* counter, so "did spans actually emit, and were the slow trees
-# tail-kept?" is one greppable block (script/trace_smoke.sh reads it)
+# tail-kept?" is one block
 TRACE_COUNTERS = (
     "trace/spans_emitted",
     "trace/spans_dropped",
@@ -145,8 +142,7 @@ TRACE_COUNTERS = (
 # cascade serving's routing decisions (serve/pool.py CascadeRouter):
 # rendered as their own section — zeros included — whenever the stream
 # carries any cascade/* event, so "did the gate actually run, and what
-# fraction of traffic escalated?" is one greppable block
-# (script/cascade_smoke.sh reads it)
+# fraction of traffic escalated?" is one block
 CASCADE_COUNTERS = (
     "cascade/answered_small",
     "cascade/escalated",
@@ -278,8 +274,8 @@ def aggregate(events: Iterable[dict]) -> dict:
                 meta = dict(e.get("fields", {}))
             elif name == "pipeline_cell":
                 # one row per tuning-sweep cell (train/pipeline.py —
-                # also the shape bench.py --mode pipeline writes to its
-                # --sweep-out JSONL, so that artifact folds here too)
+                # also the shape its --sweep-out JSONL holds, so that
+                # artifact folds here too)
                 pipeline.append(dict(e.get("fields", {})))
             elif name == "eval_pipeline":
                 # one row per pred_eval run (eval/pipeline.py overlap
@@ -433,9 +429,9 @@ def render_table(summary: dict) -> str:
                          f"{g['last']:>10.3f}")
     pipeline = summary.get("pipeline", [])
     if pipeline:
-        # tuning-sweep cells, fastest first (bench.py --mode pipeline /
-        # train/pipeline.py): the full wait breakdown per cell, so "which
-        # knob moved the needle and where did the time go" is one block
+        # tuning-sweep cells, fastest first (train/pipeline.py): the full
+        # wait breakdown per cell, so "which knob moved the needle and
+        # where did the time go" is one block
         lines.append("")
         lines.append(f"{'pipeline cell':<18}{'imgs/s':>10}{'loader_s':>10}"
                      f"{'assembly_s':>11}{'dispatch_s':>11}{'wait%':>8}")
@@ -520,12 +516,10 @@ def render_table(summary: dict) -> str:
 
 
 def bench_rows(summary: dict) -> List[dict]:
-    """Rate gauges → ``BENCH_*.json``-compatible metric rows (the
-    ``{"metric", "value", "unit"}`` shape bench.py prints), so a telemetry
-    run can feed the bench ledger without a separate measurement pass.
-    A rate gauge is one whose name contains ``imgs_per_sec`` (the
-    Speedometer feed, pred_eval's rate, and bench's own result gauge,
-    whose suffixed names carry batch/network tags)."""
+    """Rate gauges → ``{"metric", "value", "unit"}`` rows.  A rate gauge
+    is one whose name contains ``imgs_per_sec`` (the Speedometer feed,
+    pred_eval's rate).  The rows' readers — the CPU gate and its smokes —
+    were deleted in PR 30 (ROADMAP D13)."""
     rows = []
     for name, g in summary.get("gauges", {}).items():
         if "imgs_per_sec" in name:

@@ -123,9 +123,10 @@ def add_common_args(parser: argparse.ArgumentParser, train: bool = True):
         parser.add_argument("--tuned-pipeline", action="store_true",
                             dest="tuned_pipeline",
                             help="boot into the input-pipeline cell "
-                                 "persisted by `bench.py --mode pipeline "
-                                 "--auto-tune` (k steps/dispatch, loader "
-                                 "workers, prefetch depth, device-prep); "
+                                 "persisted by `python -m mx_rcnn_tpu.train."
+                                 "pipeline --auto-tune` (k steps/dispatch, "
+                                 "loader workers, prefetch depth, "
+                                 "device-prep); "
                                  "explicit flags win per field")
         # data flywheel replay (ISSUE 13): mix mined serving captures
         # into the epoch plan (data/replay.py); the mix is drawn from the
@@ -209,8 +210,8 @@ def apply_program_cache(args) -> None:
 
 def parse_cfg_overrides(items) -> dict:
     """``--cfg PATH=VALUE`` (python-literal) → overrides dict.  Shared by
-    the CLI drivers, bench.py and scripts/profile_step.py so the syntax
-    and error messages stay identical everywhere."""
+    the CLI drivers and the pipeline tuner so the syntax and error
+    messages stay identical everywhere."""
     import ast
 
     overrides = {}
@@ -263,10 +264,10 @@ def config_from_args(args, train: bool = True) -> Config:
         cfg = cfg.replace(network=dataclasses.replace(
             cfg.network, PIXEL_STDS=(127.0, 127.0, 127.0)))
     if train and getattr(args, "tuned_pipeline", False):
-        # boot into the persisted tuned pipeline cell (bench.py --mode
-        # pipeline --auto-tune).  Looked up AFTER every other override is
-        # applied — the tuned key is a tuned-field-normalized digest of
-        # exactly this config.
+        # boot into the persisted tuned pipeline cell (python -m
+        # mx_rcnn_tpu.train.pipeline --auto-tune).  Looked up AFTER every
+        # other override is applied — the tuned key is a
+        # tuned-field-normalized digest of exactly this config.
         from mx_rcnn_tpu.train.pipeline import apply_tuned_to_args
 
         cfg = apply_tuned_to_args(args, cfg)

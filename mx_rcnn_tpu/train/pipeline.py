@@ -21,7 +21,8 @@ host work, and the best cell is box- and config-dependent.  This module:
 * writes ``sweep.jsonl`` — telemetry-meta-shaped ``pipeline_cell`` rows
   that ``scripts/telemetry_report.py`` folds into its pipeline table.
 
-Entry point for humans: ``bench.py --mode pipeline [--auto-tune]``.
+Entry point for humans: ``python -m mx_rcnn_tpu.train.pipeline
+[--auto-tune]`` (:func:`main` below).
 """
 
 from __future__ import annotations
@@ -44,6 +45,9 @@ from mx_rcnn_tpu.train.trainer import LOADER_WAIT_TRIPWIRE_FRAC, \
 
 TUNED_FILENAME = "pipeline_tuned.json"
 TUNED_SCHEMA = "mxr-pipeline-tuned-v1"
+# the command that writes the tuned file; every message that sends a user
+# to the tuner names it through this
+TUNE_COMMAND = "python -m mx_rcnn_tpu.train.pipeline --auto-tune"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -102,7 +106,7 @@ def save_tuned(cfg: Config, cell: PipelineCell, result: dict,
         "device_prep": bool(cell.device_prep),
         "imgs_per_sec": float(result.get("imgs_per_sec", 0.0)),
         "loader_wait_frac": float(result.get("loader_wait_frac", 0.0)),
-        "recorded_by": "bench.py --mode pipeline --auto-tune",
+        "recorded_by": TUNE_COMMAND,
     }
     os.makedirs(os.path.dirname(path), exist_ok=True)
     tmp = f"{path}.tmp.{os.getpid()}"
@@ -349,8 +353,8 @@ def apply_tuned_to_args(args, cfg: Config,
     if tuned is None:
         logger.warning(
             "--tuned-pipeline: no tuned cell for this config under %s — "
-            "run `bench.py --mode pipeline --auto-tune` first; continuing "
-            "with the configured pipeline", path or tuned_path())
+            "run `%s` first; continuing with the configured pipeline",
+            path or tuned_path(), TUNE_COMMAND)
         return cfg
     tpu_over = {}
     if getattr(args, "loader_workers", None) is None:
@@ -380,3 +384,87 @@ def parse_cells(k_list: Sequence[int], workers_list: Sequence[int],
     return [PipelineCell(k, w, p, dp)
             for k in k_list for w in workers_list
             for p in prefetch_list for dp in device_prep]
+
+
+def _int_list(spec: str) -> List[int]:
+    """Comma-separated ints ("0,2,4") -> [0, 2, 4]."""
+    return [int(tok) for tok in spec.split(",") if tok.strip()]
+
+
+def main(argv: Optional[Sequence[str]] = None) -> dict:
+    """Sweep the matrix through the real train hot loop on a synthetic
+    roidb — fresh AnchorLoader per cell, one shared step-program cache —
+    and print one JSON line: every cell's imgs/s with its loader_wait /
+    dispatch / fetch_stall / assembly_wait breakdown, and the best.
+    ``--auto-tune`` persists the winner next to the program cache, where
+    ``train_end2end.py --tuned-pipeline`` finds it."""
+    import argparse
+
+    from mx_rcnn_tpu.compile import setup_compile_cache
+    from mx_rcnn_tpu.config import generate_config
+    from mx_rcnn_tpu.data.synthetic import SyntheticDataset
+    from mx_rcnn_tpu.tools.common import parse_cfg_overrides
+
+    ap = argparse.ArgumentParser(
+        prog="python -m mx_rcnn_tpu.train.pipeline", description=main.__doc__)
+    ap.add_argument("--network", default="resnet101",
+                    help="config preset (e.g. resnet101, resnet101_fpn)")
+    ap.add_argument("--batch", type=int, default=1)
+    ap.add_argument("--k-list", default="1,2",
+                    help="comma list of steps-per-dispatch group sizes")
+    ap.add_argument("--workers-list", default="0,2",
+                    help="comma list of loader worker counts")
+    ap.add_argument("--prefetch-list", default="2",
+                    help="comma list of prefetch queue depths")
+    ap.add_argument("--device-prep", action="store_true",
+                    help="sweep device-side preprocessing as a matrix axis "
+                         "(each k×w×p cell runs host-prep AND device-prep)")
+    ap.add_argument("--auto-tune", action="store_true",
+                    help="persist the winning cell next to the program "
+                         "cache (train_end2end.py / train_alternate.py "
+                         "--tuned-pipeline reads it)")
+    ap.add_argument("--pipeline-images", type=int, default=32,
+                    help="synthetic roidb size per epoch")
+    ap.add_argument("--pipeline-epochs", type=int, default=1,
+                    help="measured epochs per cell (one extra warmup epoch "
+                         "always runs first)")
+    ap.add_argument("--sweep-out", default="",
+                    help="per-cell JSONL path (telemetry-meta-shaped rows; "
+                         "scripts/telemetry_report.py renders the table).  "
+                         "Default: pipeline_sweep.jsonl next to the program "
+                         "cache")
+    ap.add_argument("--cfg", action="append", default=[],
+                    help="config override PATH=VALUE (tools/common.py "
+                         "syntax); the tuned cell is keyed by the config's "
+                         "digest, so pass what the train run will pass")
+    args = ap.parse_args(argv)
+    setup_compile_cache()
+
+    cfg = generate_config(args.network, "PascalVOC",
+                          **parse_cfg_overrides(args.cfg))
+    # pixel scale as the drivers' --synthetic sets it: same digest
+    cfg = cfg.replace(network=dataclasses.replace(
+        cfg.network, PIXEL_STDS=(127.0, 127.0, 127.0)))
+    roidb = SyntheticDataset(num_images=args.pipeline_images, height=600,
+                             width=800).gt_roidb()
+    cells = parse_cells(_int_list(args.k_list), _int_list(args.workers_list),
+                        _int_list(args.prefetch_list),
+                        device_prep=((False, True) if args.device_prep
+                                     else (False,)))
+    sweep_out = args.sweep_out or os.path.join(
+        os.path.dirname(tuned_path()), "pipeline_sweep.jsonl")
+    os.makedirs(os.path.dirname(os.path.abspath(sweep_out)), exist_ok=True)
+    res = PipelineSweep(cfg, roidb, batch=args.batch).sweep(
+        cells, epochs=args.pipeline_epochs, warmup_epochs=1,
+        auto_tune=args.auto_tune, sweep_jsonl=sweep_out)
+    reg = res.pop("registry")
+    # programs stays flat across cells that share k (no per-cell compile)
+    res.update(programs=len(reg.get("programs", [])),
+               registry_counters=reg.get("counters", {}),
+               sweep_jsonl=sweep_out)
+    print(json.dumps(res))
+    return res
+
+
+if __name__ == "__main__":
+    main()

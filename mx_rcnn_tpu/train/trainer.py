@@ -712,7 +712,7 @@ def fit(cfg: Config, model, params, train_loader,
                 logger.warning(
                     "input pipeline not saturated: loader_wait %.1fs is "
                     "%.0f%% of epoch wall (threshold %.0f%%) — retune with "
-                    "bench.py --mode pipeline --auto-tune",
+                    "python -m mx_rcnn_tpu.train.pipeline --auto-tune",
                     loader_wait_s, 100 * wait_frac,
                     100 * LOADER_WAIT_TRIPWIRE_FRAC)
         if proc0:

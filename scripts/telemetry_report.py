@@ -1,6 +1,5 @@
 #!/usr/bin/env python
-"""Fold telemetry JSONL event streams into the human table and a
-BENCH_*.json-compatible summary.
+"""Fold telemetry JSONL event streams into the human table.
 
   python scripts/telemetry_report.py RUN_DIR              # all ranks' files
   python scripts/telemetry_report.py a/events_rank0.jsonl b/events_rank0.jsonl
@@ -10,35 +9,32 @@ BENCH_*.json-compatible summary.
 
 Accepts any mix of run directories (expanded to every events_rank*.jsonl
 inside — the multi-host layout) and explicit event files; multiple runs
-fold into one aggregate, which is how the bench trajectory accumulates
-across sessions.  Pure host-side JSON folding: no jax import, safe on a
+fold into one aggregate.  Pure host-side JSON folding: no jax import, safe on a
 machine with no accelerator.
 
 The table includes a "recovery event" section (loader/bad_record,
 train/nan_*, train/preempted, checkpoint/retry — zeros included) so
 fault-tolerance triage reads off one block; script/fault_smoke.sh
-asserts on it.  Streams from a serving run (serve.py / bench.py --mode
-serve) additionally get a "serve health" section — requests/batches plus
-the rejection, deadline-exceeded, and post-warmup recompile counters,
-zeros included — which script/serve_smoke.sh asserts on the same way.
+asserts on it.  Streams from a serving run (serve.py) additionally get
+a "serve health" section — requests/batches plus the rejection,
+deadline-exceeded, and post-warmup recompile counters, zeros included.
 Streams from a fabric router (serve.py --fabric) get a "fabric health"
 section on top: membership churn (member_joined / member_evicted /
 member_quarantined), circuit-breaker opens, hedges fired/won, retries,
-partitions, and rolling reloads, zeros included;
-script/fabric_smoke.sh asserts on it.  Streams from a model pool
+partitions, and rolling reloads, zeros included.  Streams from a model pool
 (serve.py --models) get a "model pool" section: weight page-in/out and
 cross-model scheduler counters plus the per-model paging variants,
-zeros included; script/multimodel_smoke.sh asserts on it.
+zeros included.
 
-Streams carrying ``pipeline_cell`` meta rows — a live run of ``bench.py
---mode pipeline``, or its ``--sweep-out`` JSONL passed directly as a
-path — get a "pipeline cell" section: one row per sweep cell (fastest
+Streams carrying ``pipeline_cell`` meta rows — a live run of ``python -m
+mx_rcnn_tpu.train.pipeline``, or its ``--sweep-out`` JSONL passed
+directly as a path — get a "pipeline cell" section: one row per sweep cell (fastest
 first) with imgs/s and the loader_wait / assembly_wait / dispatch
 breakdown, so "which knob moved the needle and where did the time go"
-reads off one table; script/pipeline_smoke.sh asserts on it.
+reads off one table (tests/test_pipeline.py).
 
 Streams carrying ``eval_pipeline`` meta rows (any ``pred_eval`` run —
-test.py, bench.py --mode eval, script/eval_smoke.sh) get an "eval
+test.py) get an "eval
 pipeline" section: one row per eval run with imgs/s, wall time, the
 loader / readback / host-post-process wait split and the overlap
 fraction (how much host post-process hid under the device forward), so

@@ -7,8 +7,7 @@ hot swap, ``--join`` self-registration, ``MXR_FAULT_NET_*`` injectors)
 over the shape-faithful :class:`FakeServePredictor` — no model weights,
 no XLA forward — so ``tests/test_fabric.py`` can drive a real
 ReplicaPool + FabricRouter over real processes and real sockets
-(kill -9, TCP resets, blackholes) in seconds.  ``script/fabric_smoke.sh``
-exercises the same topology with the real model.
+(kill -9, TCP resets, blackholes) in seconds.
 """
 
 import argparse

@@ -22,30 +22,28 @@ Serve-side chaos (ISSUE 8): the injectors themselves live in
 ``mx_rcnn_tpu/serve/replica.py`` (``MXR_FAULT_REPLICA_*``, parsed by
 ``ReplicaFaults`` — package code, same placement rule as the
 ``MXR_FAULT_*`` train injectors above); this module only provides
-:func:`replica_fault_env`, the composer tests and
-``script/replica_smoke.sh`` use to build the env dict for a chosen
-replica index, so the var names have exactly one spelling.
+:func:`replica_fault_env`, the composer tests use to build the env dict
+for a chosen replica index, so the var names have exactly one spelling.
 
 Fabric-side network chaos (ISSUE 12) follows the same split:
 ``MXR_FAULT_NET_{DROP,DELAY_MS,RESET}`` are parsed by ``NetFaults`` in
 ``mx_rcnn_tpu/serve/replica.py`` and injected member-side at the HTTP
 frontend; :func:`net_fault_env` is the composer for
-tests/test_fabric.py and script/fabric_smoke.sh.
+tests/test_fabric.py.
 
 Flywheel capture chaos (ISSUE 13), same split again:
 ``MXR_FAULT_FLYWHEEL_{CORRUPT_SHARD,TRUNCATE_SPILL}`` (value = the
 0-based index of the spilled shard to damage) are parsed by
 ``RequestCapture`` in ``mx_rcnn_tpu/flywheel/capture.py``;
-:func:`flywheel_fault_env` is the composer for tests/test_flywheel.py
-and script/flywheel_smoke.sh.  The damaged shard's replay records then
+:func:`flywheel_fault_env` is the composer for tests/test_flywheel.py.
+The damaged shard's replay records then
 exercise the loader's PR-2 bad-record substitution path.
 
 Fleet-flywheel chaos (ISSUE 17), same split: the fleet fault env vars
 are parsed by package code (``MXR_FAULT_FLYWHEEL_DUP_MANIFEST`` in
 ``flywheel/capture.py``; ``MXR_FAULT_FLYWHEEL_{PARTITION_MINE,
 KILL_TRAIN}`` in ``flywheel/fleet.py``); :func:`fleet_fault_env` is
-the composer for tests/test_flywheel_fleet.py and
-script/flywheel_fleet_smoke.sh."""
+the composer for tests/test_flywheel_fleet.py."""
 
 from __future__ import annotations
 
@@ -58,7 +56,9 @@ import numpy as np
 
 def corrupt_record(roidb: list, i: int) -> list:
     """Make ``roidb[i]`` unloadable: drop inline pixels, point the image
-    path at nothing — ``_load_record`` raises on it."""
+    path at nothing — ``_load_record`` raises on it.  ``AnchorLoader``
+    copies the list it is given, so a fault meant for a built loader goes
+    into ``loader.roidb``, not into the list it was built from."""
     rec = dict(roidb[i])
     rec.pop("image_array", None)
     rec["image"] = "/nonexistent/faults_harness_corrupt.jpg"

@@ -6,8 +6,7 @@ warmup→ready, ``/admin/reload`` hot swap, ``MXR_FAULT_REPLICA_*``
 injectors) over the shape-faithful :class:`FakeServePredictor` — no
 model weights, no XLA forward — so ``tests/test_replica.py`` can drive a
 real supervisor + router over real processes (kill -9, respawn, rolling
-reload) in seconds.  ``script/replica_smoke.sh`` exercises the same
-topology with the real model.
+reload) in seconds.
 
 Hot-reload contract: ``--params-file`` points at a JSON dict of floats;
 a reload target's ``prefix`` names such a file, and ``predict`` scales

@@ -21,8 +21,7 @@ Three layers, mirroring tests/test_fabric.py:
   zero recompiles across the scale events.
 
 Plus the satellite pins: Prometheus ``fabric_member_count{state=...}``
-gauges, loadgen ``--profile`` schedules, perf_gate autoscale rows, and
-dormancy (autoscale off = the fabric byte-for-byte unchanged).
+gauges and dormancy (autoscale off = the fabric byte-for-byte unchanged).
 """
 
 import json
@@ -36,7 +35,7 @@ from mx_rcnn_tpu.serve import autoscaler as ac
 from mx_rcnn_tpu.serve import fabric as fb
 from mx_rcnn_tpu.serve import supervisor as sv
 from tests.test_fabric import (A, B, C, PoolHarness, _cleanup, _e2e_opts,
-                               _free_port, _load_script, _member_proc,
+                               _free_port, _member_proc,
                                _predict_body, _ready_pool, _wait)
 
 
@@ -537,67 +536,6 @@ def test_prometheus_autoscale_pane_when_enabled():
     text = fb.fabric_prometheus(router)
     assert "mxr_autoscale_demand" in text
     assert "mxr_autoscale_hold_total" in text
-
-
-# -- satellite 2: loadgen profiles ------------------------------------------
-
-
-def test_loadgen_profile_schedules():
-    lg = _load_script("loadgen")
-    assert set(lg.PROFILES) == {"diurnal", "flashcrowd"}
-    offs, segs = lg.profile_schedule("diurnal", 100, 10.0)
-    assert len(offs) == 100 and offs == sorted(offs)
-    assert sum(s["requests"] for s in segs) == 100
-    assert [s["rate"] for s in segs] == [4.0, 8.0, 16.0, 8.0, 4.0]
-    assert segs[0]["t0_s"] == 0.0
-    offs, segs = lg.profile_schedule("flashcrowd", 50, 20.0)
-    assert len(offs) == 50
-    assert segs[1]["rate"] == 160.0         # the 8× spike
-    assert segs[1]["rate"] / segs[0]["rate"] == 16.0
-    # rate 0 degenerates to fire-at-once, not a division crash
-    offs, _ = lg.profile_schedule("flashcrowd", 10, 0.0)
-    assert offs == [0.0] * 10
-
-
-# -- satellite 5: perf_gate autoscale rows ----------------------------------
-
-
-def _autoscale_doc(**row_extra):
-    row = {"name": "default", "profile": "flashcrowd",
-           "p99_ms": 120.0, "p99_ceiling_ms": 400.0, "error_rate": 0.0,
-           "fleet": {"start": 1, "peak": 2, "end": 1},
-           "time_to_scale_s": 2.4, "time_to_scale_ceiling_s": 20.0,
-           "scale_floor": 1.0, "recompiles_during_run": 0,
-           "recompile_ceiling": 0.0}
-    row.update(row_extra)
-    return {"schema": "mxr_autoscale_report", "version": 1,
-            "fleet_excess_recompiles": 0, "scenarios": [row]}
-
-
-def test_perf_gate_autoscale_rows(tmp_path):
-    pg = _load_script("perf_gate")
-    path = tmp_path / "AUTOSCALE_r01.json"
-    path.write_text(json.dumps(_autoscale_doc()))
-    rows = {r["metric"]: r for r in pg.load_rows(str(path))}
-    assert rows["autoscale_default_p99_ms"]["ceiling"] == 400.0
-    assert rows["autoscale_default_scale_up"] == {
-        "metric": "autoscale_default_scale_up", "value": 1.0,
-        "unit": "members", "floor": 1.0}
-    assert rows["autoscale_default_time_to_scale_s"]["ceiling"] == 20.0
-    assert rows["autoscale_default_recompiles"]["ceiling"] == 0.0
-    assert rows["autoscale_fleet_excess_recompiles"]["value"] == 0.0
-    assert pg.main(["--dir", str(tmp_path)]) == 0
-    assert pg.main(["--dir", str(tmp_path), "--check-format"]) == 0
-    # one program compiled during the scale event → the gate fails
-    path.write_text(json.dumps(_autoscale_doc(recompiles_during_run=1)))
-    assert pg.main(["--dir", str(tmp_path)]) == 1
-    # the fleet never grew under the flash crowd → the gate fails
-    path.write_text(json.dumps(_autoscale_doc(
-        fleet={"start": 1, "peak": 1, "end": 1})))
-    assert pg.main(["--dir", str(tmp_path)]) == 1
-    # p99 through the scale events over the pinned ceiling → fails
-    path.write_text(json.dumps(_autoscale_doc(p99_ms=900.0)))
-    assert pg.main(["--dir", str(tmp_path)]) == 1
 
 
 # -- dormant-by-default: autoscale off = fleet unchanged --------------------

@@ -13,9 +13,8 @@ escalations in the mix compiles nothing new on either engine or
 registry; (5) escalated frames land in the capture ring tagged
 ``cascade_escalated`` with the big model's records; (6) a tenant with
 ``fidelity="full"`` pins to the big model (and a non-cascade sibling
-bypasses untouched); (7) the whole thing end-to-end under
-``scripts/loadgen.py --cascade`` over a unix socket, producing an
-``mxr_cascade_report`` that ``scripts/perf_gate.py`` expands.
+bypasses untouched); (7) the whole thing end-to-end over a unix socket,
+the responses' provenance and the router's counters agreeing.
 
 The real-model fixture is module-scoped: two synthetic-weight e2e
 engines (distinct config digests — the realistic small/big deployment
@@ -23,9 +22,7 @@ shape on one chip) built once and shared by every gate-path test.
 """
 
 import dataclasses
-import importlib.util
 import json
-import os
 import threading
 
 import numpy as np
@@ -41,17 +38,6 @@ from mx_rcnn_tpu.serve import (CascadeRouter, ModelPool, ServeEngine,
                                make_server, unix_http_request, warmup)
 from tests.test_multimodel import add_fake_model
 from tests.test_serve import make_engine, tiny_cfg
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load_script(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(REPO, "scripts", f"{name}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
 
 def set_thresh(router, t):
     """Retune a live router (what a config push would do); rebuilding a
@@ -322,51 +308,58 @@ def test_fidelity_full_pins_tenant_to_big(cascade_pool):
     assert router.counters["forced_big"] == base_forced + 1
 
 
-# -- (7) two real models e2e under loadgen ---------------------------------
+# -- (7) two real models e2e over a socket ---------------------------------
 
 
-def test_loadgen_cascade_e2e_report(cascade_pool, tmp_path):
+def test_cascade_e2e_over_socket(cascade_pool, tmp_path):
+    """The only test that drives two real models through the cascade over
+    a socket: every request answered, every answer says which model gave
+    it and why, and the router's own counters add up to the traffic."""
+    from benchmark.loadgen import http_unix
+
     pool, router = cascade_pool
     sock = str(tmp_path / "cascade.sock")
     server = make_server(pool.engine_for(), unix_socket=sock, pool=pool,
                          cascade=router)
     th = threading.Thread(target=server.serve_forever, daemon=True)
     th.start()
-    report = str(tmp_path / "CASCADE_r01.json")
-    lg = _load_script("loadgen")
+    rng = np.random.RandomState(11)
+    gated = [json.dumps(encode_image_payload(img)).encode()
+             for img in _mixed_images(rng, 6)]
+    direct = [json.dumps({**encode_image_payload(img), "model": "big"}
+                         ).encode() for img in _mixed_images(rng, 2)]
+    base = dict(router.counters)
+    big_requests = pool.engine_for("big").counters["requests"]
     try:
-        lg.main(["--unix-socket", sock, "--cascade", "--n", "6",
-                 "--rate", "0", "--short", "60", "--long", "100",
-                 "--speedup-floor", "0.05", "--report", report,
-                 "--assert-2xx"])
+        replies = [http_unix(sock, "POST", "/predict", body, timeout=300)
+                   for body in gated + direct]
+        status, metrics = http_unix(sock, "GET", "/metrics")
     finally:
         server.shutdown()
         server.server_close()
 
-    with open(report) as f:
-        doc = json.load(f)
-    assert doc["schema"] == "mxr_cascade_report"
-    by_name = {s["name"]: s for s in doc["scenarios"]}
-    assert set(by_name) == {"big_only", "cascade"}
-    assert by_name["big_only"]["model"] == "big"
-    casc = by_name["cascade"]
-    assert casc["small"] == "small" and casc["big"] == "big"
-    assert casc["requests"] == 6 and casc["error_rate"] == 0.0
-    assert 0.0 <= casc["escalation_rate"] <= 1.0
-    assert casc["agreement"] is not None
-    assert 0.0 <= casc["agreement"] <= 1.0
-    assert casc["speedup_vs_big"] > 0
-    assert casc["speedup_floor"] == 0.05
-    assert set(casc["classes"]) == {"answered_small", "escalated"}
-
-    # the gate consumes the report: floors present, escalation_rate
-    # validated (bare row — a traffic property, not a build property)
-    pg = _load_script("perf_gate")
-    rows = pg.cascade_report_rows(doc)
-    by_metric = {r["metric"]: r for r in rows}
-    assert by_metric["cascade_speedup_vs_big"]["floor"] == 0.05
-    assert by_metric["cascade_cascade_p99_ms"]["direction"] == "down"
-    assert by_metric["cascade_big_only_p99_ms"]["direction"] == "down"
-    assert "cascade_cascade_escalation_rate" in by_metric
-    assert "floor" not in by_metric["cascade_cascade_escalation_rate"]
-    assert "direction" not in by_metric["cascade_cascade_escalation_rate"]
+    assert [st for st, _ in replies] == [200] * 8
+    provs = [doc["cascade"] for _, doc in replies]
+    for prov in provs[:6]:      # gated: the hardness decided
+        assert prov["model"] in ("small", "big")
+        assert prov["escalated"] == (prov["model"] == "big")
+        assert prov["thresh"] == router.thresh
+        assert 0.0 <= prov["hardness"] < HARDNESS_MAX
+    for prov in provs[6:]:      # addressed to the big model: never gated
+        assert prov == {"model": "big", "escalated": False,
+                        "reason": "addressed"}
+    escalated = sum(p["escalated"] for p in provs)
+    delta = {k: router.counters[k] - base[k] for k in base}
+    assert delta["escalated"] == escalated
+    assert delta["answered_small"] == 6 - escalated
+    assert delta["escalation_rejected"] == 0
+    assert delta["gate_batches"] >= 1
+    # the big engine saw the escalations and the two addressed to it
+    assert (pool.engine_for("big").counters["requests"] - big_requests
+            == escalated + 2)
+    # and /metrics carries the router's own account of the same traffic
+    assert status == 200
+    assert metrics["cascade"]["counters"] == dict(router.counters)
+    assert metrics["cascade"]["small"] == "small"
+    assert metrics["cascade"]["big"] == "big"
+    assert 0.0 <= metrics["cascade"]["escalation_rate"] <= 1.0

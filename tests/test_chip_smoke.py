@@ -210,21 +210,3 @@ def test_build_native_replaces_a_stale_library_and_fails_loudly(
     with pytest.raises(subprocess.CalledProcessError):
         chip_smoke.build_native()
     assert not so.exists()   # and no stale binary is left to load
-
-
-def test_loadgen_import_leaves_the_backend_uninitialised():
-    # the client may run beside a server that holds the chip: importing
-    # it pulls jax in (through mx_rcnn_tpu.serve.frontend) but must never
-    # initialise a back end
-    code = ("import runpy, sys; sys.argv = ['loadgen.py', '--help']\n"
-            "try:\n"
-            "    runpy.run_path('scripts/loadgen.py', run_name='__main__')\n"
-            "except SystemExit:\n"
-            "    pass\n"
-            "from jax._src import xla_bridge\n"
-            "assert 'jax' in sys.modules\n"
-            "assert not xla_bridge.backends_are_initialized()\n")
-    env = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
-    proc = subprocess.run([sys.executable, "-c", code], cwd=REPO, env=env,
-                          capture_output=True, text=True, timeout=120)
-    assert proc.returncode == 0, proc.stderr[-2000:]

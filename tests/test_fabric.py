@@ -16,12 +16,9 @@ Three layers, mirroring tests/test_replica.py:
   retry keeps availability; ``MXR_FAULT_NET_RESET`` trips a breaker
   that closes after recovery; ``MXR_FAULT_NET_DROP`` partitions the
   majority away and the reachable subset keeps serving; a rolling
-  remote reload lands with zero non-2xx.  ``script/fabric_smoke.sh``
-  repeats the topology with the real model.
+  remote reload lands with zero non-2xx.
 """
 
-import argparse
-import importlib.util
 import json
 import os
 import socket
@@ -42,14 +39,6 @@ from tests.faults import net_fault_env
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER = os.path.join(REPO, "tests", "fabric_worker.py")
-
-
-def _load_script(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(REPO, "scripts", f"{name}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 @pytest.fixture(autouse=True)
@@ -174,28 +163,6 @@ def test_build_child_argv_strips_fabric_flags():
     assert "--serve-batch 4" in joined
     assert out[-4:] == ["--unix-socket", "/tmp/r0.sock",
                         "--replica-index", "0"]
-
-
-def test_choose_mode_dispatch_keeps_fork_plane_bit_identical():
-    import serve
-
-    def ns(**kw):
-        base = dict(replica_index=-1, replicas=1, fabric=False,
-                    pool_file="", join="")
-        base.update(kw)
-        return argparse.Namespace(**base)
-
-    # with every fabric flag dormant, the pre-fabric decision tree
-    assert serve.choose_mode(ns()) == "single"
-    assert serve.choose_mode(ns(replicas=4)) == "plane"
-    assert serve.choose_mode(ns(replicas=4, replica_index=2)) == "replica"
-    # opt-in paths
-    assert serve.choose_mode(ns(fabric=True)) == "fabric"
-    assert serve.choose_mode(ns(pool_file="/p")) == "fabric"
-    assert serve.choose_mode(ns(join="h:1")) == "member"
-    assert serve.choose_mode(ns(fabric=True, replicas=2)) == "fabric"
-    # child check stays FIRST even under fabric flags
-    assert serve.choose_mode(ns(fabric=True, replica_index=0)) == "replica"
 
 
 # -- pool state machine (scripted probes, fake clock) -----------------------
@@ -727,42 +694,6 @@ def test_fabric_prometheus_survives_evicted_member():
     # the evicted member's gauges drop; the survivor's still render
     assert "queue_depth_age_s_10_0_0_1:8000" not in text
     assert "queue_depth_age_s_10_0_0_2:8000" in text
-
-
-# -- satellite gates: loadgen member share + perf_gate fabric rows ----------
-
-
-def test_loadgen_member_share_diff():
-    lg = _load_script("loadgen")
-    share = lg.member_share({A: 10, B: 0}, {A: 30, B: 10, C: 5})
-    assert share == {A: 0.5714, B: 0.2857, C: 0.1429}
-    assert lg.member_share({}, {}) == {}
-
-
-def test_perf_gate_fabric_floor_rows(tmp_path):
-    pg = _load_script("perf_gate")
-
-    def write(agg, per, n=3, **extra):
-        doc = {"schema": "mxr_fabric_report", "version": 1,
-               "members": n, "aggregate_imgs_per_sec": agg,
-               "per_member_imgs_per_sec": per, **extra}
-        (tmp_path / "FABRIC_r01.json").write_text(json.dumps(doc))
-
-    write(27.0, 10.0)                        # linearity 0.9 ≥ 0.85
-    assert pg.main(["--dir", str(tmp_path)]) == 0
-    assert pg.main(["--dir", str(tmp_path), "--check-format"]) == 0
-    write(18.0, 10.0)                        # 0.6 < 0.85 → fail
-    assert pg.main(["--dir", str(tmp_path)]) == 1
-    write(18.0, 10.0, linearity_floor=0.5)   # CPU smoke's own floor
-    assert pg.main(["--dir", str(tmp_path)]) == 0
-    # the fabric-specific property: availability UNDER partition
-    write(27.0, 10.0, availability_under_partition=0.85)
-    assert pg.main(["--dir", str(tmp_path)]) == 1   # < 0.90 default
-    write(27.0, 10.0, availability_under_partition=0.95,
-          availability=0.92, availability_floor=0.9)
-    assert pg.main(["--dir", str(tmp_path)]) == 0
-    write(27.0, 10.0, availability=0.85, availability_floor=0.9)
-    assert pg.main(["--dir", str(tmp_path)]) == 1
 
 
 def test_telemetry_report_fabric_health_section(tmp_path):

@@ -398,50 +398,6 @@ def test_flywheel_counters_render_as_report_table(tmp_path):
         assert name in FLYWHEEL_COUNTERS
 
 
-# -- loadgen capture check + perf gate rows --------------------------------
-
-
-def test_loadgen_capture_check_failure_logic():
-    sys.path.insert(0, os.path.join(REPO, "scripts"))
-    try:
-        from loadgen import capture_check_failure
-    finally:
-        sys.path.pop(0)
-    # exact match, strided sampling, and within-tolerance all pass
-    assert capture_check_failure({"captured": 0}, {"captured": 10,
-                                 "sample_every": 1}, 10, 0.1) is None
-    assert capture_check_failure({"captured": 5}, {"captured": 9,
-                                 "sample_every": 3}, 12, 0.1) is None
-    # silent capture loss fails loudly
-    msg = capture_check_failure({"captured": 0}, {"captured": 2,
-                                "sample_every": 1}, 10, 0.1)
-    assert msg and "captured delta 2" in msg
-    # a capture-off target is itself a smoke-script bug
-    assert "no flywheel section" in capture_check_failure({}, {}, 10, 0.1)
-
-
-def test_perf_gate_flywheel_floor_rows(tmp_path):
-    sys.path.insert(0, os.path.join(REPO, "scripts"))
-    try:
-        import perf_gate as pg
-    finally:
-        sys.path.pop(0)
-    doc = {"schema": "mxr_flywheel_report", "captured": 40, "mined": 8,
-           "generation_before": 0, "generation_after": 1}
-    rows = {r["metric"]: r for r in pg.flywheel_report_rows(doc)}
-    assert rows["flywheel_mined_fraction"]["value"] == pytest.approx(0.2)
-    assert rows["flywheel_mined_fraction"]["floor"] == 0.01
-    assert rows["flywheel_reload_generations"]["value"] == 1.0
-    assert rows["flywheel_reload_generations"]["floor"] == 1.0
-    path = tmp_path / "FLYWHEEL_r01.json"
-    path.write_text(json.dumps(doc))
-    assert {r["metric"] for r in pg.load_rows(str(path))} == set(rows)
-    # a stalled loop (no generation advance) sits under the floor
-    stalled = pg.flywheel_report_rows(dict(doc, generation_after=0))
-    gen = [r for r in stalled if r["metric"] == "flywheel_reload_generations"]
-    assert gen[0]["value"] < gen[0]["floor"]
-
-
 # -- closed loop -----------------------------------------------------------
 
 

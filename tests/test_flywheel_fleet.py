@@ -23,7 +23,6 @@ Four layers, mirroring the subsystem split:
   member ever serves it.
 """
 
-import importlib.util
 import itertools
 import json
 import os
@@ -55,14 +54,6 @@ from tests.test_serve import tiny_cfg as serve_cfg
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 WORKER = os.path.join(REPO, "tests", "fabric_worker.py")
-
-
-def _load_script(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(REPO, "scripts", f"{name}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 @pytest.fixture(autouse=True)
@@ -481,46 +472,7 @@ def test_eval_shard_quality_scores_live_engine(tmp_path):
         engine.stop()
 
 
-# -- report / gate / loadgen plumbing --------------------------------------
-
-
-def test_perf_gate_fleet_rows_additive():
-    pg = _load_script("perf_gate")
-    r01 = {"schema": "mxr_flywheel_report", "captured": 100, "mined": 10,
-           "generation_before": 0, "generation_after": 1}
-    rows = pg.flywheel_report_rows(r01)
-    assert [r["metric"] for r in rows] == [
-        "flywheel_mined_fraction", "flywheel_reload_generations"]
-    r02 = dict(r01, generation_promoted=1, promotion_gate_pass=1,
-               drift_detected=0)
-    rows = pg.flywheel_report_rows(r02)
-    by = {r["metric"]: r for r in rows}
-    assert by["flywheel_generation_promoted"]["value"] == 1.0
-    assert by["flywheel_generation_promoted"]["floor"] == \
-        pg.FLYWHEEL_PROMOTED_FLOOR
-    assert by["flywheel_promotion_gate_pass"]["value"] == 1.0
-    assert "floor" not in by["flywheel_promotion_gate_pass"]
-    assert by["flywheel_drift_detected"]["value"] == 0.0
-    # a stalled loop fails the floor
-    stalled = dict(r02, generation_promoted=0)
-    row = {r["metric"]: r for r in pg.flywheel_report_rows(stalled)}[
-        "flywheel_generation_promoted"]
-    assert row["value"] < row["floor"]
-
-
-def test_loadgen_folds_fabric_member_flywheel_sections():
-    lg = _load_script("loadgen")
-    single = {"flywheel": {"captured": 7, "sample_every": 2}}
-    assert lg.fold_flywheel_sections(single) == {"captured": 7,
-                                                 "sample_every": 2}
-    fabric = {"engines": {
-        "127.0.0.1:1": {"flywheel": {"captured": 3, "sample_every": 1}},
-        "127.0.0.1:2": {"flywheel": {"captured": 5, "sample_every": 2}},
-        "127.0.0.1:3": {"status": "evicted"}}}
-    assert lg.fold_flywheel_sections(fabric) == {"captured": 8,
-                                                 "sample_every": 2}
-    assert lg.fold_flywheel_sections({"engines": {}}) == {}
-    assert lg.fold_flywheel_sections({}) == {}
+# -- report plumbing ---------------------------------------------------------
 
 
 def test_flywheel_counters_table_has_fleet_rows():

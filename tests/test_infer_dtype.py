@@ -3,12 +3,14 @@ zero-steady-state-recompile, int8 structural sanity.
 
 The bf16 "variant" casts float params to bfloat16 host-side and casts
 outputs back to f32 in-program; compute is already COMPUTE_DTYPE (bf16
-by default), so the only delta vs the f32 path is weight storage — the
-parity tolerances below pin that delta.  Parity is detection-RECORD
-matching, not tensor allclose: every confident f32 detection must have
-a bf16 twin (same class, score within 0.04, box within 4 px) and vice
-versa, the invariant a serving swap to ``--infer-dtype bfloat16``
-actually relies on.
+by default), so the only delta vs the f32 path is weight storage.  Its
+parity is decided the way the benchmark decides ``correct``
+(``benchmark/compare.py``, imported, not copied): every bf16 detection
+record is matched by IoU to the f32 path's dense candidate of its class,
+a response is read by its median record, and the two gaps must stay
+under limits that lie between this variant's readings over six seeds
+and a coarser control's (``test_bf16_parity_and_per_dtype_steady_state``
+has the numbers).  The int8 tests below keep their own coarser pins.
 """
 
 import dataclasses
@@ -30,7 +32,6 @@ from mx_rcnn_tpu.train.checkpoint import denormalize_for_save
 
 SCORE_MARGIN = 0.03   # dets this close to THRESH may flip in/out — skip
 SCORE_ATOL = 0.04
-BBOX_ATOL_PX = 4.0
 
 
 def tiny_cfg():
@@ -43,36 +44,84 @@ def tiny_cfg():
     return cfg.replace(network=net, tpu=tpu)
 
 
-def records_for(pred, cfg, img):
-    """Offline path on one image, self-padded to batch 2 (the serve
-    batch shape, so the engine-warmed programs are reused)."""
+def conditioned_cfg():
+    """The benchmark's tiny size (``tests/benchmark_checks/tiny.py``):
+    ResNet-50 C4, COCO's 81 classes, 9 anchors, a 96x128 bucket, 300 -> 30
+    proposals, the pixel scale ``--synthetic`` sets."""
+    cfg = generate_config(
+        "resnet50", "coco", tpu__SCALES=((96, 128),),
+        TEST__RPN_PRE_NMS_TOP_N=300, TEST__RPN_POST_NMS_TOP_N=30)
+    return cfg.replace(network=dataclasses.replace(
+        cfg.network, PIXEL_STDS=(127.0, 127.0, 127.0)))
+
+
+def conditioned_params(cfg, seed):
+    """The benchmark's weights: activations O(1) through the frozen-BN
+    trunk, decisive logits, box deltas of a tenth.  A plain random
+    init saturates every score and amplifies one rounding step into tens
+    of pixels on one corner, so two dtypes' detections are not
+    comparable on it (a 4 px corner tolerance on such weights was red
+    from PR 21 to PR 30)."""
+    from benchmark import weights
+
+    net = {"depth": "resnet50", "num_classes": cfg.NUM_CLASSES,
+           "num_anchors": cfg.network.NUM_ANCHORS}
+    return jax.tree.map(np.asarray, weights.as_tree(weights.make(net, seed)))
+
+
+def dense_and_records(pred, cfg, img):
+    """One image through the offline path, self-padded to batch 2:
+    ((scores, boxes) of the valid RoIs — every class, before the
+    per-class NMS — and the record list after it)."""
     prepared, im_info = prepare_image(img, cfg, cfg.tpu.SCALES[0])
     rois, valid, scores, deltas, _ = [
         np.asarray(jax.device_get(x)) for x in pred.predict(
             np.stack([prepared, prepared]), np.stack([im_info, im_info]))]
     boxes = decode_image_boxes(rois[0], deltas[0], im_info)
-    return detections_to_records(per_class_nms(
+    records = detections_to_records(per_class_nms(
         scores[0], boxes, valid[0], cfg.NUM_CLASSES,
         cfg.TEST.THRESH, cfg.TEST.NMS, cfg.TEST.MAX_PER_IMAGE))
+    return (scores[0][valid[0]], boxes[valid[0]]), records
 
 
-def assert_matched(src, dst, thresh, tag):
-    """Every confident det in ``src`` has a twin in ``dst``."""
-    for r in src:
-        if r["score"] < thresh + SCORE_MARGIN:
-            continue
-        twins = [s for s in dst
-                 if s["cls"] == r["cls"]
-                 and abs(s["score"] - r["score"]) < SCORE_ATOL
-                 and np.allclose(s["bbox"], r["bbox"], atol=BBOX_ATOL_PX)]
-        assert twins, (tag, r, dst)
+def records_for(pred, cfg, img):
+    """The record list alone (the serve batch shape, so the engine-warmed
+    programs are reused)."""
+    return dense_and_records(pred, cfg, img)[1]
+
+
+def float8_weights(params):
+    """The coarser control: every float leaf rounded to float8_e4m3
+    under a per-tensor scale — 3 mantissa bits where bfloat16 keeps 7."""
+    import jax.numpy as jnp
+
+    def rounded(x):
+        scale = np.abs(x).max() / 448.0    # e4m3's largest finite value
+        if scale == 0:
+            return x
+        q = jnp.asarray(x / scale).astype(jnp.float8_e4m3fn)
+        return np.asarray(q.astype(jnp.float32) * scale, x.dtype)
+
+    return jax.tree.map(rounded, params)
+
+
+# Readings on this configuration over seeds 1-6, four images a seed, 400
+# records each, the three structure counts 0 in every run (CPU, PR 30):
+#   bfloat16 weights   box_gap 0.0023-0.0038   score_gap 0.0058-0.0084
+#   float8 control     box_gap 0.0169-0.2020   score_gap 0.0433-0.0528
+#   (int8 weights      box_gap 0.0061-0.0078   score_gap 0.0160-0.0192)
+# Each limit lies 2.1-2.3 x over the variant's largest reading and as far
+# under the control's least.
+BF16_LIMITS = {"records": 100, "box_gap": 0.008, "score_gap": 0.019,
+               "order_faults": 0, "low_scores": 0, "nms_faults": 0}
 
 
 def test_bf16_parity_and_per_dtype_steady_state():
-    cfg = tiny_cfg()
+    from benchmark.compare import compare, judge
+
+    cfg = conditioned_cfg()
     model = build_model(cfg)
-    params = denormalize_for_save(
-        init_params(model, cfg, jax.random.PRNGKey(0), 2, (96, 128)), cfg)
+    params = conditioned_params(cfg, seed=1)
 
     pred32 = Predictor(model, params, cfg)
     pred16 = Predictor(model, params, cfg, dtype="bfloat16")
@@ -97,13 +146,24 @@ def test_bf16_parity_and_per_dtype_steady_state():
         assert engine.metrics()["dtype"] == "bfloat16"
         assert engine.metrics()["compile"]["dtype"] == "bfloat16"
 
-        # parity on the warmed shapes: confident detections must match
-        # 1:1 between the f32 and bf16 variants, both directions
-        for img in images:
-            r32 = records_for(pred32, cfg, img)
-            r16 = records_for(pred16, cfg, img)
-            assert_matched(r32, r16, cfg.TEST.THRESH, "f32->bf16")
-            assert_matched(r16, r32, cfg.TEST.THRESH, "bf16->f32")
+        # parity on the warmed shapes: the bf16 records against the f32
+        # path's dense candidates, and the control in bf16's place
+        images += [rng.randint(0, 255, (h, w, 3), dtype=np.uint8)
+                   for h, w in ((72, 110), (80, 120))]
+        net = {"test_nms": cfg.TEST.NMS, "test_thresh": cfg.TEST.THRESH}
+        dense = [dense_and_records(pred32, cfg, img)[0] for img in images]
+
+        def numbers(pred):
+            sample = [{"detections": dense_and_records(pred, cfg, img)[1]}
+                      for img in images]
+            return compare(sample, dense, net)
+
+        ok, compared = judge(numbers(pred16), BF16_LIMITS)
+        assert ok, compared
+        # the f32 programs again, float8 weights: no new compile
+        control = Predictor(model, float8_weights(params), cfg)
+        ok, compared = judge(numbers(control), BF16_LIMITS)
+        assert not ok, compared
     finally:
         engine.stop()
 

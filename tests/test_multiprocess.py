@@ -29,18 +29,23 @@ from __future__ import annotations
 
 import os
 import re
+import shutil
 import subprocess
 import sys
+import time
 
 import numpy as np
 
 WORKER = os.path.join(os.path.dirname(__file__), "mp_worker.py")
-# generous: the round-4 single-phase run measured 860 s under heavy CPU
-# load on a single-core host (both ranks compile the full train step
-# concurrently); the three-phase worker adds two more train-step compiles
-# per rank (resume reuses the phase-1 program via the per-rank persistent
-# cache, k=2 compiles the scanned multi-step program)
-TIMEOUT = 3600
+# One limit for the whole test — the two ranks, then the control — well
+# under the suite's own (1,470 s for every file together): past it the
+# test fails alone, where a longer wait would have the suite cut and
+# every later test lost.  Readings on this 8-core box (PR 30): 166 s with
+# the per-rank compile caches warm, 961 s with them cold beside six busy
+# cores (each rank compiles the train step, the resumed one and the k=2
+# scanned one), so a cold run under the suite's six workers may fail once
+# and leaves the caches warm for the next.
+DEADLINE_S = 900
 
 PHASES = ("PHASE1", "PHASE2", "PHASE3")
 
@@ -73,7 +78,14 @@ def _free_port() -> int:
         return s.getsockname()[1]
 
 
-def test_two_process_fit_matches_single_process(tmp_path):
+def _run_all(tmp_path):
+    """-> (the two ranks' outputs, the control's), all three exited 0
+    inside the one deadline."""
+    t_end = time.monotonic() + DEADLINE_S
+
+    def left():
+        return max(t_end - time.monotonic(), 1)
+
     port = _free_port()
     mp_ckpt = str(tmp_path / "mp2")  # ranks SHARE this prefix (orbax
     # writes from the primary host, barriers on both)
@@ -81,7 +93,7 @@ def test_two_process_fit_matches_single_process(tmp_path):
     outs = []
     try:
         for i, p in enumerate(workers):
-            out, _ = p.communicate(timeout=TIMEOUT)
+            out, _ = p.communicate(timeout=left())
             outs.append(out.decode())
         for i, p in enumerate(workers):
             assert p.returncode == 0, f"rank {i} failed:\n{outs[i][-4000:]}"
@@ -92,12 +104,24 @@ def test_two_process_fit_matches_single_process(tmp_path):
 
     control_p = _run(0, 1, port, str(tmp_path / "ctl"))
     try:
-        out, _ = control_p.communicate(timeout=TIMEOUT)
+        out, _ = control_p.communicate(timeout=left())
     finally:
         if control_p.poll() is None:
             control_p.kill()
     control_out = out.decode()
     assert control_p.returncode == 0, control_out[-4000:]
+    return outs, control_out
+
+
+def test_two_process_fit_matches_single_process(tmp_path):
+    try:
+        outs, control_out = _run_all(tmp_path)
+    finally:
+        # 811 MB of checkpoints a run, and pytest keeps the last three
+        # runs' directories: the driver's run of PR 29 failed here on
+        # "No space left on device" (OS error 28) while the control saved
+        shutil.rmtree(tmp_path / "mp2", ignore_errors=True)
+        shutil.rmtree(tmp_path / "ctl", ignore_errors=True)
 
     # 16 imgs / global batch 8 = 2 steps per epoch in every phase
     want_step = {"PHASE1": 2, "PHASE2": 4, "PHASE3": 2}

@@ -6,12 +6,11 @@ Prometheus rendering + the rank-0 obs server folding peer snapshot files
 publishing through the same snapshot files a real peer process would),
 the flight recorder through real ``fit`` runs (NaN halt and SIGTERM via
 ``tests/faults.py``), and the Chrome trace export (nesting + JSON round
-trip).  Satellites ride along: gauge min/max/last exposure, the serve
-frontend's content negotiation, and ``scripts/perf_gate.py``.
+trip).  Satellites ride along: gauge min/max/last exposure and the serve
+frontend's content negotiation.
 """
 
 import glob
-import importlib.util
 import json
 import os
 import pathlib
@@ -402,62 +401,6 @@ def test_report_cli_trace_flag(tmp_path):
     assert r.returncode == 0, r.stderr
     doc = json.load(open(out))
     assert len(doc["traceEvents"]) > 0
-
-
-# -- perf gate -------------------------------------------------------------
-
-
-def _perf_gate():
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate", str(REPO / "scripts" / "perf_gate.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def _bench_file(path, n, vs, metric="m", **extra):
-    row = {"metric": metric, "value": 10.0 * n, "unit": "imgs/sec",
-           "vs_baseline": vs, **extra}
-    with open(path, "w") as f:
-        json.dump({"n": n, "cmd": "bench", "rc": 0, "tail": "",
-                   "parsed": row}, f)
-
-
-def test_perf_gate_passes_and_fails(tmp_path):
-    pg = _perf_gate()
-    for i, vs in enumerate([1.0, 1.2, 1.19], 1):  # within 10% of best
-        _bench_file(tmp_path / f"BENCH_r0{i}.json", i, vs)
-    assert pg.main(["--dir", str(tmp_path)]) == 0
-    _bench_file(tmp_path / "BENCH_r04.json", 4, 1.0)  # >10% below 1.2
-    assert pg.main(["--dir", str(tmp_path)]) == 1
-
-
-def test_perf_gate_skips_baseline_recorded_and_methods(tmp_path):
-    pg = _perf_gate()
-    _bench_file(tmp_path / "BENCH_r01.json", 1, 1.5)
-    _bench_file(tmp_path / "BENCH_r02.json", 2, None,
-                baseline_recorded=True)  # null ratio: recorded, not scored
-    # a method switch resets the comparison group — 1.0 after a
-    # cross-method 1.5 is not a regression
-    _bench_file(tmp_path / "BENCH_r03.json", 3, 1.0,
-                baseline_method="chain")
-    assert pg.main(["--dir", str(tmp_path)]) == 0
-
-
-def test_perf_gate_check_format(tmp_path):
-    pg = _perf_gate()
-    _bench_file(tmp_path / "BENCH_r01.json", 1, 1.0)
-    assert pg.main(["--check-format", "--dir", str(tmp_path)]) == 0
-    with open(tmp_path / "BENCH_r02.json", "w") as f:
-        json.dump({"rc": 0, "tail": "no parsed row"}, f)
-    assert pg.main(["--check-format", "--dir", str(tmp_path)]) == 1
-
-
-def test_perf_gate_checked_in_trajectory():
-    # the repo's own BENCH_*.json must stay gate- and format-clean
-    pg = _perf_gate()
-    assert pg.main(["--check-format", "--dir", str(REPO)]) == 0
-    assert pg.main(["--dir", str(REPO)]) == 0
 
 
 # -- serve frontend content negotiation ------------------------------------

@@ -1,6 +1,6 @@
 """Pipeline composer + autotuner (``train/pipeline.py``): sweep mechanics
 over injected fake step functions (no model build — the real-model path
-is covered by script/pipeline_smoke.sh), per-cell breakdown fields, the
+is ``python -m mx_rcnn_tpu.train.pipeline``), per-cell breakdown fields, the
 sweep JSONL → telemetry-report round trip, tuned-cell persistence, and
 ``--tuned-pipeline`` boot precedence (explicit user flags win)."""
 

@@ -14,8 +14,7 @@ Three layers, mirroring the subsystem split:
   subprocesses (``tests/replica_worker.py``): kill -9 one of two
   replicas mid-burst and observe failover + respawn; roll a hot reload
   through the plane under traffic with zero dropped 2xx-eligible
-  requests.  ``script/replica_smoke.sh`` repeats this with the real
-  model.
+  requests.
 """
 
 import dataclasses
@@ -650,34 +649,6 @@ def test_make_reloader_validates_target():
         assert engine.generation == 5
     finally:
         engine.stop()
-
-
-def test_perf_gate_replica_linearity_and_availability_floors(tmp_path):
-    import importlib.util
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    spec = importlib.util.spec_from_file_location(
-        "perf_gate", os.path.join(repo, "scripts", "perf_gate.py"))
-    pg = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(pg)
-
-    def write(agg, per, n=2, **extra):
-        doc = {"schema": "mxr_replica_report", "version": 1,
-               "replicas": n, "aggregate_imgs_per_sec": agg,
-               "per_replica_imgs_per_sec": per, **extra}
-        (tmp_path / "REPLICA_r01.json").write_text(json.dumps(doc))
-
-    write(18.0, 10.0)                        # linearity 0.9 ≥ 0.85 default
-    assert pg.main(["--dir", str(tmp_path)]) == 0
-    assert pg.main(["--dir", str(tmp_path), "--check-format"]) == 0
-    write(12.0, 10.0)                        # 0.6 < 0.85 → gate fails
-    assert pg.main(["--dir", str(tmp_path)]) == 1
-    # the CPU smoke pins its own floor (replicas share one host's cores)
-    write(12.0, 10.0, linearity_floor=0.5)
-    assert pg.main(["--dir", str(tmp_path)]) == 0
-    write(18.0, 10.0, availability=0.8, availability_floor=0.9)
-    assert pg.main(["--dir", str(tmp_path)]) == 1
-    write(18.0, 10.0, availability=0.95, availability_floor=0.9)
-    assert pg.main(["--dir", str(tmp_path)]) == 0
 
 
 # -- end-to-end chaos: real supervisor over real subprocesses ---------------

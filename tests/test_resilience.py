@@ -144,8 +144,8 @@ def test_step_checkpoint_roundtrip_and_resume_point(tmp_path):
 
 
 def test_bad_record_substituted_and_counted(tmp_path):
-    cfg, roidb, loader = tiny_data(n_images=8)
-    corrupt_record(roidb, 2)
+    cfg, _, loader = tiny_data(n_images=8)
+    corrupt_record(loader.roidb, 2)
     telemetry.configure(str(tmp_path), rank=0, world=1)
     try:
         batches = list(loader)
@@ -162,9 +162,9 @@ def test_bad_record_substituted_and_counted(tmp_path):
 
 
 def test_systemic_breakage_raises():
-    cfg, roidb, loader = tiny_data(n_images=8)
-    for i in range(len(roidb)):
-        corrupt_record(roidb, i)
+    cfg, _, loader = tiny_data(n_images=8)
+    for i in range(len(loader.roidb)):
+        corrupt_record(loader.roidb, i)
     with pytest.raises(RuntimeError, match="systemic"):
         list(loader)
 

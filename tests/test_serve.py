@@ -204,6 +204,68 @@ def test_post_nms_native_counts_the_images_the_library_served(
         assert len(dets) == 1
 
 
+def test_pyramid_network_served_by_the_native_call_equals_the_numpy_loop():
+    """The second benchmark configuration's served path at a tiny size
+    (ResNet-50-FPN, a 128x192 bucket, 500 -> 60 proposals, batch 2):
+    every image's per-class NMS is the one native call
+    (``post_nms_native`` == ``served``), the records are those of the
+    Python loop over the numpy NMS on the same read-back, and the level
+    counters of ``_roi_level_counts`` add up to ``rois_valid``."""
+    import jax
+
+    from mx_rcnn_tpu import native
+    from mx_rcnn_tpu.eval import Predictor
+    from mx_rcnn_tpu.models import build_model, init_params
+    from mx_rcnn_tpu.train.checkpoint import denormalize_for_save
+
+    assert native.available("mxr_nms_classes")
+    cfg = generate_config(
+        "resnet50_fpn", "coco", tpu__SCALES=((128, 192),),
+        TEST__RPN_PRE_NMS_TOP_N=500, TEST__RPN_POST_NMS_TOP_N=60)
+    assert cfg.network.HAS_FPN
+    model = build_model(cfg)
+    params = denormalize_for_save(
+        init_params(model, cfg, jax.random.PRNGKey(0), 2, (128, 192)), cfg)
+    pred = Predictor(model, params, cfg)
+    engine = ServeEngine(pred, cfg, ServeOptions(
+        batch_size=2, max_delay_ms=1.0, max_queue=16)).start()
+    rng = np.random.RandomState(11)
+    images = [rng.randint(0, 255, (h, w, 3), dtype=np.uint8)
+              for h, w in ((80, 120), (64, 100), (72, 96))]
+    try:
+        # one at a time: each batch is the image and its own copy as the
+        # padding row, which is what the offline call below is given
+        served = [engine.submit(img).result(timeout=600) for img in images]
+        # a turn books its counters after it has set its answers
+        deadline = time.monotonic() + 30
+        while (engine.metrics()["counters"]["served"] < 3
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
+        counters = engine.metrics()["counters"]
+    finally:
+        engine.stop()
+
+    assert counters["served"] == counters["post_nms_native"] == 3
+    assert counters["rois_valid"] > 0
+    assert counters["rois_valid"] == sum(
+        counters[f"rois_level_p{lvl}"] for lvl in (2, 3, 4, 5))
+    assert counters["post_candidates"] > counters["post_kept"] > 0
+    valid_seen = 0
+    for img, dets in zip(images, served):
+        prepared, im_info = prepare_image(img, cfg, cfg.tpu.SCALES[0])
+        rois, valid, scores, deltas, _ = [
+            np.asarray(jax.device_get(x)) for x in pred.predict(
+                np.stack([prepared, prepared]),
+                np.stack([im_info, im_info]))]
+        valid_seen += int(valid[0].sum())
+        assert dets == detections_to_records(per_class_nms(
+            scores[0], decode_image_boxes(rois[0], deltas[0], im_info),
+            valid[0], cfg.NUM_CLASSES, cfg.TEST.THRESH, cfg.TEST.NMS,
+            cfg.TEST.MAX_PER_IMAGE, nms_fn=numpy_nms))
+        assert dets
+    assert counters["rois_valid"] == valid_seen
+
+
 def test_full_bucket_flushes_before_older_partial():
     cfg = tiny_cfg()
     engine = make_engine(cfg, batch_size=4, max_delay_ms=300.0)

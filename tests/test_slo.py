@@ -1,7 +1,6 @@
 """SLO layer tier-1 tests (CPU, no network): the histogram primitive,
-the engine's latency/policy surface, the SLO controller's control law
-(driven deterministically through the injectable-``now`` ``tick``), and
-the loadgen/perf_gate SLO report contract.
+the engine's latency/policy surface, and the SLO controller's control law
+(driven deterministically through the injectable-``now`` ``tick``).
 
 The controller tests run against a real ``ServeEngine`` over the
 ``FakePredictor`` from ``test_serve`` — no model, no compile — and feed
@@ -9,9 +8,7 @@ the engine's own histograms directly, which is exactly the interface the
 controller consumes in production.
 """
 
-import importlib.util
 import json
-import os
 
 import numpy as np
 import pytest
@@ -24,21 +21,11 @@ from mx_rcnn_tpu.telemetry.report import aggregate, load_events
 
 from tests.test_serve import make_engine, raw_image, tiny_cfg
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
 
 @pytest.fixture(autouse=True)
 def _restore_sink():
     yield
     telemetry.shutdown()
-
-
-def _load_script(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(REPO, "scripts", f"{name}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 # -- histogram primitive ---------------------------------------------------
@@ -358,65 +345,3 @@ def test_controller_start_stop_restores_policy():
         assert engine._admit_limit is None
     engine.stop()
 
-
-# -- loadgen scenarios + the SLO report ------------------------------------
-
-
-def test_loadgen_schedule_profiles():
-    lg = _load_script("loadgen")
-    steady = lg.schedule("steady", 8, 4.0)
-    assert steady == pytest.approx([i / 4.0 for i in range(8)])
-    bursty = lg.schedule("bursty", 8, 4.0, burst=4)
-    assert bursty == pytest.approx([0.0] * 4 + [1.0] * 4)
-    # same average rate: both finish their arrivals in the same span
-    assert max(bursty) <= max(steady)
-    assert lg.schedule("steady", 3, 0.0) == [0.0] * 3  # burst-everything
-
-
-def test_loadgen_summarize_and_assert_2xx_message():
-    lg = _load_script("loadgen")
-    # (status, latency_s, queue_wait_ms, error_str, t_done_s)
-    results = [(200, 0.010, 5.0, None, 0.10),
-               (200, 0.020, 6.0, None, 0.90),
-               (503, 0.001, None, None, 0.20),
-               (0, 0.5, None, "ConnectionRefusedError: x", 0.50)]
-    out = lg.summarize(results, wall=1.0)
-    assert out["requests"] == 4 and out["error_rate"] == 0.5
-    assert out["status"] == {"0": 1, "200": 2, "503": 1}
-    assert out["p50_ms"] is not None and out["imgs_per_sec"] == 2.0
-    # availability excludes the shed 503 from the denominator: 2/3
-    assert out["availability"] == pytest.approx(2 / 3, abs=1e-4)
-    # transport error at 0.50 → first 2xx completion after it at 0.90
-    assert out["time_to_recover_s"] == pytest.approx(0.4, abs=1e-3)
-    msg = lg.assert_2xx_failure(results)
-    assert "2/4" in msg and "1x status 503" in msg
-    assert "1x transport error" in msg and "ConnectionRefusedError" in msg
-    assert lg.assert_2xx_failure([(200, 0.01, 1.0, None, 0.01)]) is None
-    # never hard-failed → no recovery metric; all-2xx availability is 1.0
-    clean = lg.summarize([(200, 0.01, 1.0, None, 0.01)], wall=1.0)
-    assert clean["availability"] == 1.0
-    assert clean["time_to_recover_s"] is None
-
-
-def test_perf_gate_slo_rows(tmp_path):
-    pg = _load_script("perf_gate")
-
-    def write(i, p99, err):
-        doc = {"schema": "mxr_slo_report", "version": 1, "scenarios": [
-            {"name": "bursty", "requests": 64, "status": {"200": 64},
-             "p50_ms": 20.0, "p99_ms": p99, "error_rate": err,
-             "imgs_per_sec": 30.0, "wall_s": 2.0}]}
-        (tmp_path / f"SLO_r0{i}.json").write_text(json.dumps(doc))
-
-    write(1, 50.0, 0.0)
-    write(2, 52.0, 0.01)          # within threshold + slack: fine
-    assert pg.main(["--dir", str(tmp_path)]) == 0
-    assert pg.main(["--dir", str(tmp_path), "--check-format"]) == 0
-    write(3, 120.0, 0.30)         # p99 blowup + dropped bursts
-    assert pg.main(["--dir", str(tmp_path)]) == 1
-    # error_rate uses the absolute slack: 0 → 0.015 alone must NOT fail
-    for f in tmp_path.glob("SLO_r*.json"):
-        f.unlink()
-    write(1, 50.0, 0.0)
-    write(2, 50.0, 0.015)
-    assert pg.main(["--dir", str(tmp_path)]) == 0

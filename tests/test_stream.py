@@ -13,10 +13,8 @@ Runs against the shape-faithful FakePredictor — the gate's jit is the
 only compiled program, tiny on CPU.
 """
 
-import importlib.util
 import io
 import json
-import os
 
 import numpy as np
 
@@ -28,16 +26,6 @@ from mx_rcnn_tpu.serve import (StaleSeqError, StreamManager, StreamOptions,
                                run_stream_stdio, unix_http_request)
 from mx_rcnn_tpu.serve.frontend import unix_http_request_raw
 from tests.test_serve import FakePredictor, make_engine, raw_image, tiny_cfg
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load_script(name):
-    spec = importlib.util.spec_from_file_location(
-        name, os.path.join(REPO, "scripts", f"{name}.py"))
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 def _mgr(engine, **opts):
@@ -377,68 +365,7 @@ def test_run_stream_stdio_round_trip():
     assert replies[0]["seq"] == 1 and replies[2]["seq"] == 2
 
 
-# -- satellite gates: perf_gate rows + telemetry report section -------------
-
-
-def test_perf_gate_stream_rows_floor_and_ceiling(tmp_path):
-    pg = _load_script("perf_gate")
-    doc = {"schema": "mxr_stream_report", "version": 1, "scenarios": [
-        {"name": "static", "streams": 4, "frames_sent": 128,
-         "p99_ms": 120.0, "error_rate": 0.0, "frames_dropped": 0,
-         "dispatches_per_frame": 0.2, "skip_fraction": 0.8,
-         "skip_fraction_floor": 0.5, "p99_ceiling_ms": 500.0},
-        {"name": "pan", "streams": 4, "frames_sent": 128,
-         "p99_ms": 150.0, "error_rate": 0.0, "frames_dropped": 1,
-         "dispatches_per_frame": 1.0},
-    ]}
-    rows = {r["metric"]: r for r in pg.stream_report_rows(doc)}
-    assert rows["stream_static_p99_ms"]["ceiling"] == 500.0
-    assert rows["stream_static_skip_fraction"]["floor"] == 0.5
-    assert rows["stream_static_dispatches_per_frame"]["direction"] == "down"
-    assert (rows["stream_static_dispatches_per_frame"]["abs_slack"]
-            == pg.STREAM_DPF_ABS_SLACK)
-    # no ceiling pinned → ordinary trend row, scored against history
-    assert rows["stream_pan_p99_ms"]["direction"] == "down"
-    assert "skip_fraction" not in {m.rsplit("_", 1)[-1] for m in rows
-                                   if m.startswith("stream_pan")}
-
-    path = tmp_path / "STREAM_r01.json"
-    path.write_text(json.dumps(doc))
-    assert pg.main(["--dir", str(tmp_path)]) == 0
-    assert pg.main(["--dir", str(tmp_path), "--check-format"]) == 0
-
-    # ceiling is scored on the newest run ALONE — one bad run fails
-    doc["scenarios"][0]["p99_ms"] = 600.0
-    path.write_text(json.dumps(doc))
-    assert pg.main(["--dir", str(tmp_path)]) == 1
-
-    # so is the skip_fraction floor
-    doc["scenarios"][0]["p99_ms"] = 120.0
-    doc["scenarios"][0]["skip_fraction"] = 0.3
-    path.write_text(json.dumps(doc))
-    assert pg.main(["--dir", str(tmp_path)]) == 1
-
-
-def test_perf_gate_bench_stream_series_are_separate(tmp_path):
-    """bench --mode serve stream metrics ride as their OWN series —
-    never scored against the request/response imgs_per_sec rows."""
-    pg = _load_script("perf_gate")
-    doc = {"n": 1, "cmd": "bench --mode serve --serve-stream", "rc": 0,
-           "parsed": {"mode": "serve", "metric": "serve_fused",
-                      "imgs_per_sec": 10.0, "p50_ms": 90.0, "p99_ms": 120.0,
-                      "dispatches_per_frame": 0.3, "skip_fraction": 0.9,
-                      "vs_baseline": None}}
-    (tmp_path / "BENCH_r08.json").write_text(json.dumps(doc))
-    rows = pg.load_rows(str(tmp_path / "BENCH_r08.json"))
-    metrics = {r["metric"]: r for r in rows}
-    dpf = metrics["serve_fused_dispatches_per_frame"]
-    assert dpf["direction"] == "down" and "vs_baseline" not in dpf
-    sf = metrics["serve_fused_skip_fraction"]
-    assert sf["floor"] == pg.BENCH_SKIP_FRACTION_FLOOR
-    assert pg.main(["--dir", str(tmp_path)]) == 0
-    doc["parsed"]["skip_fraction"] = 0.2  # below the floor
-    (tmp_path / "BENCH_r08.json").write_text(json.dumps(doc))
-    assert pg.main(["--dir", str(tmp_path)]) == 1
+# -- telemetry report section ---------------------------------------------
 
 
 def test_telemetry_report_streaming_section(tmp_path):

@@ -330,27 +330,6 @@ def test_trace_query_merges_dedupes_and_renders(tmp_path):
     assert orphan in roots and len(roots) == 2
 
 
-def test_loadgen_trace_helpers_and_perf_gate_rows(tmp_path):
-    lg = _load_script("loadgen")
-    ok = (200, 0.01, 0.0, None, 0.1)
-    bad = (200, 0.01, 0.0,
-           "trace echo mismatch: sent aa, got None", 0.1)
-    assert lg.trace_echo_failure([ok, ok]) is None
-    msg = lg.trace_echo_failure([ok, bad])
-    assert msg and "trace echo assertion failed" in msg
-    pg = _load_script("perf_gate")
-    doc = {"schema": "mxr_slo_report",
-           "scenarios": [{"name": "steady", "p50_ms": 10.0, "p99_ms": 30.0,
-                          "error_rate": 0.0, "traced": 12, "tail_kept": 2}]}
-    rows = {r["metric"]: r for r in pg.slo_report_rows(doc)}
-    assert rows["slo_steady_traced"]["value"] == 12
-    assert rows["slo_steady_tail_kept"]["value"] == 2
-    # the report file passes --check-format with the additive fields
-    path = tmp_path / "SLO_r01.json"
-    path.write_text(json.dumps(doc))
-    assert pg.check_format([str(path)]) == []
-
-
 # -- end to end: one trace id across a real two-member fabric ---------------
 
 

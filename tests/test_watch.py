@@ -21,10 +21,9 @@ Three layers, mirroring tests/test_autoscale.py:
   tail-sampled trace ids attached, and a restart on the same address
   resolves it — the full arc persisted in ``alerts_router.jsonl``.
 
-Plus the satellite pins: ``mxr_alert_state`` exposition format,
-perf_gate ``mxr_watch_report`` rows, loadgen ``--watch-check``
-semantics, and dormancy (watch off = fabric metrics, exposition and
-telemetry JSONL byte-for-byte unchanged).
+Plus the satellite pins: ``mxr_alert_state`` exposition format and
+dormancy (watch off = fabric metrics, exposition and telemetry JSONL
+byte-for-byte unchanged).
 """
 
 import glob
@@ -43,7 +42,7 @@ from mx_rcnn_tpu.telemetry.watch import (MetricHistory, RuleError,
                                          fingerprint, fleet_from_pool,
                                          load_rules, validate_rules)
 from tests.test_fabric import (A, B, _cleanup, _e2e_opts, _free_port,
-                               _load_script, _member_proc, _predict_body,
+                               _member_proc, _predict_body,
                                _ready_pool, _wait)
 
 
@@ -553,71 +552,6 @@ def test_fleet_from_pool_normalizes_the_member_view():
     assert doc["fleet/parked"] == 0.0
     m = doc["members"][A]
     assert m["ready"] is True and m["queue_depth"] == 3.0
-
-
-# -- satellite: perf_gate mxr_watch_report rows -----------------------------
-
-
-def _watch_doc(**kw):
-    base = {"schema": "mxr_watch_report", "version": 1,
-            "clean_fired": 0, "firing_at_end": 0, "rule_errors": 0,
-            "fault_fired": 2, "fault_resolved": 2, "fault_trace_ids": 3,
-            "transitions": 9}
-    base.update(kw)
-    return base
-
-
-def test_perf_gate_watch_report_rows(tmp_path):
-    pg = _load_script("perf_gate")
-    path = tmp_path / "WATCH_r01.json"
-    path.write_text(json.dumps(_watch_doc()))
-    rows = {r["metric"]: r for r in pg.load_rows(str(path))}
-    assert rows["watch_clean_fired"]["ceiling"] == 0.0
-    assert rows["watch_firing_at_end"]["ceiling"] == 0.0
-    assert rows["watch_rule_errors"]["ceiling"] == 0.0
-    assert rows["watch_fault_fired"]["floor"] == 1.0
-    assert rows["watch_fault_resolved"]["floor"] == 1.0
-    assert rows["watch_fault_trace_ids"]["floor"] == 1.0
-    assert rows["watch_transitions"]["value"] == 9.0
-    assert "floor" not in rows["watch_transitions"]
-    assert pg.main(["--dir", str(tmp_path)]) == 0
-    assert pg.main(["--dir", str(tmp_path), "--check-format"]) == 0
-    # an alert fired under clean traffic → the gate fails
-    path.write_text(json.dumps(_watch_doc(clean_fired=1)))
-    assert pg.main(["--dir", str(tmp_path)]) == 1
-    # the injected fault never fired / never carried traces → fails
-    path.write_text(json.dumps(_watch_doc(fault_fired=0,
-                                          fault_trace_ids=0)))
-    assert pg.main(["--dir", str(tmp_path)]) == 1
-    # a stuck alert at run end → fails
-    path.write_text(json.dumps(_watch_doc(firing_at_end=1)))
-    assert pg.main(["--dir", str(tmp_path)]) == 1
-
-
-# -- satellite: loadgen --watch-check ---------------------------------------
-
-
-def test_loadgen_watch_check_semantics():
-    lg = _load_script("loadgen")
-    doc = {"firing": [{"alert": "a"}],
-           "resolved": [{"alert": "b"}],
-           "silenced": [{"alert": "c", "state": "firing"},
-                        {"alert": "d", "state": "pending"}]}
-    firing, fired = lg.watch_alert_names(doc)
-    assert firing == ["a"]
-    # fired covers resolved and silenced-while-firing — a silence
-    # hides the page, not the fact
-    assert fired == ["a", "b", "c"]
-    # a watch-off target fails loudly
-    assert "no /alerts route" in lg.watch_check_failure({}, [])
-    # clean contract: nothing may have fired at all
-    clean = {"firing": [], "resolved": [], "silenced": []}
-    assert lg.watch_check_failure(clean, []) is None
-    assert "expected a clean pass" in lg.watch_check_failure(doc, [])
-    # expectations: every named alert fired, nothing stray still firing
-    assert lg.watch_check_failure(doc, ["a", "b", "c"]) is None
-    assert "expected ['z']" in lg.watch_check_failure(doc, ["z", "a"])
-    assert "still firing" in lg.watch_check_failure(doc, ["b"])
 
 
 # -- end-to-end: kill a REAL member, the router watchtower pages ------------

@@ -13,12 +13,12 @@ Runs in-process (the reference shells out per stage); each stage reuses the
 previous stage's params exactly like the reference's load_param chain.
 
 ``--tuned-pipeline`` (tools/common.config_from_args) applies the persisted
-input-pipeline cell from ``bench.py --mode pipeline --auto-tune`` before
-any stage runs; ``stage_args`` copies of ``args`` carry the tuned
-``steps_per_dispatch`` into every fit-based stage, and the tuned loader
-knobs (workers/prefetch/device-prep) ride the shared ``cfg``.  Proposal
-stages (2/5) go through TestLoader, which always uses the host
-preprocessing path regardless of ``--device-prep``.
+input-pipeline cell from ``python -m mx_rcnn_tpu.train.pipeline
+--auto-tune`` before any stage runs; ``stage_args`` copies of ``args``
+carry the tuned ``steps_per_dispatch`` into every fit-based stage, and
+the tuned loader knobs (workers/prefetch/device-prep) ride the shared
+``cfg``.  Proposal stages (2/5) go through TestLoader, which always uses
+the host preprocessing path regardless of ``--device-prep``.
 """
 
 from __future__ import annotations
