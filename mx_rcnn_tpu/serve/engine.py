@@ -19,8 +19,16 @@ Mechanics:
   ``batch_size`` requests, or when its oldest request has waited
   ``max_delay_ms`` (the latency/throughput knob — 0 serves singletons
   immediately, larger values trade head-of-line latency for fill).
-  Partial batches are padded with repeats of the last request (the
-  TestLoader recipe) and the padding rows are masked out of responses.
+  A batch is assembled where its images are made: each bucket fills
+  reused **staging batches** — arrays of the predict program's whole
+  input shape, allocated and written once — and ``submit`` copies its
+  prepared image into its own row on the caller's thread.  A batch is
+  the live requests of the bucket's OLDEST staging batch; rows past them
+  (a partial batch, an expired or failed request) keep whatever an
+  earlier batch left there and reach no response.  The staging batch
+  returns to its bucket's free list once the turn that ran it is over,
+  after the read-back of its outputs: jax reads the host buffer after
+  ``predict`` has returned.
 * Backpressure is a bounded queue: ``submit`` beyond ``max_queue``
   raises :class:`RejectedError` (the frontend's 503) instead of letting
   latency grow without bound.  Per-request deadlines are swept before
@@ -49,7 +57,9 @@ on, and a ``jax.profiler.TraceAnnotation`` of the same name, so a
 ``jax.profiler`` trace of the running server shows them on the Python
 threads' line beside the device's ops.  Dispatcher thread, flat siblings
 with no enclosing annotation: ``serve/idle`` (the wait when nothing is
-due), ``serve/assemble``, ``serve/forward`` (h2d + enqueue),
+due), ``serve/assemble`` (a hand-over: the wait for a row of the claimed
+staging batch whose copy is still running on its caller's thread, at most
+one row copy; no allocation, no copy), ``serve/forward`` (h2d + enqueue),
 ``serve/readback`` (the wait for the device + d2h), per image
 ``serve/post/decode`` (``decode_image_boxes`` on the real requests' rows:
 numpy on the arrays the readback brought, no device array and no program)
@@ -70,7 +80,12 @@ counters ``post_candidates`` / ``post_kept`` (÷ ``served``: how much the
 host post-process is handed an image, and whether ``TEST.MAX_PER_IMAGE``
 binds) and ``post_nms_native`` (the images whose per-class NMS was the one
 native call of ``ops/postprocess.per_class_nms``: equal to ``served``, or 0
-where the library did not build and the Python loop ran), on a pyramid
+where the library did not build and the Python loop ran),
+``staged_rows`` (rows a caller's thread wrote into a staging batch: equal
+to ``requests``), ``assemble_waits`` (rows whose copy was still running
+when a turn claimed their batch, and was waited for) and ``staging_allocs`` (staging batches ever
+allocated: flat after warm-up, like ``recompiles``; the sink also gets
+the gauge ``serve/staging_free``), on a pyramid
 network ``rois_valid`` and ``rois_level_p2`` … ``rois_level_p5`` (the
 proposals the joint NMS kept and the level the FPN
 paper's eq. 1 pools each from, counted on the host by the legacy path) and
@@ -210,13 +225,16 @@ class ServeFuture:
 class _Request:
     __slots__ = ("image", "im_info", "t_enqueue", "deadline", "bucket",
                  "future", "raw_hw", "ratio", "orig_hw", "staged",
-                 "staged_hw", "stream", "trace", "rid")
+                 "staged_hw", "stream", "trace", "rid", "staging", "row")
 
     def __init__(self, image, im_info, t_enqueue, deadline, bucket=None,
                  raw_hw=None, ratio=None, orig_hw=None, staged=None,
                  staged_hw=None, stream=None, trace=None):
         self.image = image          # bucket-padded network input, or (in
-        # serve_e2e mode) the STAGED raw uint8 bucket array
+        # serve_e2e mode) the STAGED raw uint8 bucket array; the request's
+        # own array, never a view into a staging batch.  Dropped once its
+        # row is written, except in serve_e2e mode, where capture and the
+        # cascade's submit_staged read it after the batch
         self.im_info = im_info
         self.t_enqueue = t_enqueue  # monotonic
         self.deadline = deadline    # monotonic instant or None
@@ -239,7 +257,33 @@ class _Request:
         self.rid = None             # per-engine request id, assigned at
         # flush time ONLY for batches carrying a traced request — the
         # batch-causality key ("my request shared a dispatch with rids X")
+        self.staging = None         # the _Staging batch it has a row of,
+        self.row = None             # and which (None again: its copy raised)
         self.future = ServeFuture()
+
+
+class _Staging:
+    """One reused host batch of a bucket: the predict program's whole input
+    ``(B,) + prepared.shape`` in the prepared image's dtype, and the
+    ``(B, 3)`` ``im_info`` beside it.  Rows are handed out in order under
+    the engine's lock and written by the threads that prepared the images;
+    every field but the arrays' contents is guarded by that lock."""
+
+    __slots__ = ("images", "im_info", "taken", "pending", "claimed",
+                 "retired")
+
+    def __init__(self, batch: int, image: np.ndarray, im_info):
+        self.images = np.empty((batch,) + image.shape, image.dtype)
+        # written once, here: every page resident before a turn reads it
+        self.images.fill(0)
+        # rows nobody writes still hold a real image's im_info (a zero
+        # scale would send infs through the padding rows' boxes)
+        self.im_info = np.tile(np.asarray(im_info), (batch, 1))
+        self.taken = 0         # rows handed out since it was last opened
+        self.pending = 0       # of those, copies that have not finished
+        self.claimed = False   # a turn has it: the dispatcher waits on it
+        self.retired = False   # out of the line and done with: on the free
+        # list, or going there with its last pending copy
 
 
 def _roi_level_counts(rois: np.ndarray, roi_valid: np.ndarray) -> dict:
@@ -272,6 +316,16 @@ class ServeEngine:
         # one (short, long) pair, two orientation buckets
         self._scale = cfg.tpu.SCALES[0]
         self._queues: Dict[Tuple[int, int], List[_Request]] = {}
+        # staging batches (module docstring): per bucket the ones that hold
+        # queued requests, oldest first — only the last can have rows left
+        # to hand out — and the free ones.  A bucket never has more than
+        # _staging_cap (what max_queue can hold, the open one and the one
+        # in flight), 103-120 MB each at the benchmark's sizes
+        self._staging: Dict[Tuple[int, int], List[_Staging]] = {}
+        self._staging_free: Dict[Tuple[int, int], List[_Staging]] = {}
+        self._staging_n: Dict[Tuple[int, int], int] = {}
+        self._staging_cap = -(-self.opts.max_queue
+                              // self.opts.batch_size) + 2
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
         self._stop = False
@@ -322,6 +376,11 @@ class ServeEngine:
                          # images whose per-class NMS was the one native
                          # call (== served; 0 = no library, the loop ran)
                          "post_nms_native": 0,
+                         # staging batches: rows written by their callers
+                         # (== requests), rows a turn had to wait for,
+                         # batches ever allocated (flat once warm)
+                         "staged_rows": 0, "assemble_waits": 0,
+                         "staging_allocs": 0,
                          "host_prep_ms_total": 0.0,
                          # stream-aware flush bookkeeping: batches that
                          # carried >= 1 stream frame, the frame count, and
@@ -425,6 +484,9 @@ class ServeEngine:
             pending = [r for q in self._queues.values() for r in q]
             for q in self._queues.values():
                 q.clear()
+            self._staging.clear()
+            self._staging_free.clear()
+            self._staging_n.clear()
             self._cond.notify_all()
         for r in pending:
             r.future._set_error(RejectedError("engine stopped"))
@@ -650,8 +712,10 @@ class ServeEngine:
     def _enqueue(self, req: _Request, key, tel,
                  prep_s: float = 0.0) -> ServeFuture:
         """Shared admission tail of :meth:`submit` / :meth:`submit_staged`:
-        backpressure + shed checks under the lock, queue insert, counters,
-        work signal."""
+        backpressure + shed checks under the lock, queue insert with the
+        next free row of the bucket's open staging batch, counters; then,
+        outside the lock and on this caller's thread, the copy of the image
+        into that row; work signal."""
         with self._cond:
             if self._stop:
                 self.counters["rejected"] += 1
@@ -682,15 +746,107 @@ class ServeEngine:
                 raise RejectedError(
                     f"queue full ({depth}/{self.opts.max_queue} requests "
                     f"pending) — retry with backoff")
+            if not self._take_row_locked(req, key, tel):
+                # only rows that expired requests left behind get here:
+                # live requests alone cannot fill _staging_cap batches
+                self.counters["rejected"] += 1
+                tel.counter("serve/rejected")
+                raise RejectedError(
+                    f"queue full ({depth}/{self.opts.max_queue} requests "
+                    f"pending, every staging row of bucket {key} taken) "
+                    f"— retry with backoff")
             self._queues.setdefault(key, []).append(req)
             self.counters["requests"] += 1
             self.counters["host_prep_ms_total"] += prep_s * 1e3
             tel.counter("serve/requests")
             tel.gauge("serve/queue_depth", depth + 1)
             self._cond.notify()
+        self._write_row(req, key, tel)
         if self.on_work is not None:
             self.on_work()
         return req.future
+
+    def _take_row_locked(self, req: _Request, key, tel) -> bool:
+        """Give ``req`` the next free row of its bucket's open staging
+        batch, opening one (from the free list, else allocated) when there
+        is none or it is full.  False: the bucket is at ``_staging_cap``."""
+        B = self.opts.batch_size
+        line = self._staging.setdefault(key, [])
+        if not line or line[-1].taken == B:
+            free = self._staging_free.setdefault(key, [])
+            if not free:
+                n = self._staging_n.get(key, 0)
+                if n >= self._staging_cap:
+                    return False
+                # a bucket is born with the three a saturated engine turns
+                # over (in flight, waiting full, filling), during warm-up;
+                # a deeper queue adds one at a time, on its caller's thread
+                more = 1 if n else min(3, self._staging_cap)
+                free.extend(_Staging(B, req.image, req.im_info)
+                            for _ in range(more))
+                self._staging_n[key] = n + more
+                self.counters["staging_allocs"] += more
+                tel.counter("serve/staging_allocs", more)
+            s = free.pop()
+            s.taken = 0
+            s.claimed = s.retired = False
+            line.append(s)
+        s = line[-1]
+        req.staging, req.row = s, s.taken
+        s.taken += 1
+        s.pending += 1
+        return True
+
+    def _write_row(self, req: _Request, key, tel):
+        """The caller's half of batch assembly: copy the prepared image and
+        its ``im_info`` into the request's row, outside the lock (numpy
+        lets go of the GIL for the copy).  A copy that raises fails its own
+        request — out of the queue, its row a padding row — and re-raises,
+        so the dispatcher never waits for a row nobody will write."""
+        s, row = req.staging, req.row
+        try:
+            np.copyto(s.images[row], req.image)
+            s.im_info[row] = req.im_info
+        except BaseException as e:
+            req.row = None
+            req.future._set_error(e)
+            raise
+        finally:
+            with self._cond:
+                s.pending -= 1
+                if req.row is None:
+                    q = self._queues.get(key, [])
+                    if req in q:     # not yet claimed by a turn
+                        q.remove(req)
+                else:
+                    self.counters["staged_rows"] += 1
+                    if not self.opts.serve_e2e:
+                        req.image = None
+                if not s.pending:
+                    if s.retired:
+                        self._retire_locked(key, s)
+                    elif s.claimed:
+                        self._cond.notify_all()
+        tel.counter("serve/staged_rows")
+
+    def _retire_locked(self, key, s: _Staging):
+        """``s`` has left its bucket's line and no turn needs it (any
+        more): to the free list once no copy is running into it — now, or
+        from :meth:`_write_row` with the last of them."""
+        s.retired = True
+        if not s.pending and not self._stop:
+            self._staging_free.setdefault(key, []).append(s)
+
+    def _retire_dead_locked(self, key):
+        """Staging batches at the head of a bucket's line that no queued
+        request is left in (swept by their deadlines) leave it: to the free
+        list now, or with the last copy still running into them."""
+        q, line = self._queues.get(key), self._staging.get(key, [])
+        head = q[0].staging if q else None
+        while line and line[0] is not head:
+            if head is None and line[0].taken < self.opts.batch_size:
+                break   # the open one: its remaining rows are still good
+            self._retire_locked(key, line.pop(0))
 
     def submit_staged(self, staged: np.ndarray, raw_hw, ratio, im_info,
                       orig_hw,
@@ -735,13 +891,17 @@ class ServeEngine:
     # -- dispatch --------------------------------------------------------
 
     def _sweep_expired_locked(self, now: float) -> List[_Request]:
+        """Requests past their deadline leave their queues; each leaves
+        its row behind as a padding row of its staging batch."""
         expired = []
-        for q in self._queues.values():
+        for key, q in self._queues.items():
             live = []
             for r in q:
                 (expired if r.deadline is not None and r.deadline <= now
                  else live).append(r)
-            q[:] = live
+            if len(live) < len(q):
+                q[:] = live
+                self._retire_dead_locked(key)
         return expired
 
     def _next_batch_locked(self, now: float):
@@ -753,7 +913,12 @@ class ServeEngine:
 
         "Full" and "due" are judged per bucket against the controller's
         policy overrides (flush threshold <= opts.batch_size, possibly
-        shortened delay); without a controller both fall back to opts."""
+        shortened delay); without a controller both fall back to opts.
+
+        The batch of a due bucket is the queued requests of its OLDEST
+        staging batch — the head of the queue and whoever follows it in the
+        same one — and claiming it takes that staging batch out of the
+        line, so later arrivals open the next."""
         best_key, best_t, best_full = None, None, False
         wait = None
         for key, q in self._queues.items():
@@ -773,8 +938,13 @@ class ServeEngine:
                 best_key, best_t, best_full = key, head_t, full
         if best_key is not None:
             q = self._queues[best_key]
-            B = self._bucket_batch.get(best_key, self.opts.batch_size)
-            take, q[:] = q[:B], q[B:]
+            self._retire_dead_locked(best_key)
+            s = self._staging[best_key].pop(0)
+            s.claimed = True
+            n = 1
+            while n < len(q) and q[n].staging is s:
+                n += 1
+            take, q[:] = q[:n], q[n:]
             return take, None
         return None, wait
 
@@ -838,7 +1008,13 @@ class ServeEngine:
     def dispatch_batch(self, batch: List[_Request]):
         """Run one batch claimed by :meth:`poll` (external dispatcher's
         half of ``_dispatch_loop``): forwards, fails the batch on error,
-        and releases the inflight slot either way."""
+        and releases the inflight slot and the batch's staging batch either
+        way — the latter only here, after the read-back of the batch's
+        outputs has returned: jax reads the host buffer after ``predict``
+        has returned (and ``_forward_e2e``'s ``device_put`` arrays may
+        alias it until they go), so a staging batch rewritten earlier
+        would corrupt the batch in flight."""
+        key, staging = batch[0].bucket, batch[0].staging
         try:
             self._run_batch(batch, time.monotonic())
         except BaseException as e:  # noqa: BLE001 — fail the batch
@@ -848,7 +1024,10 @@ class ServeEngine:
         finally:
             with self._cond:
                 self._inflight -= 1
+                self._retire_locked(key, staging)
+                free = len(self._staging_free.get(key, ()))
                 self._cond.notify_all()  # drain() waits on this
+            telemetry.get().gauge("serve/staging_free", free)
 
     def _claim_locked(self, now: Optional[float] = None):
         """``(expired, batch, wait_s)`` as of ``now``: sweeps the deadlines
@@ -887,19 +1066,31 @@ class ServeEngine:
 
         tel = telemetry.get()
         B = self.opts.batch_size
-        pad = B - len(reqs)
+        staging = reqs[0].staging
         for r in reqs:
             r.future.queue_wait_s = now - r.t_enqueue
             tel.add("serve/queue_wait", now - r.t_enqueue)
             self.hists["serve/queue_wait"].observe(now - r.t_enqueue)
             tel.observe("serve/queue_wait", now - r.t_enqueue)
-        # pad partial batches with repeats (the TestLoader recipe); the
-        # padded rows never reach a response
+        # the batch was assembled by its callers, row by row: wait for the
+        # copies still running (a request of the last instant; claimed, the
+        # staging batch hands out no more rows) and take the whole array.
+        # Rows past the live ones keep what an earlier batch left there;
+        # they never reach a response
         with self._stage("serve/assemble"):
-            images = np.stack([r.image for r in reqs]
-                              + [reqs[-1].image] * pad)
-            im_info = np.stack([r.im_info for r in reqs]
-                               + [reqs[-1].im_info] * pad)
+            with self._cond:
+                waits = staging.pending
+                while staging.pending:
+                    self._cond.wait()
+                self.counters["assemble_waits"] += waits
+            # a caller whose copy raised has failed its own request
+            reqs = [r for r in reqs if r.row is not None]
+        if waits:
+            tel.counter("serve/assemble_waits", waits)
+        if not reqs:
+            return
+        images, im_info = staging.images, staging.im_info
+        pad = B - len(reqs)
         tel.gauge("serve/batch_fill", len(reqs) / B)
         tel.gauge("serve/pad_ratio", pad / B)
         # each stage is timed once (telemetry.stage); the batch's phase
@@ -1080,7 +1271,8 @@ class ServeEngine:
         records = telemetry.stage("serve/post/records")  # timeline only
         kept = 0
         with self._stage("serve/postprocess", annotate=False) as post:
-            for b, r in enumerate(reqs):
+            for r in reqs:
+                b = r.row
                 with decode:
                     boxes = decode_image_boxes(rois[b], bbox_deltas[b],
                                                np.asarray(r.im_info))
@@ -1096,11 +1288,16 @@ class ServeEngine:
         for st in (decode, nms):
             st.book(self.hists[st.name])
         # the boxes over the threshold that went into the per-class NMS
-        # (per_class_nms's ``sel``, all classes and images at once)
+        # (per_class_nms's ``sel``, all classes and images at once; the
+        # rows no live request has count nothing)
         n = len(reqs)
+        live = np.zeros(len(images), bool)
+        for r in reqs:
+            live[r.row] = True
+        valid = np.asarray(roi_valid, bool) & live[:, None]
         candidates = int(np.count_nonzero(
-            (cls_prob[:n, :, 1:cfg.NUM_CLASSES] > cfg.TEST.THRESH)
-            & np.asarray(roi_valid[:n], bool)[:, :, None]))
+            (cls_prob[:, :, 1:cfg.NUM_CLASSES] > cfg.TEST.THRESH)
+            & valid[:, :, None]))
         nbytes = int(sum(np.asarray(a).nbytes for a in
                          (rois, roi_valid, cls_prob, bbox_deltas)))
         xfer = {"h2d_transfers": 2, "dispatches": 1, "readbacks": 1,
@@ -1111,7 +1308,7 @@ class ServeEngine:
                 "post_nms_native":
                     n if native.available("mxr_nms_classes") else 0}
         if cfg.network.HAS_FPN:
-            xfer.update(_roi_level_counts(rois[:n], roi_valid[:n]))
+            xfer.update(_roi_level_counts(rois, valid))
         return (xfer,
                 {"forward": fwd.seconds, "readback": rb.seconds,
                  "postprocess": post.seconds})
@@ -1128,12 +1325,13 @@ class ServeEngine:
         path (documented in ``ops.postprocess.device_postprocess``)."""
         import jax
 
-        pad = len(staged) - len(reqs)
-        raw_hw = np.stack([np.asarray(r.raw_hw) for r in reqs]
-                          + [np.asarray(reqs[-1].raw_hw)] * pad
-                          ).astype(np.int32)
-        ratio = np.asarray([r.ratio for r in reqs]
-                           + [reqs[-1].ratio] * pad, np.float32)
+        # the small sidecars by row, like the images; rows nobody wrote
+        # get the last request's, so that the device prep sees real sizes
+        raw_hw = np.tile(np.asarray(reqs[-1].raw_hw, np.int32),
+                         (len(staged), 1))
+        ratio = np.full(len(staged), reqs[-1].ratio, np.float32)
+        for r in reqs:
+            raw_hw[r.row], ratio[r.row] = r.raw_hw, r.ratio
         flip = np.zeros(len(staged), bool)  # serve traffic never flips
         cfg = self.cfg
         mpi = int(cfg.TEST.MAX_PER_IMAGE)
@@ -1162,8 +1360,8 @@ class ServeEngine:
                 kind="serve_e2e")
         kept = 0
         with self._stage("serve/postprocess") as post:
-            for b, r in enumerate(reqs):
-                dets_pc = device_dets_to_per_class(dets[b], dvalid[b],
+            for r in reqs:
+                dets_pc = device_dets_to_per_class(dets[r.row], dvalid[r.row],
                                                    cfg.NUM_CLASSES)
                 recs = detections_to_records(dets_pc)
                 kept += len(recs)

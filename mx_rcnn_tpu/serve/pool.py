@@ -728,8 +728,8 @@ class CascadeRouter:
             self.counters["gate_batches"] += 1
         tel.counter("cascade/gate_batches")
         tracer = tracectx.get()
-        for b, r in enumerate(reqs):
-            h = float(hard[b])
+        for r in reqs:
+            h = float(hard[r.row])  # its row of the staging batch
             r.future.hardness = h
             r.future.request = r
             self.hists["cascade/hardness"].observe(h)

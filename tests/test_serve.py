@@ -155,10 +155,11 @@ def test_partial_batch_padded_and_responses_unmasked():
         results = [f.result(timeout=30) for f in futs]
     finally:
         engine.stop()
-    # one forward, padded to the full batch with repeats of the last image
+    # one forward of the whole staging batch: three live rows and one that
+    # nobody wrote (tests/test_serve_staging.py holds what the rows hold)
     assert len(fake.batches) == 1 and fake.batches[0][0] == 4
     # each response carries ITS OWN image's score — row→request mapping
-    # survives the padding (and the padded duplicate rows produce nothing)
+    # survives the padding (and the padding row produces nothing)
     for img, dets in zip(imgs, results):
         prepared, _ = prepare_image(img, cfg, cfg.tpu.SCALES[0])
         assert len(dets) == 1
@@ -233,8 +234,10 @@ def test_pyramid_network_served_by_the_native_call_equals_the_numpy_loop():
     images = [rng.randint(0, 255, (h, w, 3), dtype=np.uint8)
               for h, w in ((80, 120), (64, 100), (72, 96))]
     try:
-        # one at a time: each batch is the image and its own copy as the
-        # padding row, which is what the offline call below is given
+        # one at a time: each batch is the image in row 0 and, in the
+        # padding row, whatever the staging batch held; the model is
+        # per-image, so the offline call below (the image and its own
+        # copy) gives row 0 the same answer
         served = [engine.submit(img).result(timeout=600) for img in images]
         # a turn books its counters after it has set its answers
         deadline = time.monotonic() + 30
@@ -386,7 +389,8 @@ def test_serve_e2e_unix_socket_warm_and_parity(tmp_path):
             served.append(resp["detections"])
 
         # parity: offline path (Predictor + shared postprocess) on the
-        # same pixels — self-padded to the serve batch, like the engine
+        # same pixels — self-padded to the serve batch (the engine's
+        # padding rows hold other pixels; row 0's answer is per-image)
         for img, dets in zip(images, served):
             prepared, im_info = prepare_image(img, cfg, cfg.tpu.SCALES[0])
             rois, valid, scores, deltas, _ = [
