@@ -252,10 +252,14 @@ class Predictor:
             mjit = (jax.jit if plan is None else
                     partial(jax.jit,
                             in_shardings=(plan.replicated(), None, bsh, bsh)))
-            reg.register("masks_from_feats", lambda: mjit(
-                lambda p, feats, boxes, labels: cast_out(model.apply(
+            # a name of its own in the device trace ("jit_mask_branch"):
+            # the benchmark tells this program from predict's by it
+            def mask_branch(p, feats, boxes, labels):
+                return cast_out(model.apply(
                     {"params": unpack(p)}, feats, boxes, labels,
-                    method=model.masks_from_feats))))
+                    method=model.masks_from_feats))
+
+            reg.register("masks_from_feats", lambda: mjit(mask_branch))
 
             def build_packed(hp, wp):
                 from mx_rcnn_tpu.ops.mask_paste import paste_masks
@@ -552,9 +556,18 @@ class Predictor:
                 "call predict() on this batch first"
             self._check_token(token)
             feats = self._feats
-        return self._dispatch("masks_from_feats", boxes.shape,
+        return self._dispatch("masks_from_feats",
+                              self.masks_shape(boxes.shape, feats),
                               self.registry.lookup("masks_from_feats"),
                               feats, boxes, labels)
+
+    @staticmethod
+    def masks_shape(boxes_shape, feats):
+        """The registry shape key of the cached-pyramid mask program: the
+        boxes' (B, R, 4) and P2's (h, w) — one jitted function, one XLA
+        program a bucket orientation.  The serve engine counts its first
+        dispatches by the same key."""
+        return tuple(boxes_shape) + tuple(feats[0].shape[1:3])
 
     def predict_masks_packed(self, boxes, labels, orig_boxes, hp, wp,
                              token, feats=None):
@@ -661,6 +674,24 @@ def paste_mask(prob: np.ndarray, box: np.ndarray, h: int, w: int) -> np.ndarray:
         out[oy1:oy2, ox1:ox2] = (
             resized[oy1 - y1:oy2 - y1, ox1 - x1:ox2 - x1] >= 0.5)
     return out
+
+
+def mask_to_rle(prob: np.ndarray, box: np.ndarray, h: int, w: int,
+                native: bool = True) -> dict:
+    """One final detection's mask as it is answered: the (M, M)
+    probabilities of its class pasted at ``box`` (original frame) into the
+    (h, w) frame and cut at 0.5 -> COCO's uncompressed column-major RLE.
+    ``native``: the fused C++ paste + RLE (``native.paste_rle``); without
+    it, or where the library is absent, :func:`paste_mask` + the numpy
+    encoder.  The evaluator's mask pass and the serve engine both answer
+    through this one function."""
+    from mx_rcnn_tpu.eval.mask_rle import encode
+    from mx_rcnn_tpu.native import paste_rle
+
+    counts = paste_rle(prob, box, h, w) if native else None
+    if counts is not None:
+        return {"size": [h, w], "counts": counts}
+    return encode(paste_mask(prob, box, h, w))
 
 
 def im_detect(predictor: Predictor, batch: dict):
@@ -998,8 +1029,7 @@ def _mask_pass(predictor, batch, dets, all_boxes, all_masks, roidb,
     per-detection cv2 paste (~150 ms/img at the 100-det cap) — the oracle
     the other two are tested against, and the automatic fallback when the
     native library or a duck-typed predictor lacks the fast entry points."""
-    from mx_rcnn_tpu.eval.mask_rle import encode
-    from mx_rcnn_tpu.native import paste_rle, rle_encode_packed
+    from mx_rcnn_tpu.native import rle_encode_packed
 
     if not dets:
         return
@@ -1065,12 +1095,8 @@ def _mask_pass(predictor, batch, dets, all_boxes, all_masks, roidb,
                 np.float32)
 
             def rle_for(b, r, box, h, w):
-                counts = (paste_rle(probs[b, r], box, h, w)
-                          if mode == "native" else None)
-                if counts is not None:
-                    return {"size": [h, w], "counts": counts}
-                return encode(  # "host" mode, or native lib unavailable
-                    paste_mask(probs[b, r], box, h, w))
+                return mask_to_rle(probs[b, r], box, h, w,
+                                   native=mode == "native")
 
         for b in range(B):
             for r, (k, i, di) in enumerate(taken[b]):

@@ -317,12 +317,14 @@ class FPNFasterRCNN(nn.Module):
     def masks_from_feats(self, feats, boxes, labels):
         """Mask branch over precomputed pyramid features: (B, R, 4) boxes +
         (B, R) labels → (B, R, 28, 28) sigmoid probabilities."""
-        pooled14 = self._pool_levels(feats, boxes, pooled=14)
-        mask_logits = self.mask_head(pooled14)
-        sel = jax.nn.one_hot(labels, self.cfg.NUM_CLASSES,
-                             dtype=mask_logits.dtype)
-        logit = jnp.einsum("brhwk,brk->brhw", mask_logits, sel)
-        return jax.nn.sigmoid(logit)
+        with jax.named_scope("fpn/mask_pool"):
+            pooled14 = self._pool_levels(feats, boxes, pooled=14)
+        with jax.named_scope("fpn/mask_head"):
+            mask_logits = self.mask_head(pooled14)
+            sel = jax.nn.one_hot(labels, self.cfg.NUM_CLASSES,
+                                 dtype=mask_logits.dtype)
+            logit = jnp.einsum("brhwk,brk->brhw", mask_logits, sel)
+            return jax.nn.sigmoid(logit)
 
     def predict_masks(self, images, im_info, boxes, labels):
         """Mask branch from raw images (standalone use; eval prefers

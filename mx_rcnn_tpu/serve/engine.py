@@ -67,7 +67,19 @@ numpy on the arrays the readback brought, no device array and no program)
 images) and ``serve/post/records`` (on the timeline only);
 ``serve/postprocess`` (the whole loop) and ``serve/service_time`` (the whole
 turn) are clocks without an annotation; ``serve/h2d`` exists in
-``serve_e2e`` mode.  Request threads: ``frontend/read``,
+``serve_e2e`` mode.  On a mask network (``cfg.network.HAS_MASK``, and only
+there) the turn has a second stage after the post-process
+(:meth:`ServeEngine._mask_stage`): ``serve/mask`` (the whole of it, a clock
+without an annotation) = ``serve/mask/forward`` (the records' boxes and
+classes filled into one ``(B, MAX_PER_IMAGE)`` pair + the mask program's
+dispatch over the pyramid ``predict`` left on the device) +
+``serve/mask/readback`` (the wait for the device + d2h of the ``(B, R, 28,
+28)`` probabilities) + per image ``serve/mask/paste`` (paste into the
+request's raw frame + RLE; one observation a batch), with the counters
+``mask_dispatches``, ``mask_rois`` (live records masked),
+``mask_readback_bytes`` and ``mask_native`` (records pasted by the native
+call) on ``/metrics``; the futures are set at its end, each record with its
+``"segmentation"``.  Request threads: ``frontend/read``,
 ``frontend/decode``, ``serve/host_prep``, and ``frontend/reply`` (on the
 timeline only).  Start-up: ``setup/model`` / ``setup/params`` /
 ``setup/predictor`` (``serve.py::_build_engine``) and ``setup/warmup``
@@ -395,6 +407,21 @@ class ServeEngine:
             # NMS and which level eq. 1 pools each from (_forward_legacy)
             self.counters.update(_roi_level_counts(np.zeros((0, 4)),
                                                    np.zeros((0,), bool)))
+        # a mask network's turn has a second stage (_mask_stage); no
+        # other network's turn, counters or /metrics know of it
+        self._has_mask = bool(cfg.network.HAS_MASK)
+        if self._has_mask:
+            if self.opts.serve_e2e:
+                raise ValueError(
+                    "--serve-e2e cannot serve a mask network "
+                    "(cfg.network.HAS_MASK): the fused single-dispatch "
+                    "path has no mask stage and "
+                    "would answer boxes without their masks; drop "
+                    "--serve-e2e (the default path serves masks)")
+            # mask programs dispatched, live records masked, bytes of
+            # probabilities read back, records pasted by the native call
+            self.counters.update(mask_dispatches=0, mask_rois=0,
+                                 mask_readback_bytes=0, mask_native=0)
         self._pool = None  # prep worker pool (opts.prep_workers > 0)
         # engine-authoritative latency distributions (same contract as
         # self.counters: live even with telemetry off — the controller's
@@ -420,6 +447,10 @@ class ServeEngine:
             self.hists[name] = Hist()
         if self.opts.serve_e2e:
             self.hists["serve/h2d"] = Hist()
+        if self._has_mask:
+            for name in ("serve/mask", "serve/mask/forward",
+                         "serve/mask/readback", "serve/mask/paste"):
+                self.hists[name] = Hist()
         # start-up's split, seconds ("model_s", "params_s", "predictor_s",
         # "warmup_s"): written once by serve.py's _build_engine and warmup()
         self.setup: Dict[str, float] = {}
@@ -1141,6 +1172,10 @@ class ServeEngine:
         if "post_candidates" in xfer:
             tel.counter("serve/post_candidates", xfer["post_candidates"])
             tel.counter("serve/post_nms_native", xfer["post_nms_native"])
+        if "mask_rois" in xfer:
+            tel.counter("serve/mask_dispatches", xfer["mask_dispatches"])
+            tel.counter("serve/mask_rois", xfer["mask_rois"])
+            tel.counter("serve/mask_native", xfer["mask_native"])
         if stream_frames:
             tel.counter("stream/batches")
             tel.counter("stream/batch_frames", stream_frames)
@@ -1208,7 +1243,7 @@ class ServeEngine:
             if disp_sid is None or not phases:
                 continue
             pctx = TraceContext(ctx.trace_id, disp_sid)
-            for ph in ("h2d", "forward", "readback", "postprocess"):
+            for ph in ("h2d", "forward", "readback", "postprocess", "mask"):
                 d = phases.get(ph)
                 if d is not None:
                     tracer.record(pctx, f"engine/{ph}", d)
@@ -1222,9 +1257,10 @@ class ServeEngine:
         the predictor carries one, local shape set otherwise) + the
         recompile counters/meta the SLO machinery watches."""
         if self.registry is not None:
-            first = self.predictor.note_dispatch(shape, kind=kind) \
-                if kind == "serve_e2e" else \
-                self.predictor.note_dispatch(shape)
+            # "serve_predict" is the predictor's default forward program;
+            # any other kind is the registry's own name for it
+            first = self.predictor.note_dispatch(
+                shape, kind=None if kind == "serve_predict" else kind)
         else:
             first = (kind, shape) not in self._seen_shapes
             self._seen_shapes.add((kind, shape))
@@ -1254,6 +1290,10 @@ class ServeEngine:
         with self._stage("serve/forward") as fwd:
             rois, roi_valid, cls_prob, bbox_deltas, _ = \
                 self.predictor.predict(images, im_info)
+            if self._has_mask:
+                # this batch's pyramid, still on the device: the captured
+                # pair stays this batch's whatever predict() runs next
+                feats, _ = self.predictor.capture_feats()
         # the wait for the device + d2h
         with self._stage("serve/readback") as rb:
             rois, roi_valid, cls_prob, bbox_deltas = jax.device_get(
@@ -1270,6 +1310,7 @@ class ServeEngine:
         nms = telemetry.stage("serve/post/nms")
         records = telemetry.stage("serve/post/records")  # timeline only
         kept = 0
+        held = []
         with self._stage("serve/postprocess", annotate=False) as post:
             for r in reqs:
                 b = r.row
@@ -1284,9 +1325,14 @@ class ServeEngine:
                 with records:
                     recs = detections_to_records(dets_pc)
                     kept += len(recs)
-                    r.future._set_result(recs)
+                    if self._has_mask:
+                        held.append((r, recs))   # answered with its masks
+                    else:
+                        r.future._set_result(recs)
         for st in (decode, nms):
             st.book(self.hists[st.name])
+        phases = {"forward": fwd.seconds, "readback": rb.seconds,
+                  "postprocess": post.seconds}
         # the boxes over the threshold that went into the per-class NMS
         # (per_class_nms's ``sel``, all classes and images at once; the
         # rows no live request has count nothing)
@@ -1309,9 +1355,100 @@ class ServeEngine:
                     n if native.available("mxr_nms_classes") else 0}
         if cfg.network.HAS_FPN:
             xfer.update(_roi_level_counts(rois, valid))
-        return (xfer,
-                {"forward": fwd.seconds, "readback": rb.seconds,
-                 "postprocess": post.seconds})
+        if self._has_mask:
+            # last in the turn: once the futures are set, the request
+            # threads serialise 100 count lists a reply under the GIL, and
+            # whatever the dispatcher still did here waited for it (17-20
+            # ms a turn of bookkeeping that takes 3; chip run, PR 32)
+            mask_xfer, phases["mask"] = self._mask_stage(held, feats,
+                                                         len(images), tel)
+            for k, v in mask_xfer.items():
+                xfer[k] = xfer.get(k, 0) + v
+        return xfer, phases
+
+    def _mask_stage(self, held: List[Tuple[_Request, List[dict]]], feats,
+                    n_rows: int, tel) -> Tuple[dict, float]:
+        """The second stage of a mask network's turn, after the host's
+        decode + per-class NMS has chosen each image's final records
+        (``held``: the live requests with their record lists): the records'
+        boxes (scaled frame) and classes go back to the device in ONE
+        ``(B, R, 4)`` / ``(B, R)`` pair — ``R`` = ``TEST.MAX_PER_IMAGE``,
+        padding rows and slots zero — for ONE dispatch of the mask program
+        over the pyramid ``predict`` left there (``feats``), whatever the
+        number of live records, zero included: a static shape, so nothing
+        compiles after warm-up.  Then one read-back of the ``(B, R, M, M)``
+        probabilities, and per record the paste into the request's own raw
+        frame + RLE (``eval.tester.mask_to_rle``, the evaluator's function:
+        ``native.paste_rle``, or cv2 + numpy under ``TEST.MASK_PASTE =
+        "host"`` or without the library).  Each record gains
+        ``"segmentation": {"size": [h, w], "counts": [...]}``; then the
+        futures are set.  An image the score-tie rule left with more than
+        ``R`` records is drained in further passes of the same program.
+
+        Stages: ``serve/mask`` (the whole stage; a clock without an
+        annotation) with ``serve/mask/forward`` (fill + dispatch),
+        ``serve/mask/readback`` (the wait for the device + d2h) and
+        ``serve/mask/paste`` (per image on the timeline, one observation a
+        batch).  -> (the batch's counter increments, the stage's seconds)."""
+        import jax
+
+        from mx_rcnn_tpu.eval.tester import mask_to_rle
+
+        cfg = self.cfg
+        R = cfg.TEST.MAX_PER_IMAGE if cfg.TEST.MAX_PER_IMAGE > 0 else 100
+        use_native = (cfg.TEST.MASK_PASTE != "host"
+                      and native.available("mxr_paste_rle"))
+        shape = self.predictor.masks_shape((n_rows, R, 4), feats)
+        paste = telemetry.stage("serve/mask/paste")
+        passes = rois = 0
+        with self._stage("serve/mask", annotate=False) as whole:
+            start = 0
+            while True:
+                boxes = np.zeros((n_rows, R, 4), np.float32)
+                labels = np.zeros((n_rows, R), np.int32)
+                with self._stage("serve/mask/forward") as fwd:
+                    for r, recs in held:
+                        part = recs[start:start + R]
+                        if part:
+                            boxes[r.row, :len(part)] = np.asarray(
+                                [q["bbox"] for q in part], np.float32) \
+                                * np.float32(r.im_info[2])
+                            labels[r.row, :len(part)] = [q["cls"]
+                                                         for q in part]
+                    first = self._note_first_dispatch(
+                        shape, "masks_from_feats", tel)
+                    probs = self.predictor.predict_masks_cached(
+                        boxes, labels, token=None, feats=feats)
+                with self._stage("serve/mask/readback") as rb:
+                    probs = np.asarray(jax.device_get(probs), np.float32)
+                if first and self.registry is not None:
+                    self.predictor.record_compile_seconds(
+                        shape, fwd.seconds + rb.seconds,
+                        kind="masks_from_feats")
+                for r, recs in held:
+                    h, w = r.orig_hw
+                    with paste:
+                        for i, rec in enumerate(recs[start:start + R]):
+                            rec["segmentation"] = mask_to_rle(
+                                probs[r.row, i], rec["bbox"], h, w,
+                                native=use_native)
+                            rois += 1
+                passes += 1
+                start += R
+                if not any(len(recs) > start for _, recs in held):
+                    break
+            paste.book(self.hists["serve/mask/paste"])
+            for r, recs in held:
+                r.future._set_result(recs)
+        back = passes * int(probs.nbytes)
+        return ({"mask_dispatches": passes, "mask_rois": rois,
+                 "mask_readback_bytes": back,
+                 "mask_native": rois if use_native else 0,
+                 # the turn's boundary crossings, beside predict's
+                 "dispatches": passes, "readbacks": passes,
+                 "readback_bytes": back, "h2d_transfers": 2 * passes,
+                 "h2d_bytes": passes * int(boxes.nbytes + labels.nbytes)},
+                whole.seconds)
 
     def _forward_e2e(self, reqs: List[_Request], staged, im_info,
                      tel) -> Tuple[dict, dict]:

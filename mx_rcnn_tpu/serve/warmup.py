@@ -6,7 +6,10 @@ seconds for the real backbones.  Without warmup the first user request of
 each orientation pays that compile inside its latency budget (and usually
 blows its deadline).  Warmup pushes one full batch of dummy pixels per
 bucket through the REAL engine path — same queue, same padding, same
-post-process — so every program the steady state can dispatch is ready
+post-process, and on a mask network the same second dispatch (the mask
+program runs at its one static shape whatever the number of records, so
+a zero image warms it and it is counted) — so every program the steady
+state can dispatch is ready
 before the frontend accepts traffic, and the engine's recompile counter
 (the program registry's first-dispatch bookkeeping) proves it: after
 warmup, ``counters["recompiles"] == counters["warmup_programs"]`` must
