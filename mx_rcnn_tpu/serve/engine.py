@@ -26,9 +26,29 @@ Mechanics:
   the live requests of the bucket's OLDEST staging batch; rows past them
   (a partial batch, an expired or failed request) keep whatever an
   earlier batch left there and reach no response.  The staging batch
-  returns to its bucket's free list once the turn that ran it is over,
-  after the read-back of its outputs: jax reads the host buffer after
+  returns to its bucket's free list once its batch is finished, after the
+  read-back of that batch's own outputs: jax reads the host buffer after
   ``predict`` has returned.
+* A batch runs in two halves.  Its **launch** (``_launch``): queue-wait
+  bookkeeping, the hand-over of its staging batch, ``predictor.predict``
+  until it returns (on a mask network the batch's pyramid captured) and
+  ``copy_to_host_async`` on the outputs.  Its **finish** (``_finish``): the
+  read-back, the post-process, on a mask network the mask stage over that
+  batch's own pyramid, the futures, counters, hists, trace spans and
+  capture, and — either way, and only there — the release of the inflight
+  slot and of the staging batch.  A **turn** of the dispatcher thread
+  begins with the claim of a due batch, which is launched FIRST; then the
+  flight the turn before left is finished while the device runs the new
+  one.  With nothing due a pending flight is finished at once, inside the
+  turn that launched it, so a lone request is answered in the serial order
+  and the loop never idles with a batch in flight.  At most TWO batches
+  are launched and unfinished at any instant: a constant of the loop, not
+  a knob — how often the overlap engages depends only on what the loop
+  finds in its queue (counter ``overlapped_turns`` ÷ ``batches``; the sink
+  gets the gauge ``serve/in_flight``, 1 or 2, at each launch).  A failure
+  in either half fails that batch's requests only.  ``dispatch_batch``
+  (the external dispatcher's surface) is one batch's launch and finish
+  back to back.
 * Backpressure is a bounded queue: ``submit`` beyond ``max_queue``
   raises :class:`RejectedError` (the frontend's 503) instead of letting
   latency grow without bound.  Per-request deadlines are swept before
@@ -57,16 +77,26 @@ on, and a ``jax.profiler.TraceAnnotation`` of the same name, so a
 ``jax.profiler`` trace of the running server shows them on the Python
 threads' line beside the device's ops.  Dispatcher thread, flat siblings
 with no enclosing annotation: ``serve/idle`` (the wait when nothing is
-due), ``serve/assemble`` (a hand-over: the wait for a row of the claimed
-staging batch whose copy is still running on its caller's thread, at most
-one row copy; no allocation, no copy), ``serve/forward`` (h2d + enqueue),
-``serve/readback`` (the wait for the device + d2h), per image
+due and nothing in flight), ``serve/assemble`` (a hand-over: the wait for
+a row of the claimed staging batch whose copy is still running on its
+caller's thread, at most one row copy; no allocation, no copy),
+``serve/forward`` (h2d + enqueue + the d2h started), ``serve/readback``
+(what is LEFT of the wait for the device when the finish half gets there,
++ what is left of the d2h: near the program's whole run for a lone batch
+or a device-bound turn, a few ms once the host is the slower), per image
 ``serve/post/decode`` (``decode_image_boxes`` on the real requests' rows:
 numpy on the arrays the readback brought, no device array and no program)
 / ``serve/post/nms`` (their ``Hist``s observe once a batch, summed over its
 images) and ``serve/post/records`` (on the timeline only);
-``serve/postprocess`` (the whole loop) and ``serve/service_time`` (the whole
-turn) are clocks without an annotation; ``serve/h2d`` exists in
+``serve/postprocess`` (the whole loop) and ``serve/service_time`` are
+clocks without an annotation.  ``serve/service_time`` is ONE TURN of the
+dispatcher thread — from the claim of a batch to the claim of the next
+(its launch + the finish of the flight before it), or to the end of its
+own finish when nothing else was due — one observation a claimed batch,
+so the turns never overlap and their sum plus ``serve/idle``'s cannot
+pass the wall clock; it is NOT a batch's claim → last response (that is
+``serve/request_time`` less ``serve/queue_wait``, and the tracer's
+``engine/dispatch`` span).  ``serve/h2d`` exists in
 ``serve_e2e`` mode.  On a mask network (``cfg.network.HAS_MASK``, and only
 there) the turn has a second stage after the post-process
 (:meth:`ServeEngine._mask_stage`): ``serve/mask`` (the whole of it, a clock
@@ -95,9 +125,10 @@ native call of ``ops/postprocess.per_class_nms``: equal to ``served``, or 0
 where the library did not build and the Python loop ran),
 ``staged_rows`` (rows a caller's thread wrote into a staging batch: equal
 to ``requests``), ``assemble_waits`` (rows whose copy was still running
-when a turn claimed their batch, and was waited for) and ``staging_allocs`` (staging batches ever
+when a turn claimed their batch, and was waited for), ``staging_allocs`` (staging batches ever
 allocated: flat after warm-up, like ``recompiles``; the sink also gets
-the gauge ``serve/staging_free``), on a pyramid
+the gauge ``serve/staging_free``) and ``overlapped_turns`` (batches
+launched while another was in flight), on a pyramid
 network ``rois_valid`` and ``rois_level_p2`` … ``rois_level_p5`` (the
 proposals the joint NMS kept and the level the FPN
 paper's eq. 1 pools each from, counted on the host by the legacy path) and
@@ -298,6 +329,39 @@ class _Staging:
         # list, or going there with its last pending copy
 
 
+class _Flight:
+    """One batch between the two halves of its turn: launched — its forward
+    enqueued on the device, the d2h of its outputs under way — and not yet
+    finished.  Written by :meth:`ServeEngine._launch`, read by
+    :meth:`ServeEngine._finish`, both on the dispatcher's thread."""
+
+    __slots__ = ("reqs", "key", "staging", "t_claim", "overlapped", "shape",
+                 "first", "outs", "feats", "h2d_bytes", "phases")
+
+    def __init__(self, reqs: List[_Request], t_claim: float):
+        self.reqs = reqs            # the live requests; none: the launch
+        # failed them all, and the finish has only the slot to release
+        self.key, self.staging = reqs[0].bucket, reqs[0].staging
+        self.t_claim = t_claim      # monotonic: taken off the queue
+        self.overlapped = False     # launched while another was in flight
+        self.shape = None           # the program's registry key, and
+        self.first = False          # whether this is its first dispatch
+        self.outs = None            # the forward's outputs, on the device
+        self.feats = None           # a mask network: this batch's pyramid
+        self.h2d_bytes = 0          # serve_e2e: what the one h2d shipped
+        self.phases: Dict[str, float] = {}  # the tracer's phase seconds
+
+
+def _copy_to_host_async(arrays):
+    """Start the d2h of a launched batch's outputs, so the transfer too
+    overlaps the host's work on the batch before; a stub predictor's numpy
+    arrays have nothing to start."""
+    for a in arrays:
+        start = getattr(a, "copy_to_host_async", None)
+        if start is not None:
+            start()
+
+
 def _roi_level_counts(rois: np.ndarray, roi_valid: np.ndarray) -> dict:
     """A batch's valid proposals and how the FPN paper's eq. 1 spreads them
     over P2..P5, as ``models/fpn.py::_assign_level`` writes it (``+1``
@@ -331,13 +395,13 @@ class ServeEngine:
         # staging batches (module docstring): per bucket the ones that hold
         # queued requests, oldest first — only the last can have rows left
         # to hand out — and the free ones.  A bucket never has more than
-        # _staging_cap (what max_queue can hold, the open one and the one
+        # _staging_cap (what max_queue can hold, the open one and the two
         # in flight), 103-120 MB each at the benchmark's sizes
         self._staging: Dict[Tuple[int, int], List[_Staging]] = {}
         self._staging_free: Dict[Tuple[int, int], List[_Staging]] = {}
         self._staging_n: Dict[Tuple[int, int], int] = {}
         self._staging_cap = -(-self.opts.max_queue
-                              // self.opts.batch_size) + 2
+                              // self.opts.batch_size) + 3
         self._lock = threading.Lock()
         self._cond = threading.Condition(self._lock)
         self._stop = False
@@ -356,8 +420,9 @@ class ServeEngine:
         # it while /healthz only proves the process answers
         self._ready = threading.Event()
         # drain mode (weight hot-reload): no NEW admissions, queued work
-        # still flushes; _inflight counts batches handed to the predictor
-        # so drain() can block until the device is quiescent
+        # still flushes; _inflight counts batches claimed and not yet
+        # finished (at most two: _dispatch_loop), so drain() can block
+        # until the device is quiescent and every answer is out
         self._draining = False
         self._inflight = 0
         # checkpoint generation serving right now (atomic under _lock;
@@ -393,6 +458,9 @@ class ServeEngine:
                          # batches ever allocated (flat once warm)
                          "staged_rows": 0, "assemble_waits": 0,
                          "staging_allocs": 0,
+                         # batches launched while another was in flight
+                         # (/ batches: how often the overlap engages)
+                         "overlapped_turns": 0,
                          "host_prep_ms_total": 0.0,
                          # stream-aware flush bookkeeping: batches that
                          # carried >= 1 stream frame, the frame count, and
@@ -404,7 +472,7 @@ class ServeEngine:
                          "stream_coalesced_batches": 0}
         if cfg.network.HAS_FPN:
             # a pyramid network's proposals: how many survived the joint
-            # NMS and which level eq. 1 pools each from (_forward_legacy)
+            # NMS and which level eq. 1 pools each from (_finish_legacy)
             self.counters.update(_roi_level_counts(np.zeros((0, 4)),
                                                    np.zeros((0,), bool)))
         # a mask network's turn has a second stage (_mask_stage); no
@@ -809,10 +877,13 @@ class ServeEngine:
                 n = self._staging_n.get(key, 0)
                 if n >= self._staging_cap:
                     return False
-                # a bucket is born with the three a saturated engine turns
-                # over (in flight, waiting full, filling), during warm-up;
-                # a deeper queue adds one at a time, on its caller's thread
-                more = 1 if n else min(3, self._staging_cap)
+                # a bucket is born with the four a saturated engine turns
+                # over (two in flight, one waiting full, one filling: born
+                # with three, the closed pyramid cell took a fourth in every
+                # run, once inside its window; chip runs, PR 33), during
+                # warm-up; a deeper queue adds one at a time, on its
+                # caller's thread
+                more = 1 if n else min(4, self._staging_cap)
                 free.extend(_Staging(B, req.image, req.im_info)
                             for _ in range(more))
                 self._staging_n[key] = n + more
@@ -1037,28 +1108,11 @@ class ServeEngine:
         return batch, wait
 
     def dispatch_batch(self, batch: List[_Request]):
-        """Run one batch claimed by :meth:`poll` (external dispatcher's
-        half of ``_dispatch_loop``): forwards, fails the batch on error,
-        and releases the inflight slot and the batch's staging batch either
-        way — the latter only here, after the read-back of the batch's
-        outputs has returned: jax reads the host buffer after ``predict``
-        has returned (and ``_forward_e2e``'s ``device_put`` arrays may
-        alias it until they go), so a staging batch rewritten earlier
-        would corrupt the batch in flight."""
-        key, staging = batch[0].bucket, batch[0].staging
-        try:
-            self._run_batch(batch, time.monotonic())
-        except BaseException as e:  # noqa: BLE001 — fail the batch
-            logger.exception("serve batch failed")
-            for r in batch:
-                r.future._set_error(e)
-        finally:
-            with self._cond:
-                self._inflight -= 1
-                self._retire_locked(key, staging)
-                free = len(self._staging_free.get(key, ()))
-                self._cond.notify_all()  # drain() waits on this
-            telemetry.get().gauge("serve/staging_free", free)
+        """Run one batch claimed by :meth:`poll` (the external
+        dispatcher's surface): its launch and its finish back to back, one
+        turn.  Fails the batch on error, and releases the inflight slot
+        and the batch's staging batch either way (:meth:`_finish`)."""
+        self._finish(self._launch(batch, time.monotonic()), ends_turn=True)
 
     def _claim_locked(self, now: Optional[float] = None):
         """``(expired, batch, wait_s)`` as of ``now``: sweeps the deadlines
@@ -1072,16 +1126,27 @@ class ServeEngine:
         return expired, batch, wait
 
     def _dispatch_loop(self):
+        """A turn begins with the claim of a due batch, which is launched
+        FIRST; then the flight the turn before left is finished, while the
+        device runs the one just launched.  With nothing due, a pending
+        flight is finished at once, inside the turn that launched it: the
+        serial order falls out whenever the queue holds nothing else, and
+        the loop never idles with a batch in flight.  So at most two
+        batches are launched and unfinished at any instant — the one
+        being finished and the one launched at this turn's top."""
+        flight = None   # launched by the turn under way, not finished yet
         while True:
             with self._cond:
-                if self._stop:
-                    return
-                expired, batch, wait = self._claim_locked()
-                if batch is None and not expired:
-                    # nothing is due: the device idles for want of work,
-                    # not because the host is slow.  ONE span a period,
-                    # however often a submit wakes the wait, or the
-                    # timeline would show an idle period in pieces
+                expired, batch, wait = (([], None, None) if self._stop
+                                        else self._claim_locked())
+                if flight is None and batch is None and not expired:
+                    if self._stop:
+                        return
+                    # nothing is due and nothing in flight: the device
+                    # idles for want of work, not because the host is
+                    # slow.  ONE span a period, however often a submit
+                    # wakes the wait, or the timeline would show an idle
+                    # period in pieces
                     with self._stage("serve/idle"):
                         while batch is None and not expired:
                             self._cond.wait(timeout=wait)
@@ -1089,16 +1154,46 @@ class ServeEngine:
                                 return
                             expired, batch, wait = self._claim_locked()
             self._fail_expired(expired)
+            launched = None
             if batch is not None:
-                self.dispatch_batch(batch)
+                now = time.monotonic()
+                if flight is not None:
+                    # the turn that launched it ends where this one begins
+                    self._book_turn(now - flight.t_claim)
+                launched = self._launch(batch, now)
+            if flight is not None:
+                self._finish(flight, ends_turn=launched is None)
+            flight = launched
 
-    def _run_batch(self, reqs: List[_Request], now: float):
-        import jax
+    def _book_turn(self, seconds: float):
+        """One turn of the dispatcher thread is over: ``serve/service_time``
+        (never a batch's claim → its last response, which would count the
+        seconds two flights share twice)."""
+        self.hists["serve/service_time"].observe(seconds)
+        telemetry.get().observe("serve/service_time", seconds)
 
+    def _launch(self, reqs: List[_Request], now: float) -> _Flight:
+        """The first half of a batch: queue-wait bookkeeping, the
+        hand-over of its staging batch (``serve/assemble``), and the
+        forward path's own launch up to ``predict`` having returned and the
+        d2h of its outputs being under way.  Never raises: a failure fails
+        this batch's requests and leaves a flight without requests, which
+        :meth:`_finish` only releases."""
+        flight = _Flight(reqs, now)
+        try:
+            self._launch_batch(flight)
+        except BaseException as e:  # noqa: BLE001 — fail the batch
+            logger.exception("serve batch failed in its launch")
+            for r in reqs:
+                r.future._set_error(e)
+            flight.reqs = []
+        return flight
+
+    def _launch_batch(self, flight: _Flight):
         tel = telemetry.get()
         B = self.opts.batch_size
-        staging = reqs[0].staging
-        for r in reqs:
+        now, staging = flight.t_claim, flight.staging
+        for r in flight.reqs:
             r.future.queue_wait_s = now - r.t_enqueue
             tel.add("serve/queue_wait", now - r.t_enqueue)
             self.hists["serve/queue_wait"].observe(now - r.t_enqueue)
@@ -1114,33 +1209,71 @@ class ServeEngine:
                 while staging.pending:
                     self._cond.wait()
                 self.counters["assemble_waits"] += waits
+                # this batch and, in the loop, the flight it overlaps
+                in_flight = self._inflight
             # a caller whose copy raised has failed its own request
-            reqs = [r for r in reqs if r.row is not None]
+            flight.reqs = [r for r in flight.reqs if r.row is not None]
         if waits:
             tel.counter("serve/assemble_waits", waits)
-        if not reqs:
+        if not flight.reqs:
             return
-        images, im_info = staging.images, staging.im_info
-        pad = B - len(reqs)
-        tel.gauge("serve/batch_fill", len(reqs) / B)
-        tel.gauge("serve/pad_ratio", pad / B)
+        flight.overlapped = in_flight > 1
+        tel.gauge("serve/in_flight", in_flight)
+        tel.gauge("serve/batch_fill", len(flight.reqs) / B)
+        tel.gauge("serve/pad_ratio", (B - len(flight.reqs)) / B)
+        if self.opts.serve_e2e:
+            self._launch_e2e(flight, tel)
+        else:
+            self._launch_legacy(flight, tel)
+
+    def _finish(self, flight: _Flight, ends_turn: bool):
+        """The second half of a batch: the read-back of its outputs, the
+        post-process (on a mask network the mask stage over this batch's
+        own pyramid), the futures, then the hists, counters, trace spans
+        and capture.  A failure fails this batch's requests only.  Either
+        way the inflight slot and the staging batch are released here, and
+        only here — after the read-back of this batch's own outputs has
+        returned: jax reads the host buffer after ``predict`` has returned
+        (and ``_launch_e2e``'s ``device_put`` arrays may alias it until
+        they go), so a staging batch rewritten earlier would corrupt the
+        batch in flight.  ``ends_turn``: no other flight is pending, so the
+        turn that launched this one ends with it, and is booked before the
+        slot goes (``drain`` returning means every clock is booked)."""
+        try:
+            if flight.reqs:
+                self._finish_batch(flight)
+        except BaseException as e:  # noqa: BLE001 — fail the batch
+            logger.exception("serve batch failed")
+            for r in flight.reqs:
+                r.future._set_error(e)
+        finally:
+            if ends_turn:
+                self._book_turn(time.monotonic() - flight.t_claim)
+            with self._cond:
+                self._inflight -= 1
+                self._retire_locked(flight.key, flight.staging)
+                free = len(self._staging_free.get(flight.key, ()))
+                self._cond.notify_all()  # drain() waits on this
+            telemetry.get().gauge("serve/staging_free", free)
+
+    def _finish_batch(self, flight: _Flight):
+        tel = telemetry.get()
+        B = self.opts.batch_size
+        reqs, now = flight.reqs, flight.t_claim
         # each stage is timed once (telemetry.stage); the batch's phase
         # seconds come back for the tracer, which costs exactly ONE
         # attribute check per batch when tracing is off (the capture
         # contract)
         tracer = tracectx.get()
         if self.opts.serve_e2e:
-            xfer, phases = self._forward_e2e(reqs, images, im_info, tel)
+            xfer = self._finish_e2e(flight, tel)
         else:
-            xfer, phases = self._forward_legacy(reqs, images, im_info, tel)
-        # latency distributions: service time once per batch, end-to-end
-        # request time once per request (global + per-bucket family) —
-        # into the engine's own Hists AND the active sink, so the SLO
-        # controller and /metrics see them regardless of telemetry config
+            xfer = self._finish_legacy(flight, tel)
+        # end-to-end request time once per request (global + per-bucket
+        # family) — into the engine's own Hists AND the active sink, so
+        # the SLO controller and /metrics see them regardless of telemetry
+        # config; from the batch's own instants, whatever else the turn did
         done = time.monotonic()
-        service_s = done - now
-        self.hists["serve/service_time"].observe(service_s)
-        tel.observe("serve/service_time", service_s)
         new_bucket_hists = {}
         for r in reqs:
             req_s = done - r.t_enqueue
@@ -1159,6 +1292,7 @@ class ServeEngine:
             self._bucket_hists.update(new_bucket_hists)
             self.counters["batches"] += 1
             self.counters["served"] += len(reqs)
+            self.counters["overlapped_turns"] += flight.overlapped
             if stream_frames:
                 self.counters["stream_batches"] += 1
                 self.counters["stream_batch_frames"] += stream_frames
@@ -1168,6 +1302,8 @@ class ServeEngine:
                 self.counters[k] = self.counters.get(k, 0) + v
         tel.counter("serve/batches")
         tel.counter("serve/images", len(reqs))
+        if flight.overlapped:
+            tel.counter("serve/overlapped_turns")
         tel.counter("serve/post_kept", xfer["post_kept"])
         if "post_candidates" in xfer:
             tel.counter("serve/post_candidates", xfer["post_candidates"])
@@ -1182,7 +1318,8 @@ class ServeEngine:
             if len(stream_ids) > 1:
                 tel.counter("stream/coalesced_batches")
         if tracer.enabled:
-            self._emit_trace_spans(tracer, reqs, now, done, pad, B, phases)
+            self._emit_trace_spans(tracer, reqs, now, done, B - len(reqs),
+                                   B, flight.phases)
         if self.capture.enabled:
             entries = []
             for r in reqs:
@@ -1274,36 +1411,49 @@ class ServeEngine:
                      dtype=self._dtype)
         return first
 
-    def _forward_legacy(self, reqs: List[_Request], images, im_info,
-                        tel) -> Tuple[dict, dict]:
-        """PR-3 path: host-prepped batch in, full score/delta readback,
-        host decode + per-class NMS.  Returns the batch's counter
-        increments (two h2d arrays — images and im_info ship separately
-        into the jit call — one dispatch, one fat readback, the
-        post-process's candidates and records) and its phase seconds for
-        the engine's dispatch sub-spans."""
-        import jax
-
-        shape = tuple(images.shape)
-        first = self._note_first_dispatch(shape, "serve_predict", tel)
-        # until predict returns: the h2d of the batch + the enqueue
+    def _launch_legacy(self, flight: _Flight, tel):
+        """PR-3 path, launch half: the host-prepped staging batch goes into
+        ``predict`` — until it returns, the h2d of the batch and the
+        enqueue (``serve/forward``); on a mask network the batch's pyramid
+        is captured there — and the d2h of the four outputs is started."""
+        images, im_info = flight.staging.images, flight.staging.im_info
+        flight.shape = tuple(images.shape)
+        flight.first = self._note_first_dispatch(flight.shape,
+                                                 "serve_predict", tel)
         with self._stage("serve/forward") as fwd:
             rois, roi_valid, cls_prob, bbox_deltas, _ = \
                 self.predictor.predict(images, im_info)
             if self._has_mask:
                 # this batch's pyramid, still on the device: the captured
                 # pair stays this batch's whatever predict() runs next
-                feats, _ = self.predictor.capture_feats()
-        # the wait for the device + d2h
+                flight.feats, _ = self.predictor.capture_feats()
+            flight.outs = (rois, roi_valid, cls_prob, bbox_deltas)
+            _copy_to_host_async(flight.outs)
+        flight.phases["forward"] = fwd.seconds
+
+    def _finish_legacy(self, flight: _Flight, tel) -> dict:
+        """PR-3 path, finish half: full score/delta readback, host decode +
+        per-class NMS, on a mask network the mask stage.  Returns the
+        batch's counter increments (two h2d arrays — images and im_info
+        ship separately into the jit call — one dispatch, one fat readback,
+        the post-process's candidates and records); its phase seconds for
+        the engine's dispatch sub-spans go into ``flight.phases``."""
+        import jax
+
+        reqs, phases = flight.reqs, flight.phases
+        images, im_info = flight.staging.images, flight.staging.im_info
+        # what is left of the wait for the device when the turn gets here
+        # (all of it for a lone batch, little once the host is the slower
+        # of the two) + what is left of the d2h
         with self._stage("serve/readback") as rb:
             rois, roi_valid, cls_prob, bbox_deltas = jax.device_get(
-                (rois, roi_valid, cls_prob, bbox_deltas))
-        if first and self.registry is not None:
+                flight.outs)
+        if flight.first and self.registry is not None:
             # first dispatch of a shape = its compile: the forward +
             # readback wall is the compile(+first run) cost this program
             # would charge a cold user request
             self.predictor.record_compile_seconds(
-                shape, fwd.seconds + rb.seconds)
+                flight.shape, phases["forward"] + rb.seconds)
         cfg = self.cfg
         # per image on the timeline, summed into one observation a batch
         decode = telemetry.stage("serve/post/decode")
@@ -1331,8 +1481,7 @@ class ServeEngine:
                         r.future._set_result(recs)
         for st in (decode, nms):
             st.book(self.hists[st.name])
-        phases = {"forward": fwd.seconds, "readback": rb.seconds,
-                  "postprocess": post.seconds}
+        phases.update(readback=rb.seconds, postprocess=post.seconds)
         # the boxes over the threshold that went into the per-class NMS
         # (per_class_nms's ``sel``, all classes and images at once; the
         # rows no live request has count nothing)
@@ -1360,11 +1509,11 @@ class ServeEngine:
             # threads serialise 100 count lists a reply under the GIL, and
             # whatever the dispatcher still did here waited for it (17-20
             # ms a turn of bookkeeping that takes 3; chip run, PR 32)
-            mask_xfer, phases["mask"] = self._mask_stage(held, feats,
-                                                         len(images), tel)
+            mask_xfer, phases["mask"] = self._mask_stage(
+                held, flight.feats, len(images), tel)
             for k, v in mask_xfer.items():
                 xfer[k] = xfer.get(k, 0) + v
-        return xfer, phases
+        return xfer
 
     def _mask_stage(self, held: List[Tuple[_Request, List[dict]]], feats,
                     n_rows: int, tel) -> Tuple[dict, float]:
@@ -1450,18 +1599,15 @@ class ServeEngine:
                  "h2d_bytes": passes * int(boxes.nbytes + labels.nbytes)},
                 whole.seconds)
 
-    def _forward_e2e(self, reqs: List[_Request], staged, im_info,
-                     tel) -> Tuple[dict, dict]:
-        """Single-dispatch path (``--serve-e2e``): ONE ``device_put`` of
-        the staged uint8 batch + its sidecars, ONE fused
-        prep → forward → decode+NMS dispatch (registry kind
-        ``serve_e2e``), ONE readback of the ``(B, cap, 6)`` detections.
-        Responses come from ``device_dets_to_per_class`` — the same
-        top-k-capped contract as ``--device-postprocess`` eval, so exact
-        score ties at the cap may resolve differently from the host-NMS
-        path (documented in ``ops.postprocess.device_postprocess``)."""
+    def _launch_e2e(self, flight: _Flight, tel):
+        """Single-dispatch path (``--serve-e2e``), launch half: ONE
+        ``device_put`` of the staged uint8 batch + its sidecars
+        (``serve/h2d``), ONE fused prep → forward → decode+NMS dispatch
+        (registry kind ``serve_e2e``; ``serve/forward``), and the d2h of
+        the ``(B, cap, 6)`` detections started."""
         import jax
 
+        reqs, staged = flight.reqs, flight.staging.images
         # the small sidecars by row, like the images; rows nobody wrote
         # get the last request's, so that the device prep sees real sizes
         raw_hw = np.tile(np.asarray(reqs[-1].raw_hw, np.int32),
@@ -1470,19 +1616,34 @@ class ServeEngine:
         for r in reqs:
             raw_hw[r.row], ratio[r.row] = r.raw_hw, r.ratio
         flip = np.zeros(len(staged), bool)  # serve traffic never flips
-        cfg = self.cfg
-        mpi = int(cfg.TEST.MAX_PER_IMAGE)
-        th = float(cfg.TEST.THRESH)
-        shape = tuple(staged.shape) + (f"mpi={mpi}", f"th={th:g}")
-        first = self._note_first_dispatch(shape, "serve_e2e", tel)
+        mpi = int(self.cfg.TEST.MAX_PER_IMAGE)
+        th = float(self.cfg.TEST.THRESH)
+        flight.shape = tuple(staged.shape) + (f"mpi={mpi}", f"th={th:g}")
+        flight.first = self._note_first_dispatch(flight.shape, "serve_e2e",
+                                                 tel)
         host_args = (staged, raw_hw, ratio,
-                     np.asarray(im_info, np.float32), flip)
+                     np.asarray(flight.staging.im_info, np.float32), flip)
+        flight.h2d_bytes = int(sum(a.nbytes for a in host_args))
         with self._stage("serve/h2d") as h2d:
             # the one host→device transfer: a single put of the argument
             # tuple whose only large buffer is the staged uint8 batch
             args = jax.device_put(host_args)
         with self._stage("serve/forward") as fwd:
-            dets, dvalid = self.predictor.predict_serve_e2e(*args, mpi, th)
+            flight.outs = self.predictor.predict_serve_e2e(*args, mpi, th)
+            _copy_to_host_async(flight.outs)
+        flight.phases.update(h2d=h2d.seconds, forward=fwd.seconds)
+
+    def _finish_e2e(self, flight: _Flight, tel) -> dict:
+        """Single-dispatch path, finish half: the cascade's gate, ONE
+        readback of the ``(B, cap, 6)`` detections, the records.
+        Responses come from ``device_dets_to_per_class`` — the same
+        top-k-capped contract as ``--device-postprocess`` eval, so exact
+        score ties at the cap may resolve differently from the host-NMS
+        path (documented in ``ops.postprocess.device_postprocess``)."""
+        import jax
+
+        reqs, phases = flight.reqs, flight.phases
+        dets, dvalid = flight.outs
         if self.cascade is not None:
             # on-device confidence gate: fold the (B, cap, 6) detections
             # into per-image hardness while they are STILL device arrays —
@@ -1491,25 +1652,24 @@ class ServeEngine:
             self.cascade.gate_batch(dets, dvalid, reqs)
         with self._stage("serve/readback") as rb:
             dets, dvalid = jax.device_get((dets, dvalid))
-        if first and self.registry is not None:
+        if flight.first and self.registry is not None:
             self.predictor.record_compile_seconds(
-                shape, h2d.seconds + fwd.seconds + rb.seconds,
+                flight.shape, phases["h2d"] + phases["forward"] + rb.seconds,
                 kind="serve_e2e")
         kept = 0
         with self._stage("serve/postprocess") as post:
             for r in reqs:
                 dets_pc = device_dets_to_per_class(dets[r.row], dvalid[r.row],
-                                                   cfg.NUM_CLASSES)
+                                                   self.cfg.NUM_CLASSES)
                 recs = detections_to_records(dets_pc)
                 kept += len(recs)
                 r.future._set_result(recs)
+        phases.update(readback=rb.seconds, postprocess=post.seconds)
         nbytes = int(np.asarray(dets).nbytes + np.asarray(dvalid).nbytes)
-        return ({"h2d_transfers": 1, "dispatches": 1, "readbacks": 1,
-                 "readback_bytes": nbytes,
-                 "h2d_bytes": int(sum(a.nbytes for a in host_args)),
-                 "post_kept": kept},
-                {"h2d": h2d.seconds, "forward": fwd.seconds,
-                 "readback": rb.seconds, "postprocess": post.seconds})
+        return {"h2d_transfers": 1, "dispatches": 1, "readbacks": 1,
+                "readback_bytes": nbytes,
+                "h2d_bytes": flight.h2d_bytes,
+                "post_kept": kept}
 
     # -- introspection ---------------------------------------------------
 

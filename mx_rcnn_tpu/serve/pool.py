@@ -717,7 +717,7 @@ class CascadeRouter:
         return hard, dt
 
     def gate_batch(self, dets, dvalid, reqs) -> None:
-        """Engine hook (small model's ``_forward_e2e``): stamp per-image
+        """Engine hook (small model's ``_finish_e2e``): stamp per-image
         hardness + a request backlink on each future, observe gate cost,
         and emit the PR-16 trace span carrying the gate verdict."""
         hard, dt = self._dispatch_gate(dets, dvalid)

@@ -201,7 +201,7 @@ def test_a_staging_batch_is_not_reused_before_its_read_back_returned():
         with engine._lock:   # and now it is back, to be used again
             free = [s for f in engine._staging_free.values() for s in f]
         assert any(np.shares_memory(s.images, inflight) for s in free)
-        assert engine.counters["staging_allocs"] == 3
+        assert engine.counters["staging_allocs"] == 4
     finally:
         pred.release.set()
         engine.stop()
@@ -217,7 +217,8 @@ def test_32_threads_each_response_is_its_own_images():
     B, max_queue, per_thread, n_threads = 4, 64, 10, 32
     engine = ServeEngine(FakePredictor(cfg, delay_s=0.002), cfg, ServeOptions(
         batch_size=B, max_delay_ms=50.0, max_queue=max_queue)).start()
-    bound = -(-max_queue // B) + 2      # a bucket: full ones, open, in flight
+    bound = -(-max_queue // B) + 3      # a bucket: full ones, open, two in
+    # flight
     want = {v: FakePredictor.row_score(
         prepare_image(raw_image(60, 100, v), cfg, cfg.tpu.SCALES[0])[0])
         for v in range(10, 250)}
@@ -255,9 +256,10 @@ def test_32_threads_each_response_is_its_own_images():
     assert counters["served"] == counters["requests"] == n
     assert counters["staged_rows"] == n
     assert counters["rejected"] == counters["deadline_exceeded"] == 0
-    # the first burst (32 at once) is the deepest the queue ever gets
-    assert 3 <= counters["staging_allocs"] <= bound
-    assert counters["staging_allocs"] == allocs_at["early"]
+    # the first burst (32 at once) is the deepest the queue ever gets; one
+    # more can follow it: two partial batches in flight beside a full queue
+    assert 4 <= counters["staging_allocs"] <= bound
+    assert 0 <= counters["staging_allocs"] - allocs_at["early"] <= 1
     assert counters["assemble_waits"] <= counters["served"]
 
 
@@ -420,7 +422,7 @@ def test_e2e_resubmitted_and_captured_pixels_stay_the_requests_own():
             for f in futs:
                 f.result(timeout=30)
             wait_booked(small)
-        assert small.counters["staging_allocs"] == 3
+        assert small.counters["staging_allocs"] == 4
         assert small.counters["batches"] >= 4
         assert len(rec.reqs) == len(rec.entries) == 8
         with small._lock:

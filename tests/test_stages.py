@@ -133,24 +133,42 @@ def test_null_tracer_still_raises_with_the_helper_in_place():
 # -- the engine's clocks on /metrics (fake predictor: no compile) -----------
 
 
-def test_metrics_stages_count_batches_and_requests_and_are_monotone():
+@pytest.mark.parametrize("arrivals", ["one at a time", "queued ahead"])
+def test_metrics_stages_count_batches_and_requests_and_are_monotone(arrivals):
+    """A turn is a claimed batch's launch plus the finish of the flight the
+    turn before left (PR 33): whether the batches come one after another
+    (each turn launches and finishes its own) or wait in the queue (each
+    turn but the first launches one and finishes another), every stage and
+    ``serve/service_time`` observe once a batch, and the stages of both
+    halves lie inside the turns."""
     # a long delay: only full batches flush, however slowly a submit runs
     engine = make_engine(tiny_cfg(), batch_size=2,
                          max_delay_ms=20000.0).start()
+    if arrivals == "queued ahead":
+        # a launch that takes long enough for the next batches to queue:
+        # the loop then finds one due at the top of the following turns
+        engine.predictor.delay_s = 0.1
     try:
         snaps = []
         for n_batches in (3, 2):
+            futs = []
             for i in range(n_batches):
-                futs = [engine.submit(raw_image(60, 100, 20 * i + v))
-                        for v in (40, 90)]  # a full batch: flushes at once
-                for f in futs:
-                    f.result(timeout=30)
+                futs += [engine.submit(raw_image(60, 100, 20 * i + v))
+                         for v in (40, 90)]  # a full batch: flushes at once
+                if arrivals == "one at a time":
+                    for f in futs:
+                        f.result(timeout=30)
+            for f in futs:
+                f.result(timeout=30)
             assert engine.drain(timeout=30)  # the last batch is booked
             engine.resume()
             snaps.append(engine.metrics())
         first, second = snaps
         for m, batches in ((first, 3), (second, 5)):
             assert m["counters"]["batches"] == batches
+            assert m["counters"]["overlapped_turns"] <= batches - 1
+            assert (m["counters"]["overlapped_turns"] == 0) == (
+                arrivals == "one at a time")
             for name in PER_BATCH:
                 assert m["stages"][name]["count"] == batches, name
             for name in PER_REQUEST:
