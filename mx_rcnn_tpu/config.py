@@ -164,10 +164,28 @@ class NetworkConfig:
     # (H/2, W/2, 12) so the stem's s2d regroup costs zero device time
     # (~1 ms/step of lane-hostile transposes otherwise); ResNet stems only
     HOST_S2D: bool = False
+    # host-side row flattening: the loader ships images as (H, W·3) — a
+    # view, no copy — so the batch meets the device with a lane-dense
+    # minor dimension.  A (…, W, 3) float32 array is laid out on the chip
+    # with its 3 channels padded to a 128-lane tile, and the host half of
+    # that transfer took 0.5 s a batch of 8 × 1024 × 1024 (PR 34, on the
+    # chip); a ViT patch embedding regroups rows into patches on the
+    # device either way (models/vit.py PatchEmbed takes both forms)
+    HOST_ROWS: bool = False
     FPN_FEAT_STRIDES: Tuple[int, ...] = (4, 8, 16, 32, 64)
     FPN_ANCHOR_SCALES: Tuple[int, ...] = (8,)
     FPN_OUT_CHANNELS: int = 256
     HAS_MASK: bool = False
+    # plain-ViT trunk (NETWORK="vit": models/vit.py, ViTDet): ViT-B widths.
+    # Blocks not named in VIT_GLOBAL_BLOCKS attend inside VIT_WINDOW-sided
+    # windows of the token grid.  The position vectors are held at the
+    # grid of tpu.SCALES[0], which has to be square.
+    VIT_PATCH: int = 16
+    VIT_WIDTH: int = 768
+    VIT_DEPTH: int = 12
+    VIT_HEADS: int = 12
+    VIT_WINDOW: int = 14
+    VIT_GLOBAL_BLOCKS: Tuple[int, ...] = (2, 5, 8, 11)
 
     @property
     def NUM_ANCHORS(self) -> int:
@@ -354,6 +372,21 @@ _NETWORK_PRESETS = {
         FIXED_PARAMS_SHARED=("conv1", "bn1", "stage1", "stage2", "stage3",
                              "stage4", "lateral", "post", "gamma", "beta"),
     ),
+    # Mask R-CNN on a plain ViT-B trunk with the simple feature pyramid
+    # (ViTDet, arXiv:2203.16527): one square bucket (generate_config sets
+    # 1024 x 1024), a 2-conv RPN, a 4-conv + 1-FC box head and LayerNorm in
+    # the heads.  Nothing is frozen: the trunk trains whole.
+    "vitdet_b_mask": dict(
+        NETWORK="vit",
+        HOST_ROWS=True,
+        IMAGE_STRIDE=32,
+        HAS_FPN=True,
+        HAS_MASK=True,
+        RCNN_FEAT_STRIDE=4,
+        FPN_ANCHOR_SCALES=(8,),
+        FIXED_PARAMS=(),
+        FIXED_PARAMS_SHARED=("backbone", "neck"),
+    ),
 }
 
 for _depth in ("resnet50", "resnet101", "resnet152"):
@@ -423,6 +456,9 @@ def generate_config(network: str, dataset: str, **overrides) -> Config:
     # FPN/Mask configs keep the Mask R-CNN paper's 2-sample ROIAlign
     if net.HAS_FPN:
         tpu = replace(tpu, ROI_SAMPLING_RATIO=2)
+    # a ViT trunk holds its position vectors at one square grid
+    if net.NETWORK == "vit":
+        tpu = replace(tpu, SCALES=((1024, 1024),))
 
     cfg = Config(network=net, dataset=ds, TRAIN=train, TEST=test, tpu=tpu)
 

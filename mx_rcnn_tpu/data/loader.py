@@ -46,12 +46,14 @@ def prepare_image(im: np.ndarray, cfg: Config,
     (``mx_rcnn_tpu/serve``) so an online request goes through byte-for-byte
     the same transform chain as an eval batch: pixel normalize → resize by
     the reference rule → zero-pad into the orientation's static bucket →
-    optional host space-to-depth."""
+    optional host space-to-depth, or rows flattened to (H, W·3)."""
     im = transform_image(im, cfg.network.PIXEL_MEANS, cfg.network.PIXEL_STDS)
     stride = max(cfg.network.IMAGE_STRIDE, cfg.network.RPN_FEAT_STRIDE)
     padded, s, (eh, ew) = resize_to_bucket(im, scale, stride)
     if cfg.network.HOST_S2D:
         padded = space_to_depth2(padded)
+    elif cfg.network.HOST_ROWS:
+        padded = padded.reshape(padded.shape[0], -1)
     return padded, np.asarray([eh, ew, s], np.float32)
 
 

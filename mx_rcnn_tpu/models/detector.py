@@ -285,12 +285,8 @@ def build_model(cfg: Config) -> FasterRCNN:
     """Factory — the analogue of the reference's ``get_<net>_train/test``
     symbol selectors (dispatch in train_end2end.py / test.py)."""
     if cfg.network.HAS_FPN:
-        try:
-            from mx_rcnn_tpu.models.fpn import FPNFasterRCNN
-        except ImportError as e:
-            raise NotImplementedError(
-                "FPN model variants are not built yet (models/fpn.py pending)"
-            ) from e
+        from mx_rcnn_tpu.models.fpn import FPNFasterRCNN
+
         return FPNFasterRCNN(cfg=cfg)
     return FasterRCNN(cfg=cfg)
 

@@ -31,7 +31,9 @@ import numpy as np
 from mx_rcnn_tpu.config import Config
 from mx_rcnn_tpu.models import losses as L
 from mx_rcnn_tpu.models.backbones import ResNetConv
-from mx_rcnn_tpu.models.heads import MaskHead, RCNNOutput, RPNHead
+from mx_rcnn_tpu.models.heads import (MaskHead, RCNNOutput, RPNHead,
+                                      conv_norm_relu)
+from mx_rcnn_tpu.models.vit import SimpleFeaturePyramid, ViT
 from mx_rcnn_tpu.ops import (all_anchors, assign_anchor, generate_anchors,
                              propose, sample_rois)
 from mx_rcnn_tpu.ops.mask_target import mask_targets_for_rois
@@ -78,8 +80,29 @@ class FPNBoxHead(nn.Module):
         return x
 
 
+class ConvFCBoxHead(nn.Module):
+    """4 × (3×3 conv 256, LN, ReLU) on the pooled map + FC-1024 (the box
+    head of the ViTDet baseline)."""
+
+    channels: int = 256
+    dtype: jnp.dtype = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        lead = x.shape[:-3]
+        x = x.reshape((-1,) + x.shape[-3:])
+        for i in range(1, 5):
+            x = conv_norm_relu(x, self.channels, f"conv{i}", True, self.dtype)
+        x = x.reshape(lead + (-1,))
+        return nn.relu(nn.Dense(1024, dtype=self.dtype, name="fc6")(x))
+
+
 class FPNFasterRCNN(nn.Module):
-    """Multi-level two-stage detector; optionally with a mask head."""
+    """Multi-level two-stage detector; optionally with a mask head.  The
+    preset chooses what stands under the five-level pyramid — a ResNet
+    with the top-down neck, or a plain ViT with the simple feature pyramid
+    (``network.NETWORK``) — and with it the RPN's depth and the heads'
+    bodies; from ``_rpn_over_levels`` on nothing knows which trunk ran."""
 
     cfg: Config
 
@@ -87,23 +110,43 @@ class FPNFasterRCNN(nn.Module):
         net = self.cfg.network
         dtype = jnp.bfloat16 if self.cfg.tpu.COMPUTE_DTYPE == "bfloat16" else jnp.float32
         self._dtype = dtype
-        assert net.NETWORK.startswith("resnet"), "FPN requires a ResNet body"
-        self.backbone = ResNetConv(depth=net.NETWORK, dtype=dtype,
-                                   all_stages=True,
-                                   remat=self.cfg.tpu.REMAT_BACKBONE)
-        self.neck = FPNNeck(out_channels=net.FPN_OUT_CHANNELS, dtype=dtype)
+        vit = net.NETWORK == "vit"
+        if vit:
+            side = set(self.cfg.tpu.SCALES[0])
+            assert len(side) == 1 and not side.pop() % (2 * net.VIT_PATCH), \
+                f"a ViT trunk takes one square bucket, a multiple of " \
+                f"{2 * net.VIT_PATCH} px; tpu.SCALES[0] = {self.cfg.tpu.SCALES[0]}"
+            self.backbone = ViT(
+                grid=self.cfg.tpu.SCALES[0][0] // net.VIT_PATCH,
+                patch=net.VIT_PATCH, width=net.VIT_WIDTH,
+                depth=net.VIT_DEPTH, heads=net.VIT_HEADS,
+                window=net.VIT_WINDOW, global_blocks=net.VIT_GLOBAL_BLOCKS,
+                dtype=dtype)
+            self.neck = SimpleFeaturePyramid(
+                out_channels=net.FPN_OUT_CHANNELS, dtype=dtype)
+        elif net.NETWORK.startswith("resnet"):
+            self.backbone = ResNetConv(depth=net.NETWORK, dtype=dtype,
+                                       all_stages=True,
+                                       remat=self.cfg.tpu.REMAT_BACKBONE)
+            self.neck = FPNNeck(out_channels=net.FPN_OUT_CHANNELS,
+                                dtype=dtype)
+        else:
+            raise ValueError(f"no pyramid trunk {net.NETWORK!r}")
         # FPN's shared RPN head is FPN_OUT_CHANNELS (256) wide — the FPN
         # paper/Detectron convention (the classic C4 RPN uses 512); at P2
         # resolution the 3×3 hidden conv is the single most expensive op in
         # the whole step (3.4 ms fwd at 512ch, profiled), so width follows
         # the convention, not the classic default
         self.rpn = RPNHead(num_anchors=net.NUM_ANCHORS,
-                           channels=net.FPN_OUT_CHANNELS, dtype=dtype)
-        self.head_body = FPNBoxHead(dtype=dtype)
+                           channels=net.FPN_OUT_CHANNELS,
+                           convs=2 if vit else 1, dtype=dtype)
+        self.head_body = (ConvFCBoxHead(channels=net.FPN_OUT_CHANNELS,
+                                        dtype=dtype) if vit
+                          else FPNBoxHead(dtype=dtype))
         self.rcnn_out = RCNNOutput(num_classes=self.cfg.NUM_CLASSES, dtype=dtype)
         if net.HAS_MASK:
             self.mask_head = MaskHead(num_classes=self.cfg.NUM_CLASSES,
-                                      dtype=dtype)
+                                      layer_norm=vit, dtype=dtype)
 
     # ---- shared pieces -----------------------------------------------------
 
@@ -112,8 +155,9 @@ class FPNFasterRCNN(nn.Module):
         return self.cfg.network.FPN_FEAT_STRIDES  # (4, 8, 16, 32, 64)
 
     def _pyramid(self, images):
-        c2, c3, c4, c5 = self.backbone(images)
-        return self.neck(c2, c3, c4, c5)
+        if self.cfg.network.NETWORK == "vit":
+            return self.neck(self.backbone(images))
+        return self.neck(*self.backbone(images))
 
     def _anchors_for_level(self, feat_h: int, feat_w: int, stride: int,
                            scale: int) -> jnp.ndarray:

@@ -9,6 +9,9 @@ Reference graph pieces (``rcnn/symbol/symbol_resnet.py`` /
   ``cls_score`` (K) and ``bbox_pred`` (4K).
 * Mask (capability target, Mask R-CNN): 4×[3×3 conv 256] → 2× deconv →
   1×1 conv K channels, per-class 28×28 sigmoid masks.
+* ``layer_norm=True`` (the ViTDet preset): the hidden convs of the box and mask
+  heads lose their bias and gain a LayerNorm over the channels at each
+  position (eps 1e-6) before the ReLU; the RPN gets a second hidden conv.
 
 Channel layout note (documented divergence): MXNet lays RPN outputs as
 (B, 2A, H, W) with softmax over a reshaped axis; here NHWC convs emit
@@ -23,9 +26,20 @@ import flax.linen as nn
 import jax.numpy as jnp
 
 
+def conv_norm_relu(x, channels: int, name: str, layer_norm: bool, dtype):
+    """3×3 conv + ReLU; with ``layer_norm`` the conv has no bias and a
+    LayerNorm over the channels stands between the two."""
+    x = nn.Conv(channels, (3, 3), padding=[(1, 1), (1, 1)],
+                use_bias=not layer_norm, dtype=dtype, name=name)(x)
+    if layer_norm:
+        x = nn.LayerNorm(epsilon=1e-6, dtype=dtype, name=f"{name}_norm")(x)
+    return nn.relu(x)
+
+
 class RPNHead(nn.Module):
     num_anchors: int = 9
     channels: int = 512
+    convs: int = 1
     dtype: jnp.dtype = jnp.bfloat16
 
     @nn.compact
@@ -35,9 +49,12 @@ class RPNHead(nn.Module):
         a = self.num_anchors
         # reference init: Normal(0.01) for all new RPN layers
         init = nn.initializers.normal(0.01)
-        x = nn.Conv(self.channels, (3, 3), padding=[(1, 1), (1, 1)],
-                    kernel_init=init, dtype=self.dtype, name="rpn_conv_3x3")(feat)
-        x = nn.relu(x)
+        x = feat
+        for i in range(1, self.convs + 1):
+            x = nn.relu(nn.Conv(
+                self.channels, (3, 3), padding=[(1, 1), (1, 1)],
+                kernel_init=init, dtype=self.dtype,
+                name="rpn_conv_3x3" if i == 1 else f"rpn_conv_3x3_{i}")(x))
         cls = nn.Conv(2 * a, (1, 1), kernel_init=init, dtype=self.dtype,
                       name="rpn_cls_score")(x)
         bbox = nn.Conv(4 * a, (1, 1), kernel_init=init, dtype=self.dtype,
@@ -70,15 +87,15 @@ class MaskHead(nn.Module):
 
     num_classes: int
     channels: int = 256
+    layer_norm: bool = False
     dtype: jnp.dtype = jnp.bfloat16
 
     @nn.compact
     def __call__(self, x):
         """x: (R, 14, 14, C) → (R, 28, 28, K) logits."""
         for i in range(1, 5):
-            x = nn.Conv(self.channels, (3, 3), padding=[(1, 1), (1, 1)],
-                        dtype=self.dtype, name=f"mask_conv{i}")(x)
-            x = nn.relu(x)
+            x = conv_norm_relu(x, self.channels, f"mask_conv{i}",
+                               self.layer_norm, self.dtype)
         x = nn.ConvTranspose(self.channels, (2, 2), strides=(2, 2),
                              dtype=self.dtype, name="mask_deconv")(x)
         x = nn.relu(x)

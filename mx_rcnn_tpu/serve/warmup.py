@@ -36,8 +36,10 @@ def warmup(engine) -> int:
     """Register + ready every (bucket, batch) program through a STARTED
     engine.
 
-    Submits ``batch_size`` dummy images per orientation (full batches →
-    immediate flush, no delay wait) and blocks until served.  Returns the
+    Submits ``batch_size`` dummy images per bucket — landscape and
+    portrait, which a square scale routes to the one bucket both share, so
+    such a network warms its programs once — (full batches → immediate
+    flush, no delay wait) and blocks until served.  Returns the
     number of programs first-dispatched (each either an XLA compile or a
     persistent-cache load); stamps it into
     ``engine.counters["warmup_programs"]`` and the
@@ -55,7 +57,10 @@ def warmup(engine) -> int:
     aot_before = (dict(reg.counters) if reg is not None else {})
     xla_before = xla_counters()
     with telemetry.stage("setup/warmup") as whole:
-        for h, w in ((short, long_), (long_, short)):  # landscape, portrait
+        # landscape, portrait: one dummy shape a distinct bucket
+        shapes = {engine.bucket_key(h, w): (h, w)
+                  for h, w in ((short, long_), (long_, short))}
+        for h, w in shapes.values():
             dummy = np.zeros((h, w, 3), np.uint8)
             futs = [engine.submit(dummy, deadline_ms=0)  # never expire
                     for _ in range(engine.opts.batch_size)]

@@ -191,3 +191,31 @@ def test_level_topk_compiles_at_the_served_row_lengths(one_chip, level):
     text = _compiled_text(lambda s: _level_topk(s, k), one_chip,
                           ((n,), jnp.float32))
     assert ("/top_k" in text) == (level < 4)
+
+
+# ---- the blocked attention kernel of the ViT trunk's global blocks at the
+# shapes of benchmark cell vitdet-serve-closed: 8 images x 12 heads, a
+# 64 x 64 grid of tokens, head dimension 64 (PR 34) ----
+
+attn_mod = importlib.import_module("mx_rcnn_tpu.kernels.attention_pallas")
+
+
+@pytest.mark.parametrize("heads,grid", [(96, 64), (24, 32)])
+def test_blocked_attention_compiles_for_v5e(one_chip, heads, grid):
+    """At the served shape and at a 512 px bucket's: Mosaic takes the
+    tiling (whole key blocks of 512, the 0/1 rows stacked under k^T in a
+    once-a-head scratch), the call carries the name the benchmark's
+    ``names.attn_kernel`` reads, and no score matrix is ever a buffer: the
+    program's temporaries stay near the folded q rows and k^T (0.4 GB at
+    96 heads), three orders under the 6.4 GB of 96 x 4096 x 4096 floats."""
+    n, d = grid * grid, 64
+    shapes = [((heads, n, d), jnp.bfloat16)] * 3 + [
+        ((heads, n, 2 * grid), jnp.bfloat16)]
+    args = [jax.ShapeDtypeStruct(s, t, sharding=one_chip) for s, t in shapes]
+    compiled = jax.jit(lambda q, k, v, rel: attn_mod.attention_blocked(
+        q, k, v, rel, grid, d ** -0.5)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert f"%{attn_mod.KERNEL_NAME}" in text
+    assert compiled.memory_analysis().temp_size_in_bytes \
+        < 3 * heads * n * (d + 2 * grid) * 2
