@@ -110,12 +110,22 @@ request's raw frame + RLE; one observation a batch), with the counters
 ``mask_readback_bytes`` and ``mask_native`` (records pasted by the native
 call) on ``/metrics``; the futures are set at its end, each record with its
 ``"segmentation"``.  Request threads: ``frontend/read``,
-``frontend/decode``, ``serve/host_prep``, and ``frontend/reply`` (on the
-timeline only).  Start-up: ``setup/model`` / ``setup/params`` /
-``setup/predictor`` (``serve.py::_build_engine``) and ``setup/warmup``
-(``warmup()``), kept as seconds.
-``/metrics`` carries ``"stages": {name: {"count", "sum_s"}}`` for every
-``Hist`` (monotone: two scrapes' difference is the window's), ``"setup":
+``frontend/decode``, ``serve/host_prep``, ``serve/stage_row`` (the copy of
+the prepared image into its staging row, :meth:`ServeEngine._write_row`)
+and ``frontend/reply`` (the response's serialisation and write).
+Start-up: ``setup/model`` / ``setup/params`` / ``setup/predictor``
+(``serve.py::_build_engine``) and ``setup/warmup`` (``warmup()``), kept as
+seconds.
+``/metrics`` carries ``"stages": {name: {"count", "sum_s", "cpu_s",
+"minflt"}}`` for every ``Hist`` (monotone: two scrapes' difference is the
+window's; ``cpu_s`` / ``minflt`` are the observing thread's CPU seconds and
+minor page faults inside the same instants as ``sum_s`` — a stage's from
+:func:`telemetry.thread_usage` at its two ends, ``serve/service_time``'s at
+the two claims that bound the turn — and 0 for the hists that are not
+clocks of one thread: ``serve/queue_wait``, ``serve/request_time``),
+``"host": {"cpu_s", "cores"}`` (the process's CPU seconds, every thread's,
+and the cores it may run on: over two scrapes' ``t_s``, the cores it kept
+busy), ``"setup":
 {"model_s", "params_s", "predictor_s", "warmup_s"}``, the scrape's
 ``"t_s"`` (the server's own clock, for a rate between two scrapes), the
 counters ``post_candidates`` / ``post_kept`` (÷ ``served``: how much the
@@ -123,8 +133,7 @@ host post-process is handed an image, and whether ``TEST.MAX_PER_IMAGE``
 binds) and ``post_nms_native`` (the images whose per-class NMS was the one
 native call of ``ops/postprocess.per_class_nms``: equal to ``served``, or 0
 where the library did not build and the Python loop ran),
-``staged_rows`` (rows a caller's thread wrote into a staging batch: equal
-to ``requests``), ``assemble_waits`` (rows whose copy was still running
+``assemble_waits`` (rows whose copy was still running
 when a turn claimed their batch, and was waited for), ``staging_allocs`` (staging batches ever
 allocated: flat after warm-up, like ``recompiles``; the sink also gets
 the gauge ``serve/staging_free``) and ``overlapped_turns`` (batches
@@ -155,6 +164,8 @@ when ``--target-p99-ms`` is set, inert otherwise):
 
 from __future__ import annotations
 
+import os
+import resource
 import threading
 import time
 from dataclasses import dataclass
@@ -335,14 +346,17 @@ class _Flight:
     finished.  Written by :meth:`ServeEngine._launch`, read by
     :meth:`ServeEngine._finish`, both on the dispatcher's thread."""
 
-    __slots__ = ("reqs", "key", "staging", "t_claim", "overlapped", "shape",
-                 "first", "outs", "feats", "h2d_bytes", "phases")
+    __slots__ = ("reqs", "key", "staging", "t_claim", "usage_claim",
+                 "overlapped", "shape", "first", "outs", "feats", "h2d_bytes",
+                 "phases")
 
-    def __init__(self, reqs: List[_Request], t_claim: float):
+    def __init__(self, reqs: List[_Request], t_claim: float, usage_claim):
         self.reqs = reqs            # the live requests; none: the launch
         # failed them all, and the finish has only the slot to release
         self.key, self.staging = reqs[0].bucket, reqs[0].staging
         self.t_claim = t_claim      # monotonic: taken off the queue
+        # the dispatcher thread's (cpu_s, minflt) at that same instant
+        self.usage_claim = usage_claim
         self.overlapped = False     # launched while another was in flight
         self.shape = None           # the program's registry key, and
         self.first = False          # whether this is its first dispatch
@@ -453,11 +467,10 @@ class ServeEngine:
                          # images whose per-class NMS was the one native
                          # call (== served; 0 = no library, the loop ran)
                          "post_nms_native": 0,
-                         # staging batches: rows written by their callers
-                         # (== requests), rows a turn had to wait for,
-                         # batches ever allocated (flat once warm)
-                         "staged_rows": 0, "assemble_waits": 0,
-                         "staging_allocs": 0,
+                         # staging batches: rows a turn had to wait for,
+                         # batches ever allocated (flat once warm); the rows
+                         # written are serve/stage_row's count
+                         "assemble_waits": 0, "staging_allocs": 0,
                          # batches launched while another was in flight
                          # (/ batches: how often the overlap engages)
                          "overlapped_turns": 0,
@@ -507,11 +520,12 @@ class ServeEngine:
         # (telemetry.stage: each also a span on the profiler's timeline).
         # One observation a batch (serve/post/*: summed over its images),
         # except serve/idle — one an idle period, when it ends — and
-        # frontend/* — one a request.
+        # serve/stage_row and frontend/* — one a request.
         for name in ("serve/idle", "serve/assemble", "serve/forward",
                      "serve/readback", "serve/postprocess",
                      "serve/post/decode", "serve/post/nms",
-                     "frontend/read", "frontend/decode"):
+                     "serve/stage_row", "frontend/read", "frontend/decode",
+                     "frontend/reply"):
             self.hists[name] = Hist()
         if self.opts.serve_e2e:
             self.hists["serve/h2d"] = Hist()
@@ -860,7 +874,7 @@ class ServeEngine:
             tel.counter("serve/requests")
             tel.gauge("serve/queue_depth", depth + 1)
             self._cond.notify()
-        self._write_row(req, key, tel)
+        self._write_row(req, key)
         if self.on_work is not None:
             self.on_work()
         return req.future
@@ -899,37 +913,37 @@ class ServeEngine:
         s.pending += 1
         return True
 
-    def _write_row(self, req: _Request, key, tel):
+    def _write_row(self, req: _Request, key):
         """The caller's half of batch assembly: copy the prepared image and
         its ``im_info`` into the request's row, outside the lock (numpy
         lets go of the GIL for the copy).  A copy that raises fails its own
         request — out of the queue, its row a padding row — and re-raises,
-        so the dispatcher never waits for a row nobody will write."""
+        so the dispatcher never waits for a row nobody will write.  The
+        whole of it is the stage ``serve/stage_row`` (one observation a
+        request, a failed copy's too)."""
         s, row = req.staging, req.row
-        try:
-            np.copyto(s.images[row], req.image)
-            s.im_info[row] = req.im_info
-        except BaseException as e:
-            req.row = None
-            req.future._set_error(e)
-            raise
-        finally:
-            with self._cond:
-                s.pending -= 1
-                if req.row is None:
-                    q = self._queues.get(key, [])
-                    if req in q:     # not yet claimed by a turn
-                        q.remove(req)
-                else:
-                    self.counters["staged_rows"] += 1
-                    if not self.opts.serve_e2e:
+        with self._stage("serve/stage_row"):
+            try:
+                np.copyto(s.images[row], req.image)
+                s.im_info[row] = req.im_info
+            except BaseException as e:
+                req.row = None
+                req.future._set_error(e)
+                raise
+            finally:
+                with self._cond:
+                    s.pending -= 1
+                    if req.row is None:
+                        q = self._queues.get(key, [])
+                        if req in q:     # not yet claimed by a turn
+                            q.remove(req)
+                    elif not self.opts.serve_e2e:
                         req.image = None
-                if not s.pending:
-                    if s.retired:
-                        self._retire_locked(key, s)
-                    elif s.claimed:
-                        self._cond.notify_all()
-        tel.counter("serve/staged_rows")
+                    if not s.pending:
+                        if s.retired:
+                            self._retire_locked(key, s)
+                        elif s.claimed:
+                            self._cond.notify_all()
 
     def _retire_locked(self, key, s: _Staging):
         """``s`` has left its bucket's line and no turn needs it (any
@@ -1112,7 +1126,8 @@ class ServeEngine:
         dispatcher's surface): its launch and its finish back to back, one
         turn.  Fails the batch on error, and releases the inflight slot
         and the batch's staging batch either way (:meth:`_finish`)."""
-        self._finish(self._launch(batch, time.monotonic()), ends_turn=True)
+        self._finish(self._launch(batch, time.monotonic(),
+                                  telemetry.thread_usage()), ends_turn=True)
 
     def _claim_locked(self, now: Optional[float] = None):
         """``(expired, batch, wait_s)`` as of ``now``: sweeps the deadlines
@@ -1156,30 +1171,35 @@ class ServeEngine:
             self._fail_expired(expired)
             launched = None
             if batch is not None:
-                now = time.monotonic()
+                now, usage = time.monotonic(), telemetry.thread_usage()
                 if flight is not None:
                     # the turn that launched it ends where this one begins
-                    self._book_turn(now - flight.t_claim)
-                launched = self._launch(batch, now)
+                    self._book_turn(flight, now, usage)
+                launched = self._launch(batch, now, usage)
             if flight is not None:
                 self._finish(flight, ends_turn=launched is None)
             flight = launched
 
-    def _book_turn(self, seconds: float):
-        """One turn of the dispatcher thread is over: ``serve/service_time``
-        (never a batch's claim → its last response, which would count the
-        seconds two flights share twice)."""
-        self.hists["serve/service_time"].observe(seconds)
+    def _book_turn(self, flight: _Flight, now: float, usage):
+        """The turn that claimed ``flight`` is over at ``now``, when the
+        dispatcher thread's :func:`telemetry.thread_usage` read ``usage``:
+        ``serve/service_time`` (never a batch's claim → its last response,
+        which would count the seconds two flights share twice), with the
+        CPU seconds and page faults of the thread between the two claims."""
+        seconds = now - flight.t_claim
+        self.hists["serve/service_time"].observe(
+            seconds, cpu_s=usage[0] - flight.usage_claim[0],
+            minflt=usage[1] - flight.usage_claim[1])
         telemetry.get().observe("serve/service_time", seconds)
 
-    def _launch(self, reqs: List[_Request], now: float) -> _Flight:
+    def _launch(self, reqs: List[_Request], now: float, usage) -> _Flight:
         """The first half of a batch: queue-wait bookkeeping, the
         hand-over of its staging batch (``serve/assemble``), and the
         forward path's own launch up to ``predict`` having returned and the
         d2h of its outputs being under way.  Never raises: a failure fails
         this batch's requests and leaves a flight without requests, which
         :meth:`_finish` only releases."""
-        flight = _Flight(reqs, now)
+        flight = _Flight(reqs, now, usage)
         try:
             self._launch_batch(flight)
         except BaseException as e:  # noqa: BLE001 — fail the batch
@@ -1248,7 +1268,8 @@ class ServeEngine:
                 r.future._set_error(e)
         finally:
             if ends_turn:
-                self._book_turn(time.monotonic() - flight.t_claim)
+                self._book_turn(flight, time.monotonic(),
+                                telemetry.thread_usage())
             with self._cond:
                 self._inflight -= 1
                 self._retire_locked(flight.key, flight.staging)
@@ -1706,13 +1727,15 @@ class ServeEngine:
                     latency[f"{short}_{tag}"] = round(v * 1e3, 3)
         out["latency"] = latency
         # monotone clocks: (after - before) of two snapshots is a window's
-        out["stages"] = {}
-        for name, h in self.hists.items():
-            doc = h.to_dict()  # count and sum under the Hist's own lock
-            out["stages"][name] = {"count": doc["count"],
-                                   "sum_s": doc["sum"]}
+        out["stages"] = {name: h.clock() for name, h in self.hists.items()}
         out["setup"] = dict(self.setup)
         out["t_s"] = time.monotonic()
+        # the whole process's CPU seconds (every thread: the dispatcher,
+        # the request threads, the runtime's) at the same instant: over two
+        # scrapes' t_s, the cores it kept busy, of the cores it may run on
+        usage = resource.getrusage(resource.RUSAGE_SELF)
+        out["host"] = {"cpu_s": usage.ru_utime + usage.ru_stime,
+                       "cores": len(os.sched_getaffinity(0))}
         out["policy"] = self.policy()
         out["dtype"] = self._dtype
         if self.capture.enabled:

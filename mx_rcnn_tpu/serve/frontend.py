@@ -42,9 +42,9 @@ Endpoints:
   canary failure, previous weights restored.
 * ``GET /metrics`` — engine counters + queue state as JSON (with the
   cumulative clock of every stage under ``"stages"`` — this module's own
-  are ``frontend/read`` and ``frontend/decode``, one observation a
-  ``/predict`` request and a profiler annotation each; ``frontend/reply``
-  is an annotation only); with
+  are ``frontend/read``, ``frontend/decode`` and ``frontend/reply``, one
+  observation a ``/predict`` request and a profiler annotation each, booked
+  into the engine the request resolved to); with
   ``Accept: text/plain`` or ``?format=prom``, Prometheus text exposition
   instead — rendered by ``telemetry/obs.py`` from the same registry the
   ``--obs-port`` server scrapes (one metrics path, not two).
@@ -506,7 +506,8 @@ class _Handler(BaseHTTPRequestHandler):
         status, resp = handle_request_doc(
             engine, doc, trace_header=self.headers.get(TRACE_HEADER),
             cascade=self.cascade, model_id=mid)
-        with telemetry.stage("frontend/reply"):  # on the timeline only
+        with telemetry.stage("frontend/reply",
+                             engine.hists["frontend/reply"]):
             self._reply(status, resp)
         if self.request_hook is not None:
             self.request_hook(status)

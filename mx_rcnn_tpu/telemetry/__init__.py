@@ -27,6 +27,7 @@ back into the human table.
 
 from __future__ import annotations
 
+import resource
 import sys
 import time
 from typing import Optional
@@ -39,7 +40,7 @@ from mx_rcnn_tpu.telemetry.sink import (HIST_LE, NULL, RING_SIZE,
 __all__ = ["Telemetry", "NullTelemetry", "NULL", "RING_SIZE",
            "SCHEMA_VERSION", "SUMMARY_NAME", "Hist", "HIST_LE",
            "quantile_from_counts", "configure", "get", "reset_null",
-           "shutdown", "stage"]
+           "shutdown", "stage", "thread_usage"]
 
 _active: "NullTelemetry | Telemetry" = NULL
 
@@ -67,6 +68,21 @@ def get() -> "NullTelemetry | Telemetry":
     return _active
 
 
+def thread_usage():
+    """``(cpu_s, minflt)`` of the calling thread since it started, from ONE
+    ``getrusage(RUSAGE_THREAD)``: its user + system seconds and its minor
+    page faults.  The difference of two readings on one thread is what it
+    ran, and faulted, in between; wall time less that CPU is time it did
+    not run: waiting for the GIL, a lock, a core or the device.  The
+    seconds advance at the scheduler's tick (4 ms on a Linux of HZ=250, 10
+    ms on the chip's host, whose thread CPU clock is no finer), so one use
+    of a short stage reads 0 or a tick; a window's sum over many uses is
+    what a reader takes.  One syscall, not two (the thread CPU clock beside
+    it): on the chip's host each costs about 6 µs, under the GIL."""
+    r = resource.getrusage(resource.RUSAGE_THREAD)
+    return r.ru_utime + r.ru_stime, r.ru_minflt
+
+
 class stage:
     """One timed stage: ``with telemetry.stage("serve/forward", hist) as st``.
 
@@ -76,13 +92,20 @@ class stage:
     when no profile is being taken — takes ``perf_counter()`` at entry and
     exit, and adds the duration to ``seconds`` (the total over ``uses``),
     for callers that hand the time on (tracectx phases, compile seconds).
+    Inside those two instants it reads :func:`thread_usage` once each, and
+    adds the thread's CPU seconds and minor page faults in between to
+    ``cpu_seconds`` and ``minflt``: a use is entered and left on ONE thread
+    (no stage of the program crosses threads; ``tests/test_stage_cpu.py``
+    holds the server to it), so ``seconds - cpu_seconds`` is what the
+    thread spent not running — over many uses: one use's CPU is good to a
+    scheduler's tick.
 
     With a ``hist`` each use is booked as it ends: one observation into the
-    hist and, when a sink is on, one span into it (``add``, what
-    ``tel.span`` did — in trace mode with the wall-clock start).  Without
-    one nothing is booked: the owner calls :meth:`book` where the hist is
-    known only later or the stage is summed over a batch's images, or
-    leaves it at the annotation and the clock.
+    hist (with its CPU and faults) and, when a sink is on, one span into it
+    (``add``, what ``tel.span`` did — in trace mode with the wall-clock
+    start).  Without one nothing is booked: the owner calls :meth:`book`
+    where the hist is known only later or the stage is summed over a
+    batch's images, or leaves it at the annotation and the clock.
 
     jax is never imported here: the annotation is entered only where the
     process already has it (``"jax" in sys.modules``).  ``annotate=False``
@@ -90,8 +113,8 @@ class stage:
     parts are annotated themselves: an enclosing event would cover every
     idle gap and name none of them."""
 
-    __slots__ = ("name", "hist", "annotate", "seconds", "uses", "_ann",
-                 "_t0", "_w0")
+    __slots__ = ("name", "hist", "annotate", "seconds", "cpu_seconds",
+                 "minflt", "uses", "_ann", "_t0", "_w0", "_u0")
 
     def __init__(self, name: str, hist: Optional[Hist] = None,
                  annotate: bool = True):
@@ -99,6 +122,8 @@ class stage:
         self.hist = hist
         self.annotate = annotate
         self.seconds = 0.0
+        self.cpu_seconds = 0.0
+        self.minflt = 0
         self.uses = 0
 
     def __enter__(self) -> "stage":
@@ -110,24 +135,32 @@ class stage:
             self._ann.__enter__()
         self._w0 = time.time() if _active.trace else None
         self._t0 = time.perf_counter()
+        self._u0 = thread_usage()   # inside the wall clock's two instants
         return self
 
     def __exit__(self, *exc):
+        cpu, flt = thread_usage()
         dt = time.perf_counter() - self._t0
         if self._ann is not None:
             self._ann.__exit__(*exc)
+        cpu -= self._u0[0]
+        flt -= self._u0[1]
         self.seconds += dt
+        self.cpu_seconds += cpu
+        self.minflt += flt
         self.uses += 1
         if self.hist is not None:
-            self.hist.observe(dt)
+            self.hist.observe(dt, cpu_s=cpu, minflt=flt)
             if _active.enabled:
                 _active.add(self.name, dt, ts=self._w0)
         return False
 
     def book(self, hist: Hist) -> None:
-        """All uses so far as ONE observation into ``hist`` and one span
-        record (``n`` = the uses) into the sink when it is on."""
-        hist.observe(self.seconds)
+        """All uses so far as ONE observation into ``hist`` (with their
+        summed CPU and faults) and one span record (``n`` = the uses) into
+        the sink when it is on."""
+        hist.observe(self.seconds, cpu_s=self.cpu_seconds,
+                     minflt=self.minflt)
         if _active.enabled:
             _active.add(self.name, self.seconds, n=self.uses)
 

@@ -134,19 +134,25 @@ class Hist:
     SNAP_INTERVAL_S = 0.5
     SNAP_KEEP = 256  # × interval ⇒ ~2 min of window reach
 
-    __slots__ = ("count", "sum", "buckets", "_lock", "_snaps",
-                 "_last_snap_t")
+    __slots__ = ("count", "sum", "cpu_sum", "minflt", "buckets", "_lock",
+                 "_snaps", "_last_snap_t")
 
     def __init__(self):
         self.count = 0
         self.sum = 0.0
+        # a stage clock's observations also carry the CPU seconds and minor
+        # page faults of their thread (telemetry.stage); other callers
+        # leave both at 0
+        self.cpu_sum = 0.0
+        self.minflt = 0
         self.buckets: List[int] = [0] * (len(HIST_LE) + 1)
         self._lock = threading.Lock()
         self._snaps: collections.deque = collections.deque(
             maxlen=self.SNAP_KEEP)
         self._last_snap_t: Optional[float] = None
 
-    def observe(self, value: float, now: Optional[float] = None):
+    def observe(self, value: float, now: Optional[float] = None,
+                cpu_s: float = 0.0, minflt: int = 0):
         value = float(value)
         i = bisect.bisect_left(HIST_LE, value)
         with self._lock:
@@ -159,6 +165,8 @@ class Hist:
                 self._last_snap_t = now
             self.count += 1
             self.sum += value
+            self.cpu_sum += cpu_s
+            self.minflt += minflt
             self.buckets[i] += 1
 
     def quantile(self, q: float) -> Optional[float]:
@@ -198,6 +206,14 @@ class Hist:
             for i, c in enumerate(other["buckets"]):
                 self.buckets[i] += int(c)
         return self
+
+    def clock(self) -> dict:
+        """The monotone totals a ``/metrics["stages"]`` entry carries:
+        observations, their seconds, and their thread's CPU seconds and
+        minor page faults — read together, under the lock."""
+        with self._lock:
+            return {"count": self.count, "sum_s": self.sum,
+                    "cpu_s": self.cpu_sum, "minflt": self.minflt}
 
     def to_dict(self) -> dict:
         with self._lock:

@@ -79,6 +79,7 @@ def test_the_forward_gets_what_np_stack_gave_on_every_live_row(case):
                 futs[1].result(timeout=30)
         wait_booked(engine)
         counters = dict(engine.counters)
+        rows = engine.hists["serve/stage_row"].count   # rows written
     finally:
         engine.stop()
     assert len(engine.predictor.seen) == 1
@@ -96,7 +97,7 @@ def test_the_forward_gets_what_np_stack_gave_on_every_live_row(case):
         assert results[i] == alone(cfg, imgs[i])
     assert counters["served"] == len(live)
     assert counters["batches"] == 1
-    assert counters["staged_rows"] == counters["requests"] == len(imgs)
+    assert rows == counters["requests"] == len(imgs)
     assert counters["assemble_waits"] == 0
 
 
@@ -247,6 +248,7 @@ def test_32_threads_each_response_is_its_own_images():
         alive = [th.name for th in threads if th.is_alive()]
         wait_booked(engine)
         counters = dict(engine.counters)
+        rows = engine.hists["serve/stage_row"].count   # rows written
     finally:
         sys.setswitchinterval(interval)
         engine.stop()
@@ -254,7 +256,7 @@ def test_32_threads_each_response_is_its_own_images():
     assert not wrong, wrong[:3]
     n = n_threads * per_thread
     assert counters["served"] == counters["requests"] == n
-    assert counters["staged_rows"] == n
+    assert rows == n
     assert counters["rejected"] == counters["deadline_exceeded"] == 0
     # the first burst (32 at once) is the deepest the queue ever gets; one
     # more can follow it: two partial batches in flight beside a full queue
@@ -303,6 +305,7 @@ def test_the_dispatcher_waits_for_a_row_whose_copy_is_held_back(monkeypatch):
         got_slow = out["slow"].result(timeout=30)
         wait_booked(engine)
         counters = dict(engine.counters)
+        rows = engine.hists["serve/stage_row"].count   # rows written
         waited_s = engine.hists["serve/assemble"].to_dict()["sum"]
     finally:
         hold.set()
@@ -312,7 +315,7 @@ def test_the_dispatcher_waits_for_a_row_whose_copy_is_held_back(monkeypatch):
     # the held row, and the quick one if the claim came before its copy
     # (a notify under the lock) had finished
     assert counters["assemble_waits"] in (1, 2)
-    assert counters["staged_rows"] == counters["served"] == 2
+    assert rows == counters["served"] == 2
     assert waited_s > 0.2
     assert got_slow == alone(cfg, raw_image(60, 100, slow_value))
     assert got_quick == alone(cfg, raw_image(60, 100, 60))
@@ -343,12 +346,14 @@ def test_a_copy_that_raises_fails_its_own_request_and_nobody_waits(
         dets = good.result(timeout=30)
         wait_booked(engine)
         counters = dict(engine.counters)
+        rows = engine.hists["serve/stage_row"].count   # rows written
     finally:
         engine.stop()
     monkeypatch.undo()
     assert dets == alone(cfg, raw_image(60, 100, 60))
-    assert counters["staged_rows"] == counters["served"] == 1
-    assert counters["requests"] == 2
+    # the failed copy is a use of the clock too: one a request admitted
+    assert rows == counters["requests"] == 2
+    assert counters["served"] == 1
 
 
 # -- (f) --serve-e2e: the cascade's re-submission and capture --------------

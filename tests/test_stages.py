@@ -28,16 +28,17 @@ PER_BATCH = ("serve/assemble", "serve/forward", "serve/readback",
              "serve/postprocess", "serve/post/decode", "serve/post/nms",
              "serve/service_time")
 # ... what a request observes once, and which of those only over HTTP
-PER_REQUEST = ("serve/host_prep", "serve/queue_wait", "serve/request_time")
-PER_HTTP_REQUEST = ("frontend/read", "frontend/decode")
+PER_REQUEST = ("serve/host_prep", "serve/stage_row", "serve/queue_wait",
+               "serve/request_time")
+PER_HTTP_REQUEST = ("frontend/read", "frontend/decode", "frontend/reply")
 # the names that have to be events on the profiler's timeline: the
-# dispatcher thread's, and a request thread's (serve/post/records and
-# frontend/reply are events only: no clock of theirs is kept)
+# dispatcher thread's, and a request thread's (serve/post/records is an
+# event only: no clock of its is kept)
 DISPATCHER_EVENTS = ("serve/idle", "serve/assemble", "serve/forward",
                      "serve/readback", "serve/post/decode", "serve/post/nms",
                      "serve/post/records")
 REQUEST_EVENTS = ("frontend/read", "frontend/decode", "serve/host_prep",
-                  "frontend/reply")
+                  "serve/stage_row", "frontend/reply")
 
 
 # -- the helper --------------------------------------------------------------
@@ -174,7 +175,8 @@ def test_metrics_stages_count_batches_and_requests_and_are_monotone(arrivals):
             for name in PER_REQUEST:
                 assert m["stages"][name]["count"] == 2 * batches, name
             for name in PER_HTTP_REQUEST:  # nothing came over HTTP
-                assert m["stages"][name] == {"count": 0, "sum_s": 0.0}
+                assert m["stages"][name] == {"count": 0, "sum_s": 0.0,
+                                             "cpu_s": 0.0, "minflt": 0}
             s = {k: v["sum_s"] for k, v in m["stages"].items()}
             # flat siblings inside one turn: they cannot outlast it
             assert (s["serve/assemble"] + s["serve/forward"]
