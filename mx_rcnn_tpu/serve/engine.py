@@ -33,11 +33,13 @@ Mechanics:
   bookkeeping, the hand-over of its staging batch, ``predictor.predict``
   until it returns (on a mask network the batch's pyramid captured) and
   ``copy_to_host_async`` on the outputs.  Its **finish** (``_finish``): the
-  read-back, the post-process, on a mask network the mask stage over that
-  batch's own pyramid, the futures, counters, hists, trace spans and
-  capture, and — either way, and only there — the release of the inflight
-  slot and of the staging batch.  A **turn** of the dispatcher thread
-  begins with the claim of a due batch, which is launched FIRST; then the
+  read-back, the post-process, the futures, counters, hists, trace spans
+  and capture (on a mask network: up to the dispatch of the mask program
+  over that batch's own pyramid, the rest in its tail, below), and —
+  either way, and only there — the release of the staging batch and,
+  unless a tail is pending, of the inflight slot.  A **turn** of the
+  dispatcher thread begins with the claim of a due batch, which is
+  launched FIRST; then the
   flight the turn before left is finished while the device runs the new
   one.  With nothing due a pending flight is finished at once, inside the
   turn that launched it, so a lone request is answered in the serial order
@@ -45,10 +47,19 @@ Mechanics:
   are launched and unfinished at any instant: a constant of the loop, not
   a knob — how often the overlap engages depends only on what the loop
   finds in its queue (counter ``overlapped_turns`` ÷ ``batches``; the sink
-  gets the gauge ``serve/in_flight``, 1 or 2, at each launch).  A failure
-  in either half fails that batch's requests only.  ``dispatch_batch``
-  (the external dispatcher's surface) is one batch's launch and finish
-  back to back.
+  gets the gauge ``serve/in_flight`` at each launch: the batches holding
+  an inflight slot, 1 or 2, 3 with a mask network's pending tail).  On a
+  mask network the finish ends with the dispatch of the batch's mask
+  program, and its read-back, paste and answers are the batch's **tail**,
+  finished in the next turn right after the next predict's read-back
+  (with nothing due, at once), so the dispatcher never waits for a mask
+  program queued behind a predict (:meth:`ServeEngine._dispatch_loop`;
+  counter ``deferred_masks`` ÷ ``batches``: how often the tail lands in a
+  later turn, 0 on a box network); the batch keeps its inflight slot
+  until its tail.  A failure
+  in any stage fails that batch's requests only.  ``dispatch_batch``
+  (the external dispatcher's surface) is one batch's launch, finish and
+  tail back to back.
 * Backpressure is a bounded queue: ``submit`` beyond ``max_queue``
   raises :class:`RejectedError` (the frontend's 503) instead of letting
   latency grow without bound.  Per-request deadlines are swept before
@@ -100,16 +111,20 @@ pass the wall clock; it is NOT a batch's claim → last response (that is
 ``serve_e2e`` mode.  On a mask network (``cfg.network.HAS_MASK``, and only
 there) the turn has a second stage after the post-process
 (:meth:`ServeEngine._mask_stage`): ``serve/mask`` (the whole of it, a clock
-without an annotation) = ``serve/mask/forward`` (the records' boxes and
-classes filled into one ``(B, MAX_PER_IMAGE)`` pair + the mask program's
-dispatch over the pyramid ``predict`` left on the device) +
-``serve/mask/readback`` (the wait for the device + d2h of the ``(B, R, 28,
-28)`` probabilities) + per image ``serve/mask/paste`` (paste into the
-request's raw frame + RLE; one observation a batch), with the counters
+without an annotation: the dispatcher's seconds in the stage, one
+observation a batch, never the wall across the two turns it spans) =
+``serve/mask/forward`` (the records' boxes and classes filled into one
+``(B, MAX_PER_IMAGE)`` pair + the mask program's dispatch over the pyramid
+``predict`` left on the device, at the end of the batch's finish) + in its
+tail ``serve/mask/readback`` (what is left of the wait for the device + the
+d2h of the ``(B, R, 28, 28)`` probabilities: at most one mask program's
+run) + per image ``serve/mask/paste`` (paste into the request's raw frame +
+RLE; one observation a batch), with the counters
 ``mask_dispatches``, ``mask_rois`` (live records masked),
 ``mask_readback_bytes`` and ``mask_native`` (records pasted by the native
 call) on ``/metrics``; the futures are set at its end, each record with its
-``"segmentation"``.  Request threads: ``frontend/read``,
+``"segmentation"``, and ``serve/request_time`` and the batch's trace spans
+are taken there.  Request threads: ``frontend/read``,
 ``frontend/decode``, ``serve/host_prep``, ``serve/stage_row`` (the copy of
 the prepared image into its staging row, :meth:`ServeEngine._write_row`)
 and ``frontend/reply`` (the response's serialisation and write).
@@ -136,8 +151,10 @@ where the library did not build and the Python loop ran),
 ``assemble_waits`` (rows whose copy was still running
 when a turn claimed their batch, and was waited for), ``staging_allocs`` (staging batches ever
 allocated: flat after warm-up, like ``recompiles``; the sink also gets
-the gauge ``serve/staging_free``) and ``overlapped_turns`` (batches
-launched while another was in flight), on a pyramid
+the gauge ``serve/staging_free``), ``overlapped_turns`` (batches
+launched while another was in flight) and ``deferred_masks`` (a mask
+network's batches whose mask read-back and paste ran in a later turn than
+their post-process), on a pyramid
 network ``rois_valid`` and ``rois_level_p2`` … ``rois_level_p5`` (the
 proposals the joint NMS kept and the level the FPN
 paper's eq. 1 pools each from, counted on the host by the legacy path) and
@@ -343,12 +360,14 @@ class _Staging:
 class _Flight:
     """One batch between the two halves of its turn: launched — its forward
     enqueued on the device, the d2h of its outputs under way — and not yet
-    finished.  Written by :meth:`ServeEngine._launch`, read by
-    :meth:`ServeEngine._finish`, both on the dispatcher's thread."""
+    finished; on a mask network also between its finish and its tail, the
+    mask program dispatched and not yet read back (``mask``).  Written by
+    :meth:`ServeEngine._launch`, read by :meth:`ServeEngine._finish` and
+    :meth:`ServeEngine._finish_tail`, all on the dispatcher's thread."""
 
     __slots__ = ("reqs", "key", "staging", "t_claim", "usage_claim",
                  "overlapped", "shape", "first", "outs", "feats", "h2d_bytes",
-                 "phases")
+                 "phases", "mask", "xfer", "deferred")
 
     def __init__(self, reqs: List[_Request], t_claim: float, usage_claim):
         self.reqs = reqs            # the live requests; none: the launch
@@ -364,6 +383,34 @@ class _Flight:
         self.feats = None           # a mask network: this batch's pyramid
         self.h2d_bytes = 0          # serve_e2e: what the one h2d shipped
         self.phases: Dict[str, float] = {}  # the tracer's phase seconds
+        self.mask: Optional[_MaskStage] = None  # dispatched, not read back
+        self.xfer: dict = {}        # the finish's counter increments, booked
+        # with the batch by its tail
+        self.deferred = False       # its tail ran in a later turn
+
+
+class _MaskStage:
+    """A mask network's second stage of one batch, from the dispatch of its
+    mask program at the end of the batch's finish
+    (:meth:`ServeEngine._mask_stage`) to the read-back, paste and answers in
+    its tail (:meth:`ServeEngine._mask_tail`).  ``whole`` is the stage's
+    one clock, entered in both, booked once at the tail's end."""
+
+    __slots__ = ("held", "feats", "rows", "R", "shape", "use_native",
+                 "whole", "start", "probs", "first", "forward_s", "h2d_bytes")
+
+    def __init__(self, held, feats, rows: int, R: int, shape, use_native):
+        self.held = held            # [(request, its record list)]
+        self.feats = feats          # the pyramid, while a pass may need it
+        self.rows, self.R = rows, R  # a pass fills one (rows, R) pair
+        self.shape = shape          # the mask program's registry key
+        self.use_native = use_native
+        self.whole = telemetry.stage("serve/mask", annotate=False)
+        self.start = 0              # the first record of the current pass
+        self.probs = None           # the current pass's output, on the device
+        self.first = False          # the current pass is the program's first
+        self.forward_s = 0.0        # and its serve/mask/forward seconds
+        self.h2d_bytes = 0          # one pass's boxes + labels
 
 
 def _copy_to_host_async(arrays):
@@ -435,8 +482,9 @@ class ServeEngine:
         self._ready = threading.Event()
         # drain mode (weight hot-reload): no NEW admissions, queued work
         # still flushes; _inflight counts batches claimed and not yet
-        # finished (at most two: _dispatch_loop), so drain() can block
-        # until the device is quiescent and every answer is out
+        # finished (at most two, and a mask network's pending tail:
+        # _dispatch_loop), so drain() can block until the device is
+        # quiescent and every answer is out
         self._draining = False
         self._inflight = 0
         # checkpoint generation serving right now (atomic under _lock;
@@ -472,8 +520,10 @@ class ServeEngine:
                          # written are serve/stage_row's count
                          "assemble_waits": 0, "staging_allocs": 0,
                          # batches launched while another was in flight
-                         # (/ batches: how often the overlap engages)
-                         "overlapped_turns": 0,
+                         # (/ batches: how often the overlap engages), and
+                         # a mask network's batches whose mask read-back and
+                         # paste ran in a later turn than their post-process
+                         "overlapped_turns": 0, "deferred_masks": 0,
                          "host_prep_ms_total": 0.0,
                          # stream-aware flush bookkeeping: batches that
                          # carried >= 1 stream frame, the frame count, and
@@ -1124,8 +1174,9 @@ class ServeEngine:
     def dispatch_batch(self, batch: List[_Request]):
         """Run one batch claimed by :meth:`poll` (the external
         dispatcher's surface): its launch and its finish back to back, one
-        turn.  Fails the batch on error, and releases the inflight slot
-        and the batch's staging batch either way (:meth:`_finish`)."""
+        turn — on a mask network its tail too.  Fails the batch on error,
+        and releases the inflight slot and the batch's staging batch either
+        way (:meth:`_finish`)."""
         self._finish(self._launch(batch, time.monotonic(),
                                   telemetry.thread_usage()), ends_turn=True)
 
@@ -1148,12 +1199,30 @@ class ServeEngine:
         serial order falls out whenever the queue holds nothing else, and
         the loop never idles with a batch in flight.  So at most two
         batches are launched and unfinished at any instant — the one
-        being finished and the one launched at this turn's top."""
+        being finished and the one launched at this turn's top.
+
+        On a mask network a batch has a third stage, its **tail**: the
+        finish of batch k ends with the dispatch of its mask program M(k),
+        and M(k)'s read-back, the paste, the answers and the booking of k
+        run in the next turn, right after the read-back of predict P(k+1).
+        A turn is then: claim k+1 → launch P(k+1) → read back P(k) → the
+        tail of k−1 → post-process k → dispatch M(k), and the device runs
+        P(k), M(k−1), P(k+1), M(k), P(k+2), …  M(k−1) was dispatched after
+        P(k) and before P(k+1), so when its read-back begins every predict
+        ahead of it on the device has been read back: the dispatcher waits
+        for at most one mask program, never for a predict (the invariant).
+        With nothing due the pending tail is finished at once with its
+        flight, so the loop never idles with a tail pending either; a
+        batch keeps its inflight slot until its tail (``_inflight`` may
+        read three at a launch), and its staging batch goes back at the
+        end of its finish."""
         flight = None   # launched by the turn under way, not finished yet
+        tail = None     # finished up to its mask program's dispatch
         while True:
             with self._cond:
                 expired, batch, wait = (([], None, None) if self._stop
                                         else self._claim_locked())
+                # a pending tail always has a pending flight after it
                 if flight is None and batch is None and not expired:
                     if self._stop:
                         return
@@ -1177,7 +1246,8 @@ class ServeEngine:
                     self._book_turn(flight, now, usage)
                 launched = self._launch(batch, now, usage)
             if flight is not None:
-                self._finish(flight, ends_turn=launched is None)
+                tail = self._finish(flight, ends_turn=launched is None,
+                                    tail=tail)
             flight = launched
 
     def _book_turn(self, flight: _Flight, now: float, usage):
@@ -1246,24 +1316,72 @@ class ServeEngine:
         else:
             self._launch_legacy(flight, tel)
 
-    def _finish(self, flight: _Flight, ends_turn: bool):
+    def _finish(self, flight: _Flight, ends_turn: bool,
+                tail: Optional[_Flight] = None) -> Optional[_Flight]:
         """The second half of a batch: the read-back of its outputs, the
-        post-process (on a mask network the mask stage over this batch's
-        own pyramid), the futures, then the hists, counters, trace spans
-        and capture.  A failure fails this batch's requests only.  Either
-        way the inflight slot and the staging batch are released here, and
-        only here — after the read-back of this batch's own outputs has
-        returned: jax reads the host buffer after ``predict`` has returned
-        (and ``_launch_e2e``'s ``device_put`` arrays may alias it until
-        they go), so a staging batch rewritten earlier would corrupt the
-        batch in flight.  ``ends_turn``: no other flight is pending, so the
-        turn that launched this one ends with it, and is booked before the
-        slot goes (``drain`` returning means every clock is booked)."""
+        post-process, the futures, then the hists, counters, trace spans
+        and capture.  On a mask network the finish ends instead with the
+        dispatch of the mask program over this batch's own pyramid, and
+        the rest — the mask read-back, the paste, the futures and the
+        booking — is the batch's tail (:meth:`_finish_tail`): finished
+        here at once when ``ends_turn``, else returned, for the next turn's
+        finish to run as its ``tail`` right after its own read-back
+        (:meth:`_dispatch_loop` says why there).  A failure fails this
+        batch's requests only; a ``tail`` this finish did not reach is
+        finished on the way out.  Either way the staging batch is
+        released here, and only here — after the read-back of this batch's
+        own outputs has returned: jax reads the host buffer after
+        ``predict`` has returned (and ``_launch_e2e``'s ``device_put``
+        arrays may alias it until they go), so a staging batch rewritten
+        earlier would corrupt the batch in flight; the mask program reads
+        no image.  The
+        inflight slot goes with the batch's last stage, here or at its
+        tail's end.  ``ends_turn``: no other flight is pending, so the turn
+        that launched this one ends with it, and is booked before the slot
+        goes (``drain`` returning means every clock is booked)."""
         try:
             if flight.reqs:
-                self._finish_batch(flight)
+                self._finish_batch(flight, tail)
         except BaseException as e:  # noqa: BLE001 — fail the batch
             logger.exception("serve batch failed")
+            flight.mask = None
+            for r in flight.reqs:
+                r.future._set_error(e)
+        finally:
+            if tail is not None and tail.mask is not None:
+                self._finish_tail(tail)
+            deferred = flight.mask is not None
+            if ends_turn and not deferred:
+                self._book_turn(flight, time.monotonic(),
+                                telemetry.thread_usage())
+            with self._cond:
+                if not deferred:
+                    self._inflight -= 1
+                self._retire_locked(flight.key, flight.staging)
+                free = len(self._staging_free.get(flight.key, ()))
+                self._cond.notify_all()  # drain() waits on this
+            telemetry.get().gauge("serve/staging_free", free)
+        if not deferred:
+            return None
+        if ends_turn:
+            self._finish_tail(flight, ends_turn=True)
+            return None
+        flight.deferred = True
+        return flight
+
+    def _finish_tail(self, flight: _Flight, ends_turn: bool = False):
+        """The third stage of a mask network's batch: its mask read-back,
+        paste and answers (:meth:`_mask_tail`), then its booking.  Never
+        raises: a failure fails this batch's requests only.  Releases the
+        batch's inflight slot, after booking its turn when ``ends_turn``."""
+        m, flight.mask = flight.mask, None
+        try:
+            xfer, flight.phases["mask"] = self._mask_tail(m, telemetry.get())
+            for k, v in flight.xfer.items():
+                xfer[k] = xfer.get(k, 0) + v
+            self._book_batch(flight, xfer)
+        except BaseException as e:  # noqa: BLE001 — fail the batch
+            logger.exception("serve batch failed in its mask stage")
             for r in flight.reqs:
                 r.future._set_error(e)
         finally:
@@ -1272,12 +1390,22 @@ class ServeEngine:
                                 telemetry.thread_usage())
             with self._cond:
                 self._inflight -= 1
-                self._retire_locked(flight.key, flight.staging)
-                free = len(self._staging_free.get(flight.key, ()))
                 self._cond.notify_all()  # drain() waits on this
-            telemetry.get().gauge("serve/staging_free", free)
 
-    def _finish_batch(self, flight: _Flight):
+    def _finish_batch(self, flight: _Flight, tail: Optional[_Flight]):
+        tel = telemetry.get()
+        if self.opts.serve_e2e:
+            xfer = self._finish_e2e(flight, tel)
+        else:
+            xfer = self._finish_legacy(flight, tel, tail)
+        if flight.mask is not None:
+            flight.xfer = xfer      # booked with the batch, by its tail
+        else:
+            self._book_batch(flight, xfer)
+
+    def _book_batch(self, flight: _Flight, xfer: dict):
+        """A batch answered: its request times, counters, trace spans and
+        capture, from its own instants, whatever else the turn did."""
         tel = telemetry.get()
         B = self.opts.batch_size
         reqs, now = flight.reqs, flight.t_claim
@@ -1286,14 +1414,10 @@ class ServeEngine:
         # attribute check per batch when tracing is off (the capture
         # contract)
         tracer = tracectx.get()
-        if self.opts.serve_e2e:
-            xfer = self._finish_e2e(flight, tel)
-        else:
-            xfer = self._finish_legacy(flight, tel)
         # end-to-end request time once per request (global + per-bucket
         # family) — into the engine's own Hists AND the active sink, so
         # the SLO controller and /metrics see them regardless of telemetry
-        # config; from the batch's own instants, whatever else the turn did
+        # config
         done = time.monotonic()
         new_bucket_hists = {}
         for r in reqs:
@@ -1314,6 +1438,7 @@ class ServeEngine:
             self.counters["batches"] += 1
             self.counters["served"] += len(reqs)
             self.counters["overlapped_turns"] += flight.overlapped
+            self.counters["deferred_masks"] += flight.deferred
             if stream_frames:
                 self.counters["stream_batches"] += 1
                 self.counters["stream_batch_frames"] += stream_frames
@@ -1325,6 +1450,8 @@ class ServeEngine:
         tel.counter("serve/images", len(reqs))
         if flight.overlapped:
             tel.counter("serve/overlapped_turns")
+        if flight.deferred:
+            tel.counter("serve/deferred_masks")
         tel.counter("serve/post_kept", xfer["post_kept"])
         if "post_candidates" in xfer:
             tel.counter("serve/post_candidates", xfer["post_candidates"])
@@ -1452,13 +1579,16 @@ class ServeEngine:
             _copy_to_host_async(flight.outs)
         flight.phases["forward"] = fwd.seconds
 
-    def _finish_legacy(self, flight: _Flight, tel) -> dict:
-        """PR-3 path, finish half: full score/delta readback, host decode +
-        per-class NMS, on a mask network the mask stage.  Returns the
-        batch's counter increments (two h2d arrays — images and im_info
-        ship separately into the jit call — one dispatch, one fat readback,
-        the post-process's candidates and records); its phase seconds for
-        the engine's dispatch sub-spans go into ``flight.phases``."""
+    def _finish_legacy(self, flight: _Flight, tel,
+                       tail: Optional[_Flight]) -> dict:
+        """PR-3 path, finish half: full score/delta readback, on a mask
+        network then the ``tail`` of the batch before, host decode +
+        per-class NMS, on a mask network the dispatch of the mask program.
+        Returns the batch's counter increments (two h2d arrays — images and
+        im_info ship separately into the jit call — one dispatch, one fat
+        readback, the post-process's candidates and records); its phase
+        seconds for the engine's dispatch sub-spans go into
+        ``flight.phases``."""
         import jax
 
         reqs, phases = flight.reqs, flight.phases
@@ -1469,6 +1599,11 @@ class ServeEngine:
         with self._stage("serve/readback") as rb:
             rois, roi_valid, cls_prob, bbox_deltas = jax.device_get(
                 flight.outs)
+        if tail is not None:
+            # the batch before's mask program ran right behind this batch's
+            # predict, which is back: its answers go out before this
+            # batch's post-process, at most one mask program's wait away
+            self._finish_tail(tail)
         if flight.first and self.registry is not None:
             # first dispatch of a shape = its compile: the forward +
             # readback wall is the compile(+first run) cost this program
@@ -1526,99 +1661,127 @@ class ServeEngine:
         if cfg.network.HAS_FPN:
             xfer.update(_roi_level_counts(rois, valid))
         if self._has_mask:
-            # last in the turn: once the futures are set, the request
-            # threads serialise 100 count lists a reply under the GIL, and
-            # whatever the dispatcher still did here waited for it (17-20
-            # ms a turn of bookkeeping that takes 3; chip run, PR 32)
-            mask_xfer, phases["mask"] = self._mask_stage(
-                held, flight.feats, len(images), tel)
-            for k, v in mask_xfer.items():
-                xfer[k] = xfer.get(k, 0) + v
+            # last: the records are answered with their masks, by the tail
+            self._mask_stage(flight, held, len(images), tel)
         return xfer
 
-    def _mask_stage(self, held: List[Tuple[_Request, List[dict]]], feats,
-                    n_rows: int, tel) -> Tuple[dict, float]:
-        """The second stage of a mask network's turn, after the host's
+    def _mask_stage(self, flight: _Flight,
+                    held: List[Tuple[_Request, List[dict]]], n_rows: int,
+                    tel):
+        """The second stage of a mask network's batch, after the host's
         decode + per-class NMS has chosen each image's final records
         (``held``: the live requests with their record lists): the records'
         boxes (scaled frame) and classes go back to the device in ONE
         ``(B, R, 4)`` / ``(B, R)`` pair — ``R`` = ``TEST.MAX_PER_IMAGE``,
         padding rows and slots zero — for ONE dispatch of the mask program
-        over the pyramid ``predict`` left there (``feats``), whatever the
-        number of live records, zero included: a static shape, so nothing
-        compiles after warm-up.  Then one read-back of the ``(B, R, M, M)``
-        probabilities, and per record the paste into the request's own raw
-        frame + RLE (``eval.tester.mask_to_rle``, the evaluator's function:
-        ``native.paste_rle``, or cv2 + numpy under ``TEST.MASK_PASTE =
-        "host"`` or without the library).  Each record gains
-        ``"segmentation": {"size": [h, w], "counts": [...]}``; then the
-        futures are set.  An image the score-tie rule left with more than
-        ``R`` records is drained in further passes of the same program.
+        over the pyramid ``predict`` left there (``flight.feats``), whatever
+        the number of live records, zero included: a static shape, so
+        nothing compiles after warm-up.  The d2h of the ``(B, R, M, M)``
+        probabilities is started, and the stage waits in ``flight.mask``
+        for the batch's tail (:meth:`_mask_tail`): in the loop a turn later,
+        once the next predict — the one program ahead of it — has been read
+        back, so the dispatcher never waits for a mask program queued
+        behind a predict (:meth:`_dispatch_loop`).
 
-        Stages: ``serve/mask`` (the whole stage; a clock without an
-        annotation) with ``serve/mask/forward`` (fill + dispatch),
-        ``serve/mask/readback`` (the wait for the device + d2h) and
-        ``serve/mask/paste`` (per image on the timeline, one observation a
-        batch).  -> (the batch's counter increments, the stage's seconds)."""
-        import jax
-
-        from mx_rcnn_tpu.eval.tester import mask_to_rle
-
+        Stages: ``serve/mask`` (the whole stage: this dispatch + the tail's
+        read-back, paste and answers, one observation a batch, booked by
+        the tail — the dispatcher's seconds in it, never the wall across
+        two turns; a clock without an annotation) with
+        ``serve/mask/forward`` (fill + dispatch) here, and in the tail
+        ``serve/mask/readback`` (what is left of the wait for the device +
+        the d2h) and ``serve/mask/paste`` (per image on the timeline, one
+        observation a batch)."""
         cfg = self.cfg
         R = cfg.TEST.MAX_PER_IMAGE if cfg.TEST.MAX_PER_IMAGE > 0 else 100
         use_native = (cfg.TEST.MASK_PASTE != "host"
                       and native.available("mxr_paste_rle"))
-        shape = self.predictor.masks_shape((n_rows, R, 4), feats)
+        m = _MaskStage(held, flight.feats, n_rows, R,
+                       self.predictor.masks_shape((n_rows, R, 4),
+                                                  flight.feats), use_native)
+        with m.whole:
+            self._mask_dispatch(m, tel)
+        # what the tail does not read is not held through the next turn:
+        # the predict's outputs, and the pyramid unless a further pass
+        # needs it (the device frees it once the program has run)
+        if not any(len(recs) > R for _, recs in held):
+            m.feats = None
+        flight.feats = flight.outs = None
+        flight.mask = m
+
+    def _mask_dispatch(self, m: _MaskStage, tel):
+        """One pass of the mask program: records ``m.start`` … ``+ R`` of
+        every image filled into the ``(B, R)`` pair, the dispatch, the d2h
+        started."""
+        boxes = np.zeros((m.rows, m.R, 4), np.float32)
+        labels = np.zeros((m.rows, m.R), np.int32)
+        with self._stage("serve/mask/forward") as fwd:
+            for r, recs in m.held:
+                part = recs[m.start:m.start + m.R]
+                if part:
+                    boxes[r.row, :len(part)] = np.asarray(
+                        [q["bbox"] for q in part], np.float32) \
+                        * np.float32(r.im_info[2])
+                    labels[r.row, :len(part)] = [q["cls"] for q in part]
+            m.first = self._note_first_dispatch(m.shape, "masks_from_feats",
+                                                tel)
+            m.probs = self.predictor.predict_masks_cached(
+                boxes, labels, token=None, feats=m.feats)
+            _copy_to_host_async((m.probs,))
+        m.forward_s = fwd.seconds
+        m.h2d_bytes = int(boxes.nbytes + labels.nbytes)
+
+    def _mask_tail(self, m: _MaskStage, tel) -> Tuple[dict, float]:
+        """The tail of a mask network's batch: one read-back of the mask
+        program's probabilities, and per record the paste into the
+        request's own raw frame + RLE (``eval.tester.mask_to_rle``, the
+        evaluator's function: ``native.paste_rle``, or cv2 + numpy under
+        ``TEST.MASK_PASTE = "host"`` or without the library).  Each record
+        gains ``"segmentation": {"size": [h, w], "counts": [...]}``; then
+        the futures are set.  An image the score-tie rule left with more
+        than ``R`` records is drained in further passes of the same
+        program, here, each dispatched and read back at once.
+        -> (the batch's counter increments, the stage's seconds)."""
+        import jax
+
+        from mx_rcnn_tpu.eval.tester import mask_to_rle
+
         paste = telemetry.stage("serve/mask/paste")
         passes = rois = 0
-        with self._stage("serve/mask", annotate=False) as whole:
-            start = 0
+        with m.whole:
             while True:
-                boxes = np.zeros((n_rows, R, 4), np.float32)
-                labels = np.zeros((n_rows, R), np.int32)
-                with self._stage("serve/mask/forward") as fwd:
-                    for r, recs in held:
-                        part = recs[start:start + R]
-                        if part:
-                            boxes[r.row, :len(part)] = np.asarray(
-                                [q["bbox"] for q in part], np.float32) \
-                                * np.float32(r.im_info[2])
-                            labels[r.row, :len(part)] = [q["cls"]
-                                                         for q in part]
-                    first = self._note_first_dispatch(
-                        shape, "masks_from_feats", tel)
-                    probs = self.predictor.predict_masks_cached(
-                        boxes, labels, token=None, feats=feats)
                 with self._stage("serve/mask/readback") as rb:
-                    probs = np.asarray(jax.device_get(probs), np.float32)
-                if first and self.registry is not None:
+                    probs = np.asarray(jax.device_get(m.probs), np.float32)
+                m.probs = None
+                if m.first and self.registry is not None:
                     self.predictor.record_compile_seconds(
-                        shape, fwd.seconds + rb.seconds,
+                        m.shape, m.forward_s + rb.seconds,
                         kind="masks_from_feats")
-                for r, recs in held:
+                for r, recs in m.held:
                     h, w = r.orig_hw
                     with paste:
-                        for i, rec in enumerate(recs[start:start + R]):
+                        for i, rec in enumerate(recs[m.start:m.start + m.R]):
                             rec["segmentation"] = mask_to_rle(
                                 probs[r.row, i], rec["bbox"], h, w,
-                                native=use_native)
+                                native=m.use_native)
                             rois += 1
                 passes += 1
-                start += R
-                if not any(len(recs) > start for _, recs in held):
+                m.start += m.R
+                if not any(len(recs) > m.start for _, recs in m.held):
                     break
+                self._mask_dispatch(m, tel)
             paste.book(self.hists["serve/mask/paste"])
-            for r, recs in held:
+            for r, recs in m.held:
                 r.future._set_result(recs)
+        m.whole.book(self.hists["serve/mask"])
         back = passes * int(probs.nbytes)
         return ({"mask_dispatches": passes, "mask_rois": rois,
                  "mask_readback_bytes": back,
-                 "mask_native": rois if use_native else 0,
-                 # the turn's boundary crossings, beside predict's
+                 "mask_native": rois if m.use_native else 0,
+                 # the batch's boundary crossings, beside predict's
                  "dispatches": passes, "readbacks": passes,
                  "readback_bytes": back, "h2d_transfers": 2 * passes,
-                 "h2d_bytes": passes * int(boxes.nbytes + labels.nbytes)},
-                whole.seconds)
+                 "h2d_bytes": passes * m.h2d_bytes},
+                m.whole.seconds)
 
     def _launch_e2e(self, flight: _Flight, tel):
         """Single-dispatch path (``--serve-e2e``), launch half: ONE
