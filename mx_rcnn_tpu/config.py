@@ -186,6 +186,23 @@ class NetworkConfig:
     VIT_HEADS: int = 12
     VIT_WINDOW: int = 14
     VIT_GLOBAL_BLOCKS: Tuple[int, ...] = (2, 5, 8, 11)
+    # Cascade R-CNN (arXiv:1906.09756): a box head a stage, each pooling
+    # again at the boxes the one before decoded and clipped (models/fpn.py
+    # roi_cascade), one box a RoI for every class, the scores averaged over
+    # the stages.  Stage t trains at foreground IoU ROI_CASCADE_FG_THRESH
+    # [t - 1] against targets normalised by 1 / ROI_CASCADE_WEIGHTS[t - 1]
+    # (Detectron2's cascade_bbox_reg_weights); train/checkpoint.py folds
+    # them into each stage's own output layer at save, so the served
+    # layers predict raw deltas.  Empty: one box head, run once
+    ROI_CASCADE_WEIGHTS: Tuple[Tuple[float, ...], ...] = ()
+    ROI_CASCADE_FG_THRESH: Tuple[float, ...] = ()
+
+    @property
+    def ROI_CASCADE_STAGES(self) -> int:
+        """Box stages — derived from the stages' weights, never stored; 1 =
+        one box head with per-class boxes, more = a RoI cascade with one
+        class-agnostic box a RoI."""
+        return max(len(self.ROI_CASCADE_WEIGHTS), 1)
 
     @property
     def NUM_ANCHORS(self) -> int:
@@ -371,6 +388,24 @@ _NETWORK_PRESETS = {
         FPN_ANCHOR_SCALES=(8,),
         FIXED_PARAMS_SHARED=("conv1", "bn1", "stage1", "stage2", "stage3",
                              "stage4", "lateral", "post", "gamma", "beta"),
+    ),
+    # Cascade Mask R-CNN (Cai & Vasconcelos, arXiv:1906.09756; Detectron2
+    # CascadeROIHeads): resnet101_fpn_mask's trunk, pyramid, RPN, stage-1
+    # head and mask head, a 4-wide class-agnostic box output on each of
+    # three box heads; stages 2-3 run in a program of their own
+    "resnet101_fpn_cascade_mask": dict(
+        NETWORK="resnet101",
+        HOST_S2D=True,
+        IMAGE_STRIDE=32,
+        HAS_FPN=True,
+        HAS_MASK=True,
+        RCNN_FEAT_STRIDE=4,
+        FPN_ANCHOR_SCALES=(8,),
+        FIXED_PARAMS_SHARED=("conv1", "bn1", "stage1", "stage2", "stage3",
+                             "stage4", "lateral", "post", "gamma", "beta"),
+        ROI_CASCADE_WEIGHTS=((10.0, 10.0, 5.0, 5.0), (20.0, 20.0, 10.0, 10.0),
+                             (30.0, 30.0, 15.0, 15.0)),
+        ROI_CASCADE_FG_THRESH=(0.5, 0.6, 0.7),
     ),
     # Mask R-CNN on a plain ViT-B trunk with the simple feature pyramid
     # (ViTDet, arXiv:2203.16527): one square bucket (generate_config sets

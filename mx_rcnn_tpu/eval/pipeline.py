@@ -41,9 +41,8 @@ from concurrent.futures import ThreadPoolExecutor
 import numpy as np
 
 from mx_rcnn_tpu import telemetry
-from mx_rcnn_tpu.ops.postprocess import (decode_image_boxes,
-                                         device_dets_to_per_class,
-                                         per_class_nms)
+from mx_rcnn_tpu.ops.postprocess import (device_dets_to_per_class,
+                                         image_boxes, per_class_nms)
 
 
 class _InFlight:
@@ -124,7 +123,7 @@ def run_pipelined(predictor, test_loader, *, all_boxes, all_masks, imdb,
             else:
                 rois, roi_valid, cls_prob, deltas = host
                 t = time.perf_counter()
-                boxes = decode_image_boxes(rois[b], deltas[b], im_info[b])
+                boxes = image_boxes(rois[b], deltas[b], im_info[b])
                 t_mid = time.perf_counter()
                 t_dec += t_mid - t
                 dets_pc = per_class_nms(cls_prob[b], boxes, roi_valid[b],

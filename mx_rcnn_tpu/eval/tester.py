@@ -25,9 +25,8 @@ from mx_rcnn_tpu.compile.registry import INFER_DTYPES
 from mx_rcnn_tpu.config import Config
 from mx_rcnn_tpu.data.loader import TestLoader
 from mx_rcnn_tpu.logger import logger
-from mx_rcnn_tpu.ops.postprocess import (decode_image_boxes,
-                                         device_dets_to_per_class,
-                                         per_class_nms)
+from mx_rcnn_tpu.ops.postprocess import (device_dets_to_per_class,
+                                         image_boxes, per_class_nms)
 
 
 def _variant_params(params, dtype: str):
@@ -210,6 +209,11 @@ class Predictor:
             jit2 = jax.jit
         self.params = params
         self._has_mask = bool(cfg.network.HAS_MASK)
+        self._roi_cascade = cfg.network.ROI_CASCADE_STAGES > 1
+        # the predict program's registry kind: with the pyramid among its
+        # outputs where a later program of the batch reads it
+        self._predict_kind = ("predict_wf" if self._has_mask
+                              or self._roi_cascade else "predict")
         self._feats = None  # pyramid cache: set by predict(), same batch only
         # cache-identity token: (images shape, monotonic predict counter).
         # predict() stamps it; the cached-mask entry points assert it so a
@@ -235,7 +239,7 @@ class Predictor:
         reg.register("pyramid", lambda: jax.jit(
             lambda p, x: model.apply({"params": unpack(p)}, x,
                                      method=model._pyramid)))
-        if self._has_mask:
+        if self._predict_kind == "predict_wf":
             def fwd_wf(p, images, im_info):
                 out, feats = model.apply({"params": unpack(p)},
                                          quant_in(images), im_info,
@@ -245,6 +249,21 @@ class Predictor:
                 return cast_out(out), feats
 
             reg.register("predict_wf", lambda: jit2(fwd_wf))
+        if self._roi_cascade:
+            cjit = (jax.jit if plan is None else
+                    partial(jax.jit, in_shardings=(
+                        plan.replicated(), None, bsh, bsh, bsh, bsh, bsh)))
+
+            # stages 2.. of the RoI cascade over predict's pyramid, a
+            # program of its own: "jit_roi_cascade" in the device trace
+            def roi_cascade(p, feats, rois, roi_valid, cls_prob, deltas,
+                            im_info):
+                return cast_out(model.apply(
+                    {"params": unpack(p)}, feats, rois, roi_valid, cls_prob,
+                    deltas, im_info, method=model.roi_cascade))
+
+            reg.register("roi_cascade", lambda: cjit(roi_cascade))
+        if self._has_mask:
             # feats sharding is None = inherit from the committed arrays:
             # on a space mesh the cached pyramid comes out of predict()
             # height-sharded, and pinning it to batch() here would make
@@ -418,7 +437,7 @@ class Predictor:
         defaults to the legacy forward program; the fused serve path
         passes ``"serve_e2e"`` so its programs are labeled apart."""
         if kind is None:
-            kind = "predict_wf" if self._has_mask else "predict"
+            kind = self._predict_kind
         return self.registry.note_dispatch(kind, shape)
 
     def record_compile_seconds(self, shape, seconds: float,
@@ -426,7 +445,7 @@ class Predictor:
         """Companion to :meth:`note_dispatch` for callers (the serve
         engine) that own the first-dispatch timing themselves."""
         if kind is None:
-            kind = "predict_wf" if self._has_mask else "predict"
+            kind = self._predict_kind
         self.registry.record_compile_seconds(kind, shape, seconds)
 
     @staticmethod
@@ -454,17 +473,32 @@ class Predictor:
         return out
 
     def predict(self, images, im_info):
+        """-> (rois, roi_valid, cls_prob, bbox_deltas, roi_scores), on the
+        device.  On a RoI cascade the ``roi_cascade`` program is launched
+        right behind predict, on its device arrays (no host round trip),
+        and its outputs take two places: ``cls_prob`` holds the (B, R, K)
+        probabilities averaged over the stages and ``bbox_deltas`` the
+        (B, R, 4) final boxes, decoded and clipped in the resized frame
+        (``ops.postprocess.image_boxes`` reads either)."""
         self._predict_count += 1
         self._feats_token = (tuple(images.shape), self._predict_count)
+        if self._predict_kind == "predict":
+            return self._dispatch("predict", images.shape,
+                                  self.registry.lookup("predict"),
+                                  images, im_info)
+        out, feats = self._dispatch(
+            "predict_wf", images.shape,
+            self.registry.lookup("predict_wf"), images, im_info)
+        if self._roi_cascade:
+            rois, roi_valid, cls_prob, deltas, roi_scores = out
+            boxes, scores, roi_valid = self._dispatch(
+                "roi_cascade", images.shape,
+                self.registry.lookup("roi_cascade"), feats, rois, roi_valid,
+                cls_prob, deltas, im_info)
+            out = (rois, roi_valid, scores, boxes, roi_scores)
         if self._has_mask:
-            out, feats = self._dispatch(
-                "predict_wf", images.shape,
-                self.registry.lookup("predict_wf"), images, im_info)
             self._feats = feats  # reused by predict_masks for this batch
-            return out
-        return self._dispatch("predict", images.shape,
-                              self.registry.lookup("predict"),
-                              images, im_info)
+        return out
 
     def predict_detections(self, images, im_info, max_per_image, thresh):
         """Fused forward + device post-process (``--device-postprocess``):
@@ -473,6 +507,7 @@ class Predictor:
         the full (R, K) scores + (R, 4K) deltas.  On mask configs the
         pyramid is cached exactly like ``predict`` (same token
         discipline)."""
+        self._refuse_roi_cascade("--device-postprocess")
         mpi = int(max_per_image)
         th = float(thresh)
         self._predict_count += 1
@@ -495,12 +530,22 @@ class Predictor:
         Device prep, the forward, and decode+NMS run fused — the caller
         (serve engine) does one ``device_put`` of the argument tuple, one
         call here, one ``device_get`` of the return."""
+        self._refuse_roi_cascade("--serve-e2e")
         mpi = int(max_per_image)
         th = float(thresh)
         fn = self.registry.lookup("serve_e2e", static=(mpi, th))
         shape = self.serve_e2e_shape(staged.shape, mpi, th)
         return self._dispatch("serve_e2e", shape, fn, staged, raw_hw,
                               ratio, im_info, flip)
+
+    def _refuse_roi_cascade(self, path: str):
+        if self._roi_cascade:
+            raise ValueError(
+                f"{path} cannot run a RoI cascade (Cascade R-CNN, "
+                f"network.ROI_CASCADE_STAGES = "
+                f"{self.cfg.network.ROI_CASCADE_STAGES}): its fused program "
+                f"decodes one head's per-class deltas; the default path "
+                f"runs the stages")
 
     @property
     def feats_token(self):
@@ -700,7 +745,8 @@ def im_detect(predictor: Predictor, batch: dict):
     divide by im_scale).
 
     Returns list of (scores (R, K), boxes (R, 4K), valid (R,)) numpy
-    triples, one per valid batch row.
+    triples, one per valid batch row; a RoI cascade's boxes are (R, 4), one
+    a RoI for every class.
 
     Contract: ``predictor.params`` must predict RAW deltas — i.e. params
     from a saved checkpoint (the de-normalize-at-save fold,
@@ -723,8 +769,8 @@ def im_detect(predictor: Predictor, batch: dict):
     with tel.span("eval/decode"):
         for b in range(n):
             # shared post-process path (ops/postprocess.py): (R, 4K)
-            # boxes in the original image frame
-            boxes = decode_image_boxes(rois[b], bbox_deltas[b], im_info[b])
+            # boxes in the original image frame, (R, 4) on a RoI cascade
+            boxes = image_boxes(rois[b], bbox_deltas[b], im_info[b])
             out.append((cls_prob[b], boxes, roi_valid[b]))
     return out
 

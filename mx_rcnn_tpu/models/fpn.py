@@ -11,6 +11,10 @@ from the FPN/Mask R-CNN papers and their standard implementations):
   P2–P5; static-shape trick — pool every level, select by one-hot (4 cheap
   gathers beat dynamic partitions on TPU).
 * head: 2×FC-1024 (the standard FPN box head), cls + bbox.
+* RoI cascade (ROI_CASCADE_STAGES > 1, Cascade R-CNN): a box head of its
+  own a stage, one class-agnostic box a RoI; stage 1 is the head above, in
+  the predict program; ``roi_cascade`` decodes, clips and pools again for
+  stages 2.. over the held pyramid and averages the stages' scores.
 * mask head (HAS_MASK): 14×14 ROIAlign on the assigned level → 4 convs +
   deconv → 28×28 per-class logits; targets are gt masks resampled into the
   RoI frame in-graph (ops/mask_target.py) from host-rasterized gt-box crops.
@@ -36,6 +40,7 @@ from mx_rcnn_tpu.models.heads import (MaskHead, RCNNOutput, RPNHead,
 from mx_rcnn_tpu.models.vit import SimpleFeaturePyramid, ViT
 from mx_rcnn_tpu.ops import (all_anchors, assign_anchor, generate_anchors,
                              propose, sample_rois)
+from mx_rcnn_tpu.ops.boxes import refine_boxes
 from mx_rcnn_tpu.ops.mask_target import mask_targets_for_rois
 from mx_rcnn_tpu.ops.proposal import propose_fpn
 from mx_rcnn_tpu.ops.roi_align import roi_align
@@ -140,13 +145,34 @@ class FPNFasterRCNN(nn.Module):
         self.rpn = RPNHead(num_anchors=net.NUM_ANCHORS,
                            channels=net.FPN_OUT_CHANNELS,
                            convs=2 if vit else 1, dtype=dtype)
-        self.head_body = (ConvFCBoxHead(channels=net.FPN_OUT_CHANNELS,
-                                        dtype=dtype) if vit
-                          else FPNBoxHead(dtype=dtype))
-        self.rcnn_out = RCNNOutput(num_classes=self.cfg.NUM_CLASSES, dtype=dtype)
+        self.head_body = self._head_body(vit, dtype)
+        # a RoI cascade's box heads regress one box a RoI for every class
+        agnostic = net.ROI_CASCADE_STAGES > 1
+        self.rcnn_out = RCNNOutput(num_classes=self.cfg.NUM_CLASSES,
+                                   cls_agnostic=agnostic, dtype=dtype)
+        # its later stages: a box head of their own each (head_body2 /
+        # rcnn_out2, ...); stage 1 is the head above
+        for t in range(2, net.ROI_CASCADE_STAGES + 1):
+            setattr(self, f"head_body{t}", self._head_body(vit, dtype))
+            setattr(self, f"rcnn_out{t}", RCNNOutput(
+                num_classes=self.cfg.NUM_CLASSES, cls_agnostic=agnostic,
+                dtype=dtype))
         if net.HAS_MASK:
             self.mask_head = MaskHead(num_classes=self.cfg.NUM_CLASSES,
                                       layer_norm=vit, dtype=dtype)
+
+    def _head_body(self, vit: bool, dtype):
+        if vit:
+            return ConvFCBoxHead(channels=self.cfg.network.FPN_OUT_CHANNELS,
+                                 dtype=dtype)
+        return FPNBoxHead(dtype=dtype)
+
+    def _box_stage(self, t: int):
+        """Stage ``t``'s (head body, output layer); stage 1 is the one box
+        head every pyramid preset has."""
+        if t == 1:
+            return self.head_body, self.rcnn_out
+        return getattr(self, f"head_body{t}"), getattr(self, f"rcnn_out{t}")
 
     # ---- shared pieces -----------------------------------------------------
 
@@ -256,11 +282,15 @@ class FPNFasterRCNN(nn.Module):
         return rois, roi_valid
 
     def _rcnn_losses(self, feats, rois, roi_valid, gt_boxes, gt_classes,
-                     gt_valid, keys):
+                     gt_valid, keys, im_info):
         """RoI sampling (ProposalTarget contract) + box-head losses.
         Returns (total, aux, tgt)."""
         cfg = self.cfg
         tr = cfg.TRAIN
+        if cfg.network.ROI_CASCADE_STAGES > 1:
+            return self._roi_cascade_losses(feats, rois, roi_valid, gt_boxes,
+                                            gt_classes, gt_valid, keys,
+                                            im_info)
         B = gt_boxes.shape[0]
         rois_aug = jnp.concatenate([rois, gt_boxes], axis=1)
         valid_aug = jnp.concatenate([roi_valid, gt_valid], axis=1)
@@ -285,6 +315,70 @@ class FPNFasterRCNN(nn.Module):
                "rcnn_label_weight": tgt["label_weight"]}
         return rcnn_cls_loss + rcnn_bbox_loss, aux, tgt
 
+    def _roi_cascade_losses(self, feats, rois, roi_valid, gt_boxes,
+                            gt_classes, gt_valid, keys, im_info):
+        """Cascade R-CNN training (Cai & Vasconcelos 2019, section 4): stage
+        t samples ``BATCH_ROIS`` of its input boxes + the gt at IoU
+        ``ROI_CASCADE_FG_THRESH[t - 1]`` (foreground at or over it,
+        background under it), regresses one class-agnostic box a RoI
+        against targets normalised by 1 / ``ROI_CASCADE_WEIGHTS[t - 1]``,
+        and hands its sampled RoIs decoded by its own deltas — held, no
+        gradient through them — to stage t + 1 as that stage's input.  The
+        stage losses are summed (``rcnn_cls_loss``, ``rcnn_bbox_loss``; a
+        stage's own under ``roi_cascade_s{t}_*``); the accuracy inputs and
+        the mask head's RoIs are stage 1's.  Returns (total, aux, stage
+        1's tgt)."""
+        cfg, net, tr = self.cfg, self.cfg.network, self.cfg.TRAIN
+        B = gt_boxes.shape[0]
+        K = cfg.NUM_CLASSES
+        boxes, valid = rois, roi_valid
+        aux = {"rcnn_cls_loss": 0.0, "rcnn_bbox_loss": 0.0}
+        first = None
+        for t in range(1, net.ROI_CASCADE_STAGES + 1):
+            thresh = net.ROI_CASCADE_FG_THRESH[t - 1]
+            stds = tuple(1.0 / w for w in net.ROI_CASCADE_WEIGHTS[t - 1])
+            keys_t = keys if t == 1 else jax.vmap(
+                jax.random.fold_in, (0, None))(keys, t)
+            tgt = jax.vmap(
+                lambda r, v, gtb, gtc, gtv, k: sample_rois(
+                    r, v, gtb, gtc, gtv, k,
+                    num_classes=K, batch_rois=tr.BATCH_ROIS,
+                    fg_fraction=tr.FG_FRACTION, fg_thresh=thresh,
+                    bg_thresh_hi=thresh, bg_thresh_lo=tr.BG_THRESH_LO,
+                    bbox_means=tr.BBOX_MEANS, bbox_stds=stds)
+            )(jnp.concatenate([boxes, gt_boxes], axis=1),
+              jnp.concatenate([valid, gt_valid], axis=1),
+              gt_boxes, gt_classes, gt_valid, keys_t)
+            tgt = jax.tree.map(jax.lax.stop_gradient, tgt)
+            body, out = self._box_stage(t)
+            cls_logits, bbox_out = out(body(
+                self._pool_levels(feats, tgt["rois"], pooled=7)))
+            # the class-specific 4K layout holds one live class a row
+            n = tgt["rois"].shape[1]
+            target = tgt["bbox_target"].reshape(B, n, K, 4).sum(2)
+            weight = tgt["bbox_weight"].reshape(B, n, K, 4).max(2)
+            cls_loss = L.softmax_ce_weighted(cls_logits, tgt["label"],
+                                             tgt["label_weight"])
+            bbox_loss = L.smooth_l1(bbox_out, target, weight, sigma=1.0,
+                                    norm=float(tr.BATCH_ROIS) * B)
+            aux[f"roi_cascade_s{t}_cls_loss"] = cls_loss
+            aux[f"roi_cascade_s{t}_bbox_loss"] = bbox_loss
+            aux["rcnn_cls_loss"] = aux["rcnn_cls_loss"] + cls_loss
+            aux["rcnn_bbox_loss"] = aux["rcnn_bbox_loss"] + bbox_loss
+            if first is None:
+                first = tgt
+                aux.update(rcnn_label=tgt["label"],
+                           rcnn_pred=jnp.argmax(cls_logits, axis=-1),
+                           rcnn_label_weight=tgt["label_weight"])
+            deltas = bbox_out * jnp.asarray(stds, jnp.float32) \
+                + jnp.asarray(tr.BBOX_MEANS, jnp.float32)
+            boxes = jax.lax.stop_gradient(refine_boxes(
+                tgt["rois"], deltas, im_info[:, 0, None, None],
+                im_info[:, 1, None, None]))
+            valid = tgt["label_weight"] > 0
+        total = aux["rcnn_cls_loss"] + aux["rcnn_bbox_loss"]
+        return total, aux, first
+
     # ---- train graph -------------------------------------------------------
 
     def __call__(self, images, im_info, gt_boxes, gt_classes, gt_valid, key,
@@ -299,7 +393,8 @@ class FPNFasterRCNN(nn.Module):
                                               gt_valid, keys[:, 0])
         rois, roi_valid = self._propose_train(levels, im_info)
         rcnn_total, rcnn_aux, tgt = self._rcnn_losses(
-            feats, rois, roi_valid, gt_boxes, gt_classes, gt_valid, keys[:, 1])
+            feats, rois, roi_valid, gt_boxes, gt_classes, gt_valid, keys[:, 1],
+            im_info)
         total = rpn_total + rcnn_total
         aux = {**rpn_aux, **rcnn_aux}
 
@@ -358,6 +453,35 @@ class FPNFasterRCNN(nn.Module):
             cls_prob = jax.nn.softmax(cls_logits, axis=-1)
         return (rois, roi_valid, cls_prob, bbox_deltas, roi_scores), feats
 
+    def roi_cascade(self, feats, rois, roi_valid, cls_prob, bbox_deltas,
+                    im_info):
+        """Stages 2.. of a RoI cascade over the pyramid ``predict_with_feats``
+        left, which is stage 1 (Cai & Vasconcelos 2019, section 4.3): from
+        stage 1's (B, R, 4) RoIs, (B, R, K) probabilities and (B, R, 4)
+        class-agnostic raw deltas, each stage t pools again at the boxes
+        b_t = clip(decode(b_{t-1}, deltas_{t-1})) (``ops.boxes.
+        refine_boxes``) on their assigned level, runs its own box head and
+        adds its softmax; no box is dropped between stages.  -> ((B, R, 4)
+        final boxes in the resized frame, (B, R, K) probabilities averaged
+        over the stages, each taken on its own stage's input boxes,
+        ``roi_valid`` as it came)."""
+        stages = self.cfg.network.ROI_CASCADE_STAGES
+        im_h = im_info[:, 0, None, None]
+        im_w = im_info[:, 1, None, None]
+        with jax.named_scope("roi_cascade/s1/decode"):
+            boxes = refine_boxes(rois, bbox_deltas, im_h, im_w)
+        scores = cls_prob
+        for t in range(2, stages + 1):
+            with jax.named_scope(f"roi_cascade/s{t}/pool"):
+                pooled = self._pool_levels(feats, boxes, pooled=7)
+            with jax.named_scope(f"roi_cascade/s{t}/head"):
+                body, out = self._box_stage(t)
+                cls_logits, deltas = out(body(pooled))
+                scores = scores + jax.nn.softmax(cls_logits, axis=-1)
+            with jax.named_scope(f"roi_cascade/s{t}/decode"):
+                boxes = refine_boxes(boxes, deltas, im_h, im_w)
+        return boxes, scores / stages, roi_valid
+
     def masks_from_feats(self, feats, boxes, labels):
         """Mask branch over precomputed pyramid features: (B, R, 4) boxes +
         (B, R) labels → (B, R, 28, 28) sigmoid probabilities."""
@@ -401,7 +525,7 @@ class FPNFasterRCNN(nn.Module):
         feats = self._pyramid(images)
         total, aux, _ = self._rcnn_losses(
             feats, rois, roi_valid, gt_boxes, gt_classes, gt_valid,
-            jax.random.split(key, B))
+            jax.random.split(key, B), im_info)
         return total, aux
 
     def predict_rpn(self, images, im_info):

@@ -66,9 +66,12 @@ class RPNHead(nn.Module):
 
 
 class RCNNOutput(nn.Module):
-    """cls_score / bbox_pred FCs on the head-body embedding."""
+    """cls_score / bbox_pred FCs on the head-body embedding.  With
+    ``cls_agnostic`` the box output is 4 wide: one box a RoI for every
+    class (a RoI cascade's stages)."""
 
     num_classes: int
+    cls_agnostic: bool = False
     dtype: jnp.dtype = jnp.bfloat16
 
     @nn.compact
@@ -76,7 +79,8 @@ class RCNNOutput(nn.Module):
         # reference init: cls_score Normal(0.01), bbox_pred Normal(0.001)
         cls = nn.Dense(self.num_classes, kernel_init=nn.initializers.normal(0.01),
                        dtype=self.dtype, name="cls_score")(x)
-        bbox = nn.Dense(4 * self.num_classes, kernel_init=nn.initializers.normal(0.001),
+        boxes = 1 if self.cls_agnostic else self.num_classes
+        bbox = nn.Dense(4 * boxes, kernel_init=nn.initializers.normal(0.001),
                         dtype=self.dtype, name="bbox_pred")(x)
         return cls.astype(jnp.float32), bbox.astype(jnp.float32)
 

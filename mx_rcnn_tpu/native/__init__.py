@@ -11,9 +11,10 @@ failure.
 API (drop-in with the numpy versions):
   bbox_overlaps(boxes (N,4), query (K,4)) -> (N,K) f32
   nms(dets (N,5), thresh) -> list[int]
-  nms_classes(scores (R,K), boxes (R,4K), valid (R,), thresh, nms_thresh)
-      -> (N,6) f32 [x1,y1,x2,y2,score,cls] or None   (one image's per-class
-      NMS in ONE foreign call; the caller's fallback is a loop over ``nms``)
+  nms_classes(scores (R,K), boxes (R,4K) or (R,4), valid (R,), thresh,
+      nms_thresh) -> (N,6) f32 [x1,y1,x2,y2,score,cls] or None   (one
+      image's per-class NMS in ONE foreign call; the caller's fallback is a
+      loop over ``nms``)
   rle_iou(dts, gts, iscrowd) -> (D,G) f64   (RLE dicts, uncompressed counts)
   available(symbol=None) -> bool
 """
@@ -70,15 +71,18 @@ def _load() -> Optional[ctypes.CDLL]:
     lib.mxr_nms.argtypes = [
         ctypes.POINTER(ctypes.c_float), ctypes.c_int64, ctypes.c_float,
         ctypes.POINTER(ctypes.c_int64)]
-    try:  # absent only in a stale .so that failed to rebuild
-        lib.mxr_nms_classes.restype = ctypes.c_int64
-        lib.mxr_nms_classes.argtypes = [
+    for name in ("mxr_nms_classes", "mxr_nms_classes_shared"):
+        try:  # absent only in a stale .so that failed to rebuild
+            fn = getattr(lib, name)
+        except AttributeError:
+            logger.warning("stale native library has no %s; per_class_nms "
+                           "loops over the classes there", name)
+            continue
+        fn.restype = ctypes.c_int64
+        fn.argtypes = [
             ctypes.POINTER(ctypes.c_float), ctypes.POINTER(ctypes.c_float),
             ctypes.POINTER(ctypes.c_uint8), ctypes.c_int64, ctypes.c_int64,
             ctypes.c_float, ctypes.c_float, ctypes.POINTER(ctypes.c_float)]
-    except AttributeError:
-        logger.warning("stale native library has no all-class NMS entry "
-                       "point; per_class_nms loops over the classes")
     try:  # absent only in a stale pre-round-4 .so that failed to rebuild
         lib.mxr_rle_encode.restype = ctypes.c_int64
         lib.mxr_rle_encode.argtypes = [
@@ -145,27 +149,31 @@ def nms(dets: np.ndarray, thresh: float) -> List[int]:
 def nms_classes(scores: np.ndarray, boxes: np.ndarray, valid,
                 thresh: float, nms_thresh: float) -> Optional[np.ndarray]:
     """One image's per-class NMS in one foreign call: (R, K) scores,
-    (R, 4K) boxes, (R,) validity → the kept rows ``[x1, y1, x2, y2, score,
-    cls]`` as (N, 6) float32, classes 1..K-1 in turn, each in the order
-    :func:`nms` keeps — or None when the library (or this entry point) is
-    missing and the caller has to loop.
+    (R, 4K) boxes — or (R, 4), one a row for every class
+    (``mxr_nms_classes_shared``) —, (R,) validity → the kept rows ``[x1,
+    y1, x2, y2, score, cls]`` as (N, 6) float32, classes 1..K-1 in turn,
+    each in the order :func:`nms` keeps — or None when the library (or this
+    entry point) is missing and the caller has to loop.
 
     Float32 throughout, as the loop over :func:`nms` computes: scores and
     boxes of another dtype are cast first, so a score is compared with
     ``thresh`` as the float32 it is kept as."""
     lib = _load()
-    if lib is None or not hasattr(lib, "mxr_nms_classes"):
-        return None
     scores = np.ascontiguousarray(scores, np.float32)
     boxes = np.ascontiguousarray(boxes, np.float32)
+    shared = boxes.shape[1:] == (4,)
+    name = "mxr_nms_classes_shared" if shared else "mxr_nms_classes"
+    if lib is None or not hasattr(lib, name):
+        return None
     valid = np.ascontiguousarray(valid, bool)
     assert scores.ndim == 2 and valid.shape == scores.shape[:1] \
-        and boxes.shape == (scores.shape[0], 4 * scores.shape[1]), \
-        f"(R, K) scores, (R, 4K) boxes and (R,) valid wanted; got " \
-        f"{scores.shape}, {boxes.shape}, {valid.shape}"
+        and boxes.shape[0] == scores.shape[0] \
+        and (shared or boxes.shape[1] == 4 * scores.shape[1]), \
+        f"(R, K) scores, (R, 4K) boxes (or (R, 4): one a row) and (R,) " \
+        f"valid wanted; got {scores.shape}, {boxes.shape}, {valid.shape}"
     r, k = scores.shape
     out = np.empty((r * max(k - 1, 0), 6), np.float32)
-    n = lib.mxr_nms_classes(
+    n = getattr(lib, name)(
         _fptr(scores), _fptr(boxes),
         valid.view(np.uint8).ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
         r, k, thresh, nms_thresh, _fptr(out))

@@ -89,9 +89,12 @@ int64_t mxr_nms(const float* dets, int64_t n, float thresh,
 // already removed box change no verdict), in the same float32 operations,
 // so the rows are mxr_nms's bit for bit and -O3 vectorises the loop.
 // out holds R*(K-1) rows of 6; returns the rows written.
-int64_t mxr_nms_classes(const float* scores, const float* boxes,
-                        const uint8_t* valid, int64_t r, int64_t k,
-                        float score_thresh, float nms_thresh, float* out) {
+// box_k: boxes a row — k (class c reads box c of its row) or 1 (every class
+// reads the row's one box: a RoI cascade's class-agnostic boxes).
+static int64_t nms_classes(const float* scores, const float* boxes,
+                           int64_t box_k, const uint8_t* valid, int64_t r,
+                           int64_t k, float score_thresh, float nms_thresh,
+                           float* out) {
   if (r <= 0 || k <= 1) return 0;
   // candidates by class, in the rows' order: count, then fill
   std::vector<int64_t> start(k + 1, 0);
@@ -131,7 +134,7 @@ int64_t mxr_nms_classes(const float* scores, const float* boxes,
       return scores[a * k + c] > scores[b * k + c];
     });
     for (int64_t j = 0; j < n; ++j) {
-      const float* b = boxes + (cand[j] * k + c) * 4;
+      const float* b = boxes + (cand[j] * box_k + (box_k == 1 ? 0 : c)) * 4;
       x1[j] = b[0]; y1[j] = b[1]; x2[j] = b[2]; y2[j] = b[3];
       area[j] = (b[2] - b[0] + 1.f) * (b[3] - b[1] + 1.f);
       sc[j] = scores[cand[j] * k + c];
@@ -154,6 +157,22 @@ int64_t mxr_nms_classes(const float* scores, const float* boxes,
     }
   }
   return written;
+}
+
+int64_t mxr_nms_classes(const float* scores, const float* boxes,
+                        const uint8_t* valid, int64_t r, int64_t k,
+                        float score_thresh, float nms_thresh, float* out) {
+  return nms_classes(scores, boxes, k, valid, r, k, score_thresh, nms_thresh,
+                     out);
+}
+
+// mxr_nms_classes over (R,4) boxes, one a row for every class.
+int64_t mxr_nms_classes_shared(const float* scores, const float* boxes,
+                               const uint8_t* valid, int64_t r, int64_t k,
+                               float score_thresh, float nms_thresh,
+                               float* out) {
+  return nms_classes(scores, boxes, 1, valid, r, k, score_thresh, nms_thresh,
+                     out);
 }
 
 // Advance to the next run, skipping zero-length runs (each skipped run

@@ -20,6 +20,8 @@ its own program on the device, with a transfer each way.  The host twin of
 
 from __future__ import annotations
 
+import math
+
 import jax.numpy as jnp
 
 # matches the reference's epsilon guard in nonlinear_transform
@@ -91,6 +93,22 @@ def clip_boxes(boxes: jnp.ndarray, im_h, im_w) -> jnp.ndarray:
     y2 = jnp.clip(boxes[..., 3::4], 0.0, im_h - 1.0)
     out = jnp.stack([x1, y1, x2, y2], axis=-1)
     return out.reshape(boxes.shape)
+
+
+# Detectron2's Box2BoxTransform scale_clamp: exp(dw) stays under 1000 / 16
+BBOX_XFORM_CLIP = math.log(1000.0 / 16.0)
+
+
+def refine_boxes(boxes: jnp.ndarray, deltas: jnp.ndarray, im_h,
+                 im_w) -> jnp.ndarray:
+    """A RoI cascade's step (Cai & Vasconcelos 2019): class-agnostic (…, 4)
+    raw deltas applied to (…, 4) boxes by :func:`bbox_pred`, dw and dh held
+    under ``BBOX_XFORM_CLIP`` first, then :func:`clip_boxes` to the image.
+    No box is dropped: every RoI goes on to the next stage."""
+    dxy = deltas[..., :2]
+    dwh = jnp.minimum(deltas[..., 2:], BBOX_XFORM_CLIP)
+    return clip_boxes(bbox_pred(boxes, jnp.concatenate([dxy, dwh], -1)),
+                      im_h, im_w)
 
 
 def bbox_overlaps(boxes: jnp.ndarray, query_boxes: jnp.ndarray) -> jnp.ndarray:

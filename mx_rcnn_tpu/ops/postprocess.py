@@ -68,13 +68,30 @@ def decode_image_boxes(rois: np.ndarray, deltas: np.ndarray,
     return out.reshape(deltas.shape)
 
 
+def image_boxes(rois: np.ndarray, out: np.ndarray,
+                im_info_row) -> np.ndarray:
+    """One image's boxes in ORIGINAL image coordinates from what the
+    predict path read back beside its rois: (R, 4K) head deltas are
+    decoded (:func:`decode_image_boxes`); (R, 4) boxes — one a RoI for
+    every class, which a RoI cascade's program has already decoded and
+    clipped in the resized frame (``models/fpn.py::roi_cascade``) — are
+    divided by the image's scale alone.  A head's deltas are never 4 wide:
+    K counts the background too."""
+    if out.shape[-1] != 4:
+        return decode_image_boxes(rois, out, im_info_row)
+    boxes = np.array(out, np.float32)
+    boxes /= np.float32(im_info_row[2])
+    return boxes
+
+
 def per_class_nms(scores: np.ndarray, boxes: np.ndarray, valid,
                   num_classes: int, thresh: float, nms_thresh: float,
                   max_per_image: int, nms_fn=None) -> List[Optional[np.ndarray]]:
     """One image's (R, K) scores + (R, 4K) original-frame boxes →
     per-class (N, 5) [x1,y1,x2,y2,score] detections (reference
     ``pred_eval`` inner block: per-class score threshold → NMS → global
-    per-image score cap).
+    per-image score cap).  (R, 4) boxes are one a RoI, the same for every
+    class (a RoI cascade's), read as they are, never tiled K times.
 
     Returns a list indexed by class; index 0 (background) is ``None``.
     With no ``nms_fn`` and the native library loaded, the threshold, the
@@ -86,9 +103,10 @@ def per_class_nms(scores: np.ndarray, boxes: np.ndarray, valid,
     if nms_fn is None:
         from mx_rcnn_tpu import native
 
-        rows = native.nms_classes(scores[:, :num_classes],
-                                  boxes[:, :4 * num_classes], valid,
-                                  thresh, nms_thresh)
+        rows = native.nms_classes(
+            scores[:, :num_classes],
+            boxes if boxes.shape[1] == 4 else boxes[:, :4 * num_classes],
+            valid, thresh, nms_thresh)
         if rows is not None:
             # cap total detections per image (reference max_per_image block)
             if 0 < max_per_image < len(rows):
@@ -100,11 +118,12 @@ def per_class_nms(scores: np.ndarray, boxes: np.ndarray, valid,
             return [None] + [rows[a:b] for a, b in zip(at[:-1], at[1:])]
         nms_fn = native.nms
     v = np.asarray(valid, bool)
+    shared = boxes.shape[1] == 4
     dets: List[Optional[np.ndarray]] = [None] * num_classes
     for k in range(1, num_classes):
         sel = (scores[:, k] > thresh) & v
         cls_scores = scores[sel, k]
-        cls_boxes = boxes[sel, 4 * k:4 * (k + 1)]
+        cls_boxes = boxes[sel, :4] if shared else boxes[sel, 4 * k:4 * (k + 1)]
         cls_dets = np.hstack([cls_boxes, cls_scores[:, None]]).astype(
             np.float32)
         keep = nms_fn(cls_dets, nms_thresh)

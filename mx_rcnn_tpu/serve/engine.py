@@ -95,7 +95,7 @@ caller's thread, at most one row copy; no allocation, no copy),
 (what is LEFT of the wait for the device when the finish half gets there,
 + what is left of the d2h: near the program's whole run for a lone batch
 or a device-bound turn, a few ms once the host is the slower), per image
-``serve/post/decode`` (``decode_image_boxes`` on the real requests' rows:
+``serve/post/decode`` (``image_boxes`` on the real requests' rows:
 numpy on the arrays the readback brought, no device array and no program)
 / ``serve/post/nms`` (their ``Hist``s observe once a batch, summed over its
 images) and ``serve/post/records`` (on the timeline only);
@@ -157,7 +157,11 @@ network's batches whose mask read-back and paste ran in a later turn than
 their post-process), on a pyramid
 network ``rois_valid`` and ``rois_level_p2`` … ``rois_level_p5`` (the
 proposals the joint NMS kept and the level the FPN
-paper's eq. 1 pools each from, counted on the host by the legacy path) and
+paper's eq. 1 pools each from, counted on the host by the legacy path),
+``roi_cascade_batches`` (a RoI cascade's batches whose stages 2.. ran, in
+the ``roi_cascade`` program ``predict`` launches behind its own; 0 on every
+other network, where ``serve/post/decode`` decodes per-class deltas and on
+a cascade only divides its one box a RoI by the image's scale) and
 ``h2d_bytes`` beside ``readback_bytes`` (÷ ``batches``: what a
 turn ships each way, the number ``--serve-e2e`` shrinks), and under
 ``"compile"`` the registry's snapshot with what XLA really compiled or
@@ -197,7 +201,7 @@ from mx_rcnn_tpu.config import Config
 from mx_rcnn_tpu.data.image import bucket_shape, stage_raw_to_bucket
 from mx_rcnn_tpu.data.loader import prepare_image
 from mx_rcnn_tpu.logger import logger
-from mx_rcnn_tpu.ops.postprocess import (decode_image_boxes,
+from mx_rcnn_tpu.ops.postprocess import (image_boxes,
                                          detections_to_records,
                                          device_dets_to_per_class,
                                          per_class_nms)
@@ -524,6 +528,8 @@ class ServeEngine:
                          # a mask network's batches whose mask read-back and
                          # paste ran in a later turn than their post-process
                          "overlapped_turns": 0, "deferred_masks": 0,
+                         # a RoI cascade's batches (stages 2.. ran)
+                         "roi_cascade_batches": 0,
                          "host_prep_ms_total": 0.0,
                          # stream-aware flush bookkeeping: batches that
                          # carried >= 1 stream frame, the frame count, and
@@ -538,6 +544,16 @@ class ServeEngine:
             # NMS and which level eq. 1 pools each from (_finish_legacy)
             self.counters.update(_roi_level_counts(np.zeros((0, 4)),
                                                    np.zeros((0,), bool)))
+        # a RoI cascade's later box stages run in a program of their own,
+        # launched by predict() right behind its own (Predictor.predict)
+        self._roi_cascade = cfg.network.ROI_CASCADE_STAGES > 1
+        if self._roi_cascade and self.opts.serve_e2e:
+            raise ValueError(
+                "--serve-e2e cannot serve a RoI cascade (Cascade R-CNN, "
+                "cfg.network.ROI_CASCADE_STAGES > 1): the fused "
+                "single-dispatch program decodes one box head's per-class "
+                "deltas and has no later stage; drop --serve-e2e (the "
+                "default path runs the stages)")
         # a mask network's turn has a second stage (_mask_stage); no
         # other network's turn, counters or /metrics know of it
         self._has_mask = bool(cfg.network.HAS_MASK)
@@ -1456,6 +1472,8 @@ class ServeEngine:
         if "post_candidates" in xfer:
             tel.counter("serve/post_candidates", xfer["post_candidates"])
             tel.counter("serve/post_nms_native", xfer["post_nms_native"])
+        if xfer.get("roi_cascade_batches"):
+            tel.counter("serve/roi_cascade_batches")
         if "mask_rois" in xfer:
             tel.counter("serve/mask_dispatches", xfer["mask_dispatches"])
             tel.counter("serve/mask_rois", xfer["mask_rois"])
@@ -1568,6 +1586,9 @@ class ServeEngine:
         flight.shape = tuple(images.shape)
         flight.first = self._note_first_dispatch(flight.shape,
                                                  "serve_predict", tel)
+        if self._roi_cascade:
+            # the stages' program, keyed by the same images' shape
+            self._note_first_dispatch(flight.shape, "roi_cascade", tel)
         with self._stage("serve/forward") as fwd:
             rois, roi_valid, cls_prob, bbox_deltas, _ = \
                 self.predictor.predict(images, im_info)
@@ -1621,8 +1642,8 @@ class ServeEngine:
             for r in reqs:
                 b = r.row
                 with decode:
-                    boxes = decode_image_boxes(rois[b], bbox_deltas[b],
-                                               np.asarray(r.im_info))
+                    boxes = image_boxes(rois[b], bbox_deltas[b],
+                                        np.asarray(r.im_info))
                 with nms:
                     dets_pc = per_class_nms(cls_prob[b], boxes, roi_valid[b],
                                             cfg.NUM_CLASSES, cfg.TEST.THRESH,
@@ -1657,7 +1678,15 @@ class ServeEngine:
                 "post_candidates": candidates,
                 "post_kept": kept,
                 "post_nms_native":
-                    n if native.available("mxr_nms_classes") else 0}
+                    n if native.available(
+                        "mxr_nms_classes_shared" if self._roi_cascade
+                        else "mxr_nms_classes") else 0}
+        if self._roi_cascade:
+            # its program reads the pyramid and predict's outputs where
+            # they are; im_info is shipped to it once more
+            xfer.update(dispatches=2, h2d_transfers=3,
+                        h2d_bytes=xfer["h2d_bytes"] + int(im_info.nbytes),
+                        roi_cascade_batches=1)
         if cfg.network.HAS_FPN:
             xfer.update(_roi_level_counts(rois, valid))
         if self._has_mask:

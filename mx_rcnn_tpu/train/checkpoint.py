@@ -4,9 +4,10 @@
 Contracts kept:
 
 * **De-normalize at save**: training regresses bbox targets normalized by
-  (BBOX_MEANS, BBOX_STDS); ``do_checkpoint`` folds them into the
-  ``bbox_pred`` weights/bias before writing, so the saved checkpoint
-  predicts raw deltas and inference needs no de-normalization.  On resume,
+  (BBOX_MEANS, BBOX_STDS) — a RoI cascade's stage t by 1 / its own
+  ``ROI_CASCADE_WEIGHTS[t - 1]`` —; ``do_checkpoint`` folds them into each
+  ``bbox_pred`` layer's weights/bias before writing, so the saved
+  checkpoint predicts raw deltas and inference needs no de-normalization.  On resume,
   the inverse fold is applied (reference train_end2end resume path).
 * Epoch-indexed checkpoints under ``prefix`` (``prefix-%04d.params`` →
   ``{prefix}/epoch_{n:04d}`` orbax directories), plus step-level resume —
@@ -38,43 +39,52 @@ from mx_rcnn_tpu.train.resilience import (decode_step_key, encode_step_key,
                                           retry_io)
 
 
-def _bbox_fold(params, means, stds, num_classes: int, invert: bool):
-    """Fold (or unfold) target normalization into the bbox_pred layer.
+def _bbox_fold(params, means, stds, invert: bool, stage_stds=None):
+    """Fold (or unfold) target normalization into the bbox_pred layers.
 
-    kernel: (D, 4K); bias: (4K,).  saved = trained * stds + means(bias only);
-    invert recovers the trained parametrization.
+    kernel: (D, 4N); bias: (4N,), N boxes a RoI (K, or 1 class-agnostic).
+    saved = trained * stds + means(bias only); invert recovers the trained
+    parametrization.  ``stage_stds`` ({output module: stds}) overrides
+    ``stds`` for the named modules' layers: a RoI cascade's stages.
     """
-    flat = jax.tree_util.tree_flatten_with_path(params)[0]
-    has_bbox = any(
-        any((getattr(e, "key", None) == "bbox_pred") for e in path)
-        for path, _ in flat)
-    if not has_bbox:
-        return params
-
-    stds_t = jnp.asarray(np.tile(np.asarray(stds, np.float32), num_classes))
-    means_t = jnp.asarray(np.tile(np.asarray(means, np.float32), num_classes))
-
     def fold(path, leaf):
         names = [getattr(e, "key", str(e)) for e in path]
-        if "bbox_pred" not in names:
+        if "bbox_pred" not in names or names[-1] not in ("kernel", "bias"):
             return leaf
+        at = names.index("bbox_pred")
+        owner = names[at - 1] if at else None
+        s = (stage_stds or {}).get(owner, stds)
+        reps = leaf.shape[-1] // 4
+        stds_t = jnp.asarray(np.tile(np.asarray(s, np.float32), reps))
+        means_t = jnp.asarray(np.tile(np.asarray(means, np.float32), reps))
         if names[-1] == "kernel":
             return leaf / stds_t[None, :] if invert else leaf * stds_t[None, :]
-        if names[-1] == "bias":
-            return (leaf - means_t) / stds_t if invert else leaf * stds_t + means_t
-        return leaf
+        return (leaf - means_t) / stds_t if invert else leaf * stds_t + means_t
 
     return jax.tree_util.tree_map_with_path(fold, params)
 
 
+def roi_cascade_stds(cfg) -> dict:
+    """{stage output module: its targets' stds}: a RoI cascade's stage t
+    (``rcnn_out``, ``rcnn_out2``, ...) regresses targets normalised by 1 /
+    ``network.ROI_CASCADE_WEIGHTS[t - 1]`` (models/fpn.py
+    ``_roi_cascade_losses``); empty on every other network."""
+    net = cfg.network
+    if net.ROI_CASCADE_STAGES <= 1:
+        return {}
+    return {("rcnn_out" if t == 1 else f"rcnn_out{t}"):
+            tuple(1.0 / w for w in net.ROI_CASCADE_WEIGHTS[t - 1])
+            for t in range(1, net.ROI_CASCADE_STAGES + 1)}
+
+
 def denormalize_for_save(params, cfg):
     return _bbox_fold(params, cfg.TRAIN.BBOX_MEANS, cfg.TRAIN.BBOX_STDS,
-                      cfg.NUM_CLASSES, invert=False)
+                      invert=False, stage_stds=roi_cascade_stds(cfg))
 
 
 def normalize_for_train(params, cfg):
     return _bbox_fold(params, cfg.TRAIN.BBOX_MEANS, cfg.TRAIN.BBOX_STDS,
-                      cfg.NUM_CLASSES, invert=True)
+                      invert=True, stage_stds=roi_cascade_stds(cfg))
 
 
 class CheckpointManager:

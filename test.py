@@ -51,6 +51,12 @@ def parse_args():
 def test_rcnn(args):
     setup_compile_cache()
     cfg = config_from_args(args, train=False)
+    if args.device_postprocess and cfg.network.ROI_CASCADE_STAGES > 1:
+        raise SystemExit(
+            f"--device-postprocess cannot run the RoI cascade of "
+            f"--network {args.network} (Cascade R-CNN): its fused program "
+            f"decodes one box head's per-class deltas; drop the flag (the "
+            f"default path runs the stages)")
     if args.device_postprocess and cfg.network.HAS_MASK \
             and cfg.TEST.MASK_PASTE == "native":
         # compact readbacks end to end: the same flag that fuses decode+NMS
